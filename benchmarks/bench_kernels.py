@@ -14,14 +14,14 @@ from jax.sharding import PartitionSpec
 from repro.dist.collectives import (
     ragged_all_to_all_reference,
     ring_ragged_all_to_all,
-    shard_map_compat,
+    shard_map_unchecked,
 )
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.moe_gemm import moe_grouped_ffn_pallas
 from repro.kernels.paged_attention import paged_attention_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 
 from .common import emit
 
@@ -110,7 +110,7 @@ def run(quick: bool = False):
     # devices this process has (CI's 8-device job makes it a real
     # exchange; on one device it degenerates to the local copy).
     n = jax.device_count()
-    mesh = compat_make_mesh((n,), ("model",))
+    mesh = make_mesh((n,), ("model",))
     R, dr = (32, 64) if quick else (128, 256)
     sizes = rng.integers(1, max(R // n, 2), (n, n)).astype(np.int32)
     if n > 1:
@@ -124,7 +124,7 @@ def run(quick: bool = False):
             return fn(rows_blk[0], send_blk[0], recv_blk[0], "model",
                       chunk_rows=R, out_rows=n * R)[None]
         spec = PartitionSpec("model")
-        return jax.jit(shard_map_compat(
+        return jax.jit(shard_map_unchecked(
             body, mesh, in_specs=(spec, spec, spec), out_specs=spec))
 
     want, us_ref = _time(_a2a(ragged_all_to_all_reference), payload, send,

@@ -15,6 +15,8 @@ import importlib
 import sys
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 BENCHES = {
     "fig6": "benchmarks.bench_capacity_sweep",
     "fig7": "benchmarks.bench_migration_trace",
@@ -45,6 +47,7 @@ def main() -> None:
                              "as BENCH_<group>.json (rows + git rev + "
                              "timestamp)")
     args = parser.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         args.quick = True
@@ -60,13 +63,7 @@ def main() -> None:
             failures.append(name)
             continue
         try:
-            mod = importlib.import_module(modname)
-        except ModuleNotFoundError:
-            # Subsystem not built yet / optional.
-            print(f"# skip {name}: module {modname} not present", file=sys.stderr)
-            continue
-        try:
-            rows = mod.run(quick=args.quick)
+            rows = importlib.import_module(modname).run(quick=args.quick)
         except Exception:
             traceback.print_exc()
             failures.append(name)

@@ -27,7 +27,7 @@ Rules (see DESIGN.md Sec. 12 for the catalog and the motivating PRs):
 
 Suppressions are inline and must carry a reason:
 
-    # check: disable=EXC01 -- private jax API probe; None is the contract
+    # check: disable=EXC01 -- the handler's fallback is the contract
 
 either on the finding's line or on a comment line directly above it.
 A suppression without a reason is itself a CHK00 finding.

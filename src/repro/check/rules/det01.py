@@ -30,7 +30,7 @@ from ..report import Finding
 
 _JIT_ENTRY_SUFFIXES = ("jax.jit", "pallas.pallas_call", "pl.pallas_call",
                        "jax.pmap")
-_SHARD_MAP_NAMES = ("shard_map", "shard_map_compat")
+_SHARD_MAP_NAMES = ("shard_map", "shard_map_unchecked")
 
 _TIME_CALLS = {
     "time.time", "time.time_ns", "time.perf_counter",

@@ -4,7 +4,7 @@ Two checks, both resolution-gated (an unresolvable site is skipped, not
 guessed at):
 
 * **Axis binding** — inside a function handed to ``shard_map`` /
-  ``shard_map_compat``, every ``jax.lax`` collective must name an axis
+  ``shard_map_unchecked``, every ``jax.lax`` collective must name an axis
   the enclosing mesh actually binds.  Bound names are recovered from the
   ``PartitionSpec``/``P`` literals in the call's in/out specs (following
   one level of local assignment) and from an inline ``Mesh(...,
@@ -28,7 +28,7 @@ from .. import callgraph
 from ..registry import Module, Rule, register
 from ..report import Finding
 
-_SHARD_MAP_NAMES = {"shard_map", "shard_map_compat"}
+_SHARD_MAP_NAMES = {"shard_map", "shard_map_unchecked"}
 # jax.lax collective -> index of its axis-name argument.
 _COLLECTIVES = {"psum": 1, "pmean": 1, "pmax": 1, "pmin": 1,
                 "all_gather": 1, "all_to_all": 1, "ppermute": 1,
@@ -155,7 +155,7 @@ class Spmd01(Rule):
                 known = False
             else:
                 bound |= axes
-        # shard_map_compat(f, mesh, in_specs, out_specs) is positional.
+        # shard_map_unchecked(f, mesh, in_specs, out_specs) is positional.
         for pos in (2, 3):
             if len(call.args) > pos:
                 axes = _axis_strings(call.args[pos], module, *scopes)

@@ -30,16 +30,9 @@ from .runtime import FractionPlacer
 
 
 def _with_memory_kind(x: jax.Array, kind: str) -> jax.Array:
-    sharding = x.sharding
-    if getattr(sharding, "memory_kind", None) == kind:
+    if x.sharding.memory_kind == kind:
         return x
-    try:
-        target = sharding.with_memory_kind(kind)
-    except ValueError:
-        # Backend without this memory kind (e.g. CPU jaxlibs lacking
-        # pinned_host): tier state stays logical, the array stays put.
-        return x
-    return jax.device_put(x, target)
+    return jax.device_put(x, x.sharding.with_memory_kind(kind))
 
 
 def memory_kind_of(x: jax.Array) -> Optional[str]:

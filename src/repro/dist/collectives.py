@@ -16,13 +16,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-# Version-portable shard_map: newer JAX exposes it at top level.  Callers
-# on older JAX import it from here instead of ``jax.shard_map``.
-try:
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map
-
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -93,17 +87,11 @@ def ring_matmul_reducescatter(h, w_row, axis_name: str):
 
 
 # ------------------------------------------------- ragged all-to-all (ep MoE)
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """shard_map without replication checking, across JAX versions (the
-    kwarg was renamed check_rep -> check_vma when shard_map moved to the
-    top level).  The ragged collectives below produce outputs the checker
-    cannot always prove replicated, so callers use this wrapper."""
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except TypeError:  # pragma: no cover - version-dependent
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+def shard_map_unchecked(f, mesh, in_specs, out_specs):
+    """shard_map without replication checking: the ragged collectives
+    below produce outputs the checker cannot always prove replicated."""
+    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                     check_vma=False)
 
 
 def _exclusive_cumsum(sizes):

@@ -164,18 +164,10 @@ def logical_to_pspec(
 
 def _active_mesh():
     """The mesh installed by ``with mesh:``, or None outside any context."""
-    try:
-        from jax._src.mesh import thread_resources
+    from jax._src.mesh import thread_resources
 
-        mesh = thread_resources.env.physical_mesh
-        if mesh is not None and not mesh.empty:
-            return mesh
-    # check: disable=EXC01 -- probes a private jax API across versions;
-    # ANY failure (ImportError, renamed attrs, changed types) means "no
-    # ambient mesh", and None is that contract.
-    except Exception:  # pragma: no cover - private-API drift
-        pass
-    return None
+    mesh = thread_resources.env.physical_mesh
+    return None if mesh.empty else mesh
 
 
 def active_mesh():
@@ -224,15 +216,7 @@ def batch_pspec(mesh) -> PartitionSpec:
 
 
 def abstract_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """Version-portable ``AbstractMesh`` constructor.
-
-    Newer JAX takes ``AbstractMesh(shape, names)``; older releases take one
-    ``((name, size), ...)`` tuple.  Tests and tooling use this helper so the
-    rule resolver stays exercisable on either API.
-    """
-    shapes = tuple(int(s) for s in axis_shapes)
-    names = tuple(axis_names)
-    try:
-        return jax.sharding.AbstractMesh(shapes, names)
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(names, shapes)))
+    """A device-free ``AbstractMesh`` of the given axes — tests and tooling
+    exercise the rule resolver on it without devices."""
+    return jax.sharding.AbstractMesh(tuple(int(s) for s in axis_shapes),
+                                     tuple(axis_names))

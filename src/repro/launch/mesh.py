@@ -3,29 +3,17 @@
 Defined as functions (never module-level constants) so importing this module
 never touches JAX device state — the dry-run must set XLA_FLAGS before any
 device query happens.
-
-``compat_make_mesh`` papers over the ``axis_types`` API difference between
-JAX releases: newer JAX wants explicit ``AxisType.Auto`` axes, older
-releases predate the parameter entirely.
 """
 
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # AxisType landed after 0.4.x; gate it so old CPU JAX still imports.
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - version-dependent
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
-def compat_make_mesh(shape, axes) -> Mesh:
-    """jax.make_mesh with AxisType.Auto on releases that support it."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes) -> Mesh:
+    """jax.make_mesh with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -33,9 +21,9 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     Multi-pod: 2x16x16 = 512 chips (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 4) -> Mesh:
     """Small mesh for CPU multi-device tests."""
-    return compat_make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

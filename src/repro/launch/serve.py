@@ -25,6 +25,7 @@ from ..configs import ARCHS, get, get_smoke
 from ..models import build_model
 from ..serve import LLM, SamplingParams, ServeConfig
 from .analysis import serving_summary
+from .compile_cache import enable_compile_cache
 
 
 def main():
@@ -77,6 +78,7 @@ def main():
                         "midpoint (requires --replicas >= 2); in-flight "
                         "requests warm-migrate to survivors")
     args = p.parse_args()
+    enable_compile_cache()
     if args.rolling_restart and args.replicas < 2:
         raise SystemExit("--rolling-restart needs --replicas >= 2 "
                          "(a lone replica has no migration target)")

@@ -29,6 +29,7 @@ from ..ft import HeartbeatMonitor
 from ..models import build_model
 from ..optim import AdamW, cosine_schedule
 from ..train import StepConfig, Trainer, TrainerConfig
+from .compile_cache import enable_compile_cache
 
 
 def main():
@@ -53,6 +54,7 @@ def main():
     p.add_argument("--simulate-failure", type=int, default=0,
                    help="inject a failure at this step and restart")
     args = p.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get(args.arch)
     cfg = dataclasses.replace(cfg, remat=not args.smoke)

@@ -42,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..dist.collectives import ring_ragged_all_to_all, shard_map_compat
+from ..dist.collectives import ring_ragged_all_to_all, shard_map_unchecked
 from ..dist.sharding import active_mesh, batch_data_axes, constrain
 from ..kernels import ops
 from .common import ArrayDef
@@ -362,7 +362,7 @@ def _moe_dropless_ep(p, x, cfg: MoEConfig, mesh):
         y = jax.lax.all_gather(y_my, "model", axis=0, tiled=True)
         return y[:T].reshape(B_loc, S, d).astype(x.dtype)
 
-    out = shard_map_compat(
+    out = shard_map_unchecked(
         body, mesh,
         in_specs=(x_spec, P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),

@@ -31,6 +31,7 @@ import dataclasses
 from collections import deque
 from typing import Deque, Dict, Iterator, List, Optional, Sequence, Tuple, Union
 
+import jax
 import numpy as np
 
 from ..core import TPU_V5E, HardwareModel
@@ -146,19 +147,22 @@ class LLM:
     tiering/scheduling knobs stay on ``ServeConfig``; per-request behaviour
     stays on ``SamplingParams`` — the caller never touches pages, tiers or
     batches.  ``replicas=N`` puts N engines behind the same front door
-    (``serve.cluster.Router``): requests dispatch least-loaded, and replica
-    failure/drain migrates in-flight streams bitwise instead of dropping
-    them.  The default ``replicas=1`` behaves exactly like the old
-    single-engine LLM — ``llm.engine`` then delegates engine attributes
-    transparently.
+    (``serve.cluster.Router``), replica *i* on ``jax.devices()[i % n]``
+    with its own copy of ``params``: requests dispatch least-loaded, and
+    replica failure/drain migrates in-flight streams bitwise (across
+    chips) instead of dropping them.  The default ``replicas=1`` behaves
+    exactly like the old single-engine LLM — ``llm.engine`` then delegates
+    engine attributes transparently.
     """
 
     def __init__(self, model, params, cfg: Optional[ServeConfig] = None,
                  hw: HardwareModel = TPU_V5E, replicas: int = 1,
                  heartbeat_timeout: float = 8.0):
         cfg = cfg or ServeConfig()
+        devices = jax.devices()
         self.cluster = Router(
-            lambda: Engine(model, params, cfg, hw),
+            lambda rid: Engine(model, params, cfg, hw,
+                               device=devices[rid % len(devices)]),
             n_replicas=replicas, heartbeat_timeout=heartbeat_timeout)
         self._handles: Dict[int, RequestHandle] = {}
         self._next_id = 0
@@ -175,8 +179,6 @@ class LLM:
     def from_arch(cls, arch: str, smoke: bool = True,
                   cfg: Optional[ServeConfig] = None,
                   seed: int = 0, replicas: int = 1) -> "LLM":
-        import jax
-
         from ..configs import get, get_smoke
         from ..models import build_model
 

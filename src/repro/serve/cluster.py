@@ -93,16 +93,18 @@ class EngineReplica:
 class Router:
     """Engine-shaped front door over N replicas (see module docstring).
 
-    ``engine_factory`` builds one fresh ``Engine`` per replica — replicas
-    share model/params through the factory's closure but own private KV
-    pools, prefix caches, and guidance runtimes.  On a one-replica cluster
-    unknown attributes delegate to that engine (``router.pool``,
-    ``router.prefix_cache``, ...), so single-engine tooling and tests keep
-    working unchanged; with more replicas the same access raises a named
-    ``AttributeError`` instead of silently picking one.
+    ``engine_factory(replica_id)`` builds one fresh ``Engine`` per replica
+    — replicas share the model through the factory's closure but own
+    private params copies, KV pools, prefix caches, and guidance runtimes
+    (``LLM`` puts replica *i* on device *i* mod the device count).  On a
+    one-replica cluster unknown attributes delegate to that engine
+    (``router.pool``, ``router.prefix_cache``, ...), so single-engine
+    tooling and tests keep working unchanged; with more replicas the same
+    access raises a named ``AttributeError`` instead of silently picking
+    one.
     """
 
-    def __init__(self, engine_factory: Callable[[], Engine],
+    def __init__(self, engine_factory: Callable[[int], Engine],
                  n_replicas: int = 1, heartbeat_timeout: float = 8.0,
                  clock: Optional[Callable[[], float]] = None):
         if n_replicas < 1:
@@ -135,7 +137,8 @@ class Router:
     def add_replica(self) -> EngineReplica:
         """Grow the cluster by one fresh replica (ids are never reused, so
         a restarted replica is observably a new member)."""
-        rep = EngineReplica(self._next_replica_id, self.engine_factory())
+        rep = EngineReplica(self._next_replica_id,
+                            self.engine_factory(self._next_replica_id))
         self._next_replica_id += 1
         self.replicas.append(rep)
         self.monitor.add_node(rep.replica_id)

@@ -80,6 +80,7 @@ number of host<->device transfers per direction.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections import deque
 from typing import Deque, Dict, List, Mapping, Optional, Sequence, Tuple
 
@@ -103,6 +104,16 @@ from .sampling import DEFAULT_MAX_TOKENS, SamplingParams
 from .scheduler import make_scheduler_policy
 
 F32 = jnp.float32
+
+# Every engine program rounds each bfloat16 value where the program says,
+# whatever XLA fuses around it: with excess precision allowed (XLA's
+# default) a fusion may carry a value in float32 past its bfloat16 cast, so
+# results would depend on where the jit boundaries fall.  This is not
+# enough to make the tiered layer-by-layer pipeline agree bitwise with the
+# resident scan on the TPU (measured: their streams still part); each path
+# agrees with itself across placements, and both with the f32 reference.
+_jit = functools.partial(
+    jax.jit, compiler_options={"xla_allow_excess_precision": False})
 
 
 @dataclasses.dataclass
@@ -149,9 +160,10 @@ class ServeConfig:
     # -------- guided expert-weight tiering (serve/expert_store.py) -------
     # Keep MoE expert FFN weights host-resident and dispatch through a
     # bounded HBM expert cache.  Decoding runs layer-by-layer (router picks
-    # sync to the host between attention and FFN) and is bitwise-equal to
-    # the resident single-scan path whenever each dispatch's expert working
-    # set fits the cache.
+    # sync to the host between attention and FFN); its result does not
+    # depend on the cache size, and on the CPU it is bitwise-equal to the
+    # resident single-scan path (on the TPU the two programs round
+    # differently).
     expert_offchip: bool = False
     # HBM cache capacity in expert blocks, shared across layers; 0 means
     # every (layer, expert) block fits (n_layers * n_experts slots).  Must
@@ -314,8 +326,12 @@ class PagedKVBackend:
 
 
 class Engine:
+    """One serving engine on one device (``device``, default the first):
+    its params, KV pools and expert store all live there, so replicas
+    behind a ``Router`` can each own a chip."""
+
     def __init__(self, model: Model, params, cfg: ServeConfig,
-                 hw: HardwareModel = TPU_V5E):
+                 hw: HardwareModel = TPU_V5E, device=None):
         assert model.cfg.family in ("dense", "moe"), \
             "paged engine serves decoder LMs"
         if model.cfg.family == "moe" and model.cfg.moe_parallelism == "ep":
@@ -358,15 +374,15 @@ class Engine:
                     f"least {floor} (0 = fully resident cache) or lower "
                     f"max_batch")
         self.model = model
-        self.params = params
         self.cfg = cfg
         self.hw = hw
+        self.device = device if device is not None else jax.devices()[0]
         mc = model.cfg
         self.pool = PagedKVPool(
             n_layers=mc.n_layers, page_size=cfg.page_size,
             kv_heads=mc.kv_heads, head_dim=model.attn_cfg.head_dim,
             hbm_pages=cfg.hbm_pages, host_pages=cfg.host_pages,
-            dtype=mc.dtype)
+            dtype=mc.dtype, device=self.device)
         self.requests: Dict[int, Request] = {}
         self.finished: Dict[int, Request] = {}
         self.wait_queue: Deque[int] = deque()
@@ -413,13 +429,17 @@ class Engine:
                 moe_params, mc.n_layers, E,
                 cfg.expert_cache_size or mc.n_layers * E,
                 double_buffer=cfg.expert_double_buffer, hw=hw,
-                window_bytes=attn_bytes)
+                window_bytes=attn_bytes, device=self.device)
             # The dense expert stacks live in the store's tiers now; serve
             # against a param view holding only the router.  ``params``
-            # itself is untouched (replicas re-derive their own stores).
+            # itself is untouched (replicas re-derive their own stores);
+            # a caller that needs the HBM back deletes its expert arrays.
             layers = dict(params["layers"])
             layers["moe"] = {"router": moe_params["router"]}
-            self.params = {**params, "layers": layers}
+            params = {**params, "layers": layers}
+        # A no-op for arrays already on this device; a replica on another
+        # chip gets its own copy there.
+        self.params = jax.device_put(params, self.device)
         self.kv_backend: Optional[PagedKVBackend] = None
         self.runtime: Optional[GuidanceRuntime] = None
         if cfg.policy == "gdt":
@@ -463,9 +483,9 @@ class Engine:
                         num_fragments=cfg.num_fragments,
                         skip_empty_intervals=True),
                     clock=lambda: self.step_count)
-        self._decode_greedy = jax.jit(self._build_decode(with_sampler=False))
-        self._decode_sampled = jax.jit(self._build_decode(with_sampler=True))
-        self._prefill = jax.jit(self._build_prefill())
+        self._decode_greedy = _jit(self._build_decode(with_sampler=False))
+        self._decode_sampled = _jit(self._build_decode(with_sampler=True))
+        self._prefill = _jit(self._build_prefill())
         if self.expert_store is not None:
             self._build_tiered_closures()
         self.last_logits: Dict[int, np.ndarray] = {}
@@ -569,7 +589,8 @@ class Engine:
         the identical ops up to the router, sync the routing picks to the
         host, and resume with ``_ffn_half``'s math against cache slots —
         the split point changes WHERE the jit boundary falls, never which
-        ops run, which is what keeps tiered output bitwise-equal."""
+        ops run (on the CPU the outputs are bitwise-equal; on the TPU the
+        two programs round differently)."""
         acfg = self.model.attn_cfg
         h = rmsnorm(lp["ln1"], x)
         q = jnp.einsum("bsd,dhk->bshk", h, lp["attn"]["wq"])
@@ -713,9 +734,11 @@ class Engine:
         of the picks, then a jitted FFN-from-cache + residual
         (``apply_dropless_flat`` with the slot map as ``group_experts``).
         Every op and its order is identical to the resident scan — only
-        the jit boundaries move — which is the invariant the bitwise
-        parity tests pin.  While one layer's FFN dispatch is in flight the
-        store stages the next layer's predicted experts (double buffer).
+        the jit boundaries move — which the bitwise parity tests pin on
+        the CPU (on the TPU the two programs round differently, so there
+        the tiered result is pinned across cache sizes instead).  While
+        one layer's FFN dispatch is in flight the store stages the next
+        layer's predicted experts (double buffer).
         """
         model = self.model
         acfg = model.attn_cfg
@@ -816,14 +839,14 @@ class Engine:
                 return logits, next_tokens
             return tail
 
-        self._t_embed = jax.jit(embed)
-        self._t_spec_route = jax.jit(spec_route)
-        self._t_decode_attn = jax.jit(decode_attn)
-        self._t_prefill_prologue = jax.jit(prefill_prologue)
-        self._t_prefill_attn = jax.jit(prefill_attn)
-        self._t_ffn = jax.jit(ffn)
-        self._t_tail_greedy = jax.jit(make_tail(False))
-        self._t_tail_sampled = jax.jit(make_tail(True))
+        self._t_embed = _jit(embed)
+        self._t_spec_route = _jit(spec_route)
+        self._t_decode_attn = _jit(decode_attn)
+        self._t_prefill_prologue = _jit(prefill_prologue)
+        self._t_prefill_attn = _jit(prefill_attn)
+        self._t_ffn = _jit(ffn)
+        self._t_tail_greedy = _jit(make_tail(False))
+        self._t_tail_sampled = _jit(make_tail(True))
 
     def _tiered_ffn(self, l, x, h2, gates, experts, lane_mask, token_mask,
                     wrap_prefetch=True):
@@ -848,9 +871,12 @@ class Engine:
         nb = store.take_rental_bytes()
         if nb and self.expert_runtime is not None:
             self.expert_runtime.record_rental(nb, source="expert_miss")
+        # Experts that only masked rows route to have no slot (-1); point
+        # them at row 0: those rows' outputs are zeroed by lane_mask, and a
+        # -1 would be an out-of-bounds weight DMA (the TPU halts on it).
+        slots = jnp.asarray(np.maximum(slot_map, 0))
         x = self._t_ffn(x, h2, store.w_gate_cache, store.w_up_cache,
-                        store.w_down_cache, jnp.asarray(slot_map), gates,
-                        experts, lane_mask)
+                        store.w_down_cache, slots, gates, experts, lane_mask)
         # Double buffer: stage the next dispatch's predicted working set
         # while this one's grouped GEMM is in flight.  Within a step the
         # prediction is speculative gating — the next layer's router run

@@ -54,17 +54,28 @@ class LRUEviction(EvictionPolicy):
 
     name = "lru"
 
+    @staticmethod
+    def _recency(engine):
+        """page -> the MOST recent last-scheduled step of its live holders,
+        else its own ``last_used`` — one pass over the sequence table."""
+        stamp: Dict[int, int] = {}
+        for rid, req in engine.requests.items():
+            for p in engine.pool.request_pages(rid):
+                stamp[p.page_id] = max(stamp.get(p.page_id, req.last_scheduled),
+                                       req.last_scheduled)
+        return lambda p: stamp.get(p.page_id, p.last_used)
+
     def pick(self, candidates: List[Page], engine) -> Optional[int]:
         if not candidates:
             return None
+        return min(candidates, key=self._recency(engine)).page_id
 
-        def recency(p: Page) -> int:
-            stamps = [engine.requests[rid].last_scheduled
-                      for rid in engine.pool.holders(p.page_id)
-                      if rid in engine.requests]
-            return max(stamps) if stamps else p.last_used
-
-        return min(candidates, key=recency).page_id
+    def pick_many(self, candidates: List[Page], engine,
+                  n: int) -> List[int]:
+        """Repeated ``pick`` is a stable sort on the same key (``min``
+        keeps the first of equals): rank once."""
+        ranked = sorted(candidates, key=self._recency(engine))
+        return [p.page_id for p in ranked[:n]]
 
 
 class FIFOEviction(EvictionPolicy):
@@ -91,6 +102,17 @@ class GuidedEviction(LRUEviction):
             if cold:
                 candidates = cold
         return super().pick(candidates, engine)
+
+    def pick_many(self, candidates: List[Page], engine,
+                  n: int) -> List[int]:
+        """Repeated ``pick`` takes every cold page (LRU among them) before
+        any page the plan wanted fast: one stable sort on (wanted fast,
+        recency)."""
+        recs: Dict[int, bool] = getattr(engine, "last_recs", {}) or {}
+        recency = self._recency(engine)
+        ranked = sorted(candidates, key=lambda p: (
+            bool(recs.get(p.page_id, False)), recency(p)))
+        return [p.page_id for p in ranked[:n]]
 
 
 EVICTION_POLICIES: Dict[str, Type[EvictionPolicy]] = {}

@@ -8,15 +8,14 @@ them in HBM opens larger-than-HBM model configs on the same hardware.
 Three layers live here:
 
 * **ExpertStore** — owns per-(layer, expert) weight blocks.  The host tier
-  is the authoritative, immutable copy of every block, flattened to
-  ``(n_layers * n_experts, ...)`` arrays on a pinned-host sharding; the
+  is the authoritative, immutable copy of every block, parked in
+  pinned_host memory one slab per layer (``serve/tier_io.HostTier``); the
   HBM tier is a bounded ``cache_slots``-row cache shared by all layers.
-  Movement mirrors ``PagedKVPool.swap_in_many``: one gather + one staged
-  ``jax.device_put`` + one scatter per weight array per direction, never a
-  per-block loop.  Expert weights never change, so *demotion is
-  metadata-only* — the slot is released and the host copy stays
-  authoritative; ``bytes_demoted`` counts the logical bytes leaving the
-  fast tier.
+  A fetch is one batched ``jax.device_put`` per weight array of the
+  layers' slabs holding the blocks, then in-place cache row writes.
+  Expert weights never change, so *demotion is metadata-only* — the slot
+  is released and the host copy stays authoritative; ``bytes_demoted``
+  counts the logical bytes leaving the fast tier.
 
 * **Double-buffered prefetch** — while layer L's grouped GEMM dispatch is
   in flight, the predicted working set for the next layer is staged onto
@@ -38,8 +37,10 @@ Three layers live here:
 
 Correctness bar: the cache-slot indirection rides the grouped GEMM's
 existing ``group_experts`` remap (``models/moe.apply_dropless_flat``), so
-any dispatch whose working set fits the cache is bitwise-equal to the
-fully-resident path.  A working set that cannot fit raises
+the result does not depend on which cache row holds a block: bitwise the
+same at any cache size that fits each dispatch's working set (and, on the
+CPU, bitwise the fully-resident path's; the TPU rounds that different
+program differently).  A working set that cannot fit raises
 ``ExpertCacheMissError`` naming ``ServeConfig.expert_cache_size`` — never
 a silent wrong-weight dispatch.
 
@@ -66,7 +67,7 @@ from ..core import MigrationPlan, MoveStats
 from ..core.fragmentation import ChunkStats
 from ..core.hwmodel import TPU_V5E, HardwareModel
 from ..core.profiler import ArenaProfile, IntervalProfile
-from .kvcache import DEVICE_KIND, HOST_KIND
+from .tier_io import HostTier, copy_rows
 
 # Modeled-time constants (StepCostModel idiom: deliberately round numbers
 # so overlap effects are deterministic on any host).
@@ -105,7 +106,7 @@ class _PendingFetch:
     layer: int
     experts: List[int]
     slots: List[int]
-    staged: Tuple[jax.Array, ...]
+    staged: tuple                 # (staged slabs, locations): HostTier.fetch
     hide_s: float                 # modeled overlap window at issue time
 
 
@@ -121,7 +122,7 @@ class ExpertStore:
     def __init__(self, moe_params: Mapping[str, jax.Array], n_layers: int,
                  n_experts: int, cache_slots: int, *,
                  double_buffer: bool = True, hw: HardwareModel = TPU_V5E,
-                 window_bytes: int = 0):
+                 window_bytes: int = 0, device=None):
         if cache_slots <= 0:
             raise ValueError(
                 f"ExpertStore needs at least one cache slot, got "
@@ -133,32 +134,26 @@ class ExpertStore:
         self.double_buffer = double_buffer
         self.hw = hw
         self.window_bytes = int(window_bytes)
-
-        dev = jax.devices()[0]
-        kinds: List[str] = []
-        # Capability probe, as in PagedKVPool: jaxlibs without memory-kind
-        # support either lack the method or refuse it; both mean one tier.
-        try:
-            kinds = [m.kind for m in dev.addressable_memories()]
-        except (AttributeError, RuntimeError, NotImplementedError):
-            pass
-        self._dev_sharding = jax.sharding.SingleDeviceSharding(
-            dev, memory_kind=DEVICE_KIND if DEVICE_KIND in kinds else None)
-        self._host_sharding = (
-            jax.sharding.SingleDeviceSharding(dev, memory_kind=HOST_KIND)
-            if HOST_KIND in kinds else self._dev_sharding)
-
-        self.block_bytes = 0
-        for name in _WEIGHTS:
-            w = moe_params[name]
-            assert w.shape[:2] == (L, E), (name, w.shape, L, E)
-            flat = jnp.reshape(w, (L * E,) + w.shape[2:])
-            setattr(self, name + "_host",
-                    jax.device_put(flat, self._host_sharding))
-            setattr(self, name + "_cache", jax.device_put(
-                jnp.zeros((self.cache_slots,) + w.shape[2:], w.dtype),
-                self._dev_sharding))
-            self.block_bytes += int(np.prod(w.shape[2:])) * w.dtype.itemsize
+        self.device = device if device is not None else jax.devices()[0]
+        weights = [moe_params[name] for name in _WEIGHTS]
+        for name, w in zip(_WEIGHTS, weights):
+            if w.shape[:2] != (L, E):
+                raise ValueError(
+                    f"expert weight {name} is shaped {w.shape}; expected "
+                    f"(n_layers={L}, n_experts={E}, ...)")
+        self.block_bytes = sum(int(np.prod(w.shape[2:])) * w.dtype.itemsize
+                               for w in weights)
+        # Host tier, addressed by chunk id: one slab per layer holding its
+        # E blocks of every weight.  The copy runs one layer at a time and
+        # waits for it, so HBM never holds more than one extra layer.
+        self.host = HostTier(self.device, axis=0)
+        for l in range(L):
+            jax.block_until_ready(self.host.park(
+                [([self.chunk_id(l, e) for e in range(E)],
+                  tuple(w[l] for w in weights))]))
+        self.w_gate_cache, self.w_up_cache, self.w_down_cache = (
+            jnp.zeros((self.cache_slots,) + w.shape[2:], w.dtype,
+                      device=self.host.device_sharding) for w in weights)
 
         self.blocks: Dict[Tuple[int, int], ExpertBlock] = {
             (l, e): ExpertBlock(l, e) for l in range(L) for e in range(E)}
@@ -213,26 +208,19 @@ class ExpertStore:
 
     # ------------------------------------------------------------ movement
     def _transfer(self, pairs: Sequence[Tuple[int, int]]):
-        """ONE batched host->device stage per weight array: gather the
-        flattened host rows, land them on the device sharding.  Returns the
-        staged arrays; the cache scatter happens at `_install`."""
-        idx = jnp.asarray(
-            [l * self.n_experts + e for l, e in pairs], dtype=jnp.int32)
-        staged = []
-        for name in _WEIGHTS:
-            host = getattr(self, name + "_host")
-            rows = np.asarray(jax.device_get(jnp.take(host, idx, axis=0)))
-            staged.append(jax.device_put(rows, self._dev_sharding))
-            self.transfer_events += 1
+        """ONE batched host->device transfer per weight array of the layer
+        slabs holding the blocks.  Returns ``HostTier.fetch``'s staging;
+        the cache writes happen at `_install`."""
+        staged = self.host.fetch([self.chunk_id(l, e) for l, e in pairs])
+        self.transfer_events += len(_WEIGHTS)
         self.bytes_fetched += self.block_bytes * len(pairs)
-        return tuple(staged)
+        return staged
 
     def _install(self, pairs: Sequence[Tuple[int, int]], slots: Sequence[int],
                  staged, step: int, *, prefetched: bool) -> None:
-        dst = jnp.asarray(list(slots), dtype=jnp.int32)
-        for name, rows in zip(_WEIGHTS, staged):
-            cache = getattr(self, name + "_cache")
-            setattr(self, name + "_cache", cache.at[dst].set(rows))
+        self.w_gate_cache, self.w_up_cache, self.w_down_cache = copy_rows(
+            (self.w_gate_cache, self.w_up_cache, self.w_down_cache),
+            slots, *staged, axis=0)
         for (l, e), s in zip(pairs, slots):
             b = self.blocks[(l, e)]
             b.slot = int(s)

@@ -3,10 +3,10 @@ the paper's arenas.
 
 Layout: one global pool of pages per tier; a page holds ``page_size`` tokens
 of K and V for *all* layers: (L, page_size, K, dh).  Pages migrate between
-the HBM pool (memory kind "device") and the host pool ("pinned_host") as
-whole blocks — they are the ``ChunkStats`` chunks the fragmentation engine
-reasons about, and each *request* is an allocation site whose arena is its
-page list.
+the HBM pool (memory kind "device") and the host tier ("pinned_host"
+slabs, ``serve/tier_io.HostTier``) as whole blocks — they are the
+``ChunkStats`` chunks the fragmentation engine reasons about, and each
+*request* is an allocation site whose arena is its page list.
 
 Attention computes only against the HBM pool; a page on the host tier must
 be swapped in before its sequence can decode (the swap is the rental the
@@ -15,8 +15,8 @@ counts — on every decode step the access set is known statically (all pages
 of the scheduled sequences, or the window's pages under SWA).
 
 Migrations are batched: ``swap_in_many`` / ``swap_out_many`` realize a whole
-direction of a ``MigrationPlan`` as one gather + one staged transfer + one
-scatter per pool array, so enforcing an N-page plan costs a constant number
+direction of a ``MigrationPlan`` as one batched transfer per pool array
+(``serve/tier_io.py``), so enforcing an N-page plan costs a constant number
 of host<->device transfers (``transfer_events`` is the probe) while the
 per-page swap/byte counters stay exact.
 
@@ -41,8 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-HOST_KIND = "pinned_host"
-DEVICE_KIND = "device"
+from .tier_io import HostTier, copy_rows, gather_rows
 
 
 @dataclasses.dataclass
@@ -111,32 +110,26 @@ class Page:
 
 
 class PagedKVPool:
-    """Two-tier physical page pools + logical page bookkeeping."""
+    """Two-tier physical page pools + logical page bookkeeping.
+
+    The HBM tier is one ``(L, hbm_pages, P, K, dh)`` array per K and V on
+    ``device``; the host tier (``host``, a ``tier_io.HostTier``) parks
+    pages in pinned_host slabs, one slab per request per migration batch,
+    addressed by host slot.
+    """
 
     def __init__(self, n_layers: int, page_size: int, kv_heads: int,
                  head_dim: int, hbm_pages: int, host_pages: int,
-                 dtype=jnp.bfloat16):
+                 dtype=jnp.bfloat16, device=None):
         self.shape = (page_size, kv_heads, head_dim)
         self.page_size = page_size
         self.n_layers = n_layers
-        pool = lambda n: jnp.zeros((n_layers, n) + self.shape, dtype)
-        dev = jax.devices()[0]
-        kinds = []
-        # Capability probe: jaxlibs without memory-kind support either
-        # lack the method or refuse it at runtime; both mean "one tier".
-        try:
-            kinds = [m.kind for m in dev.addressable_memories()]
-        except (AttributeError, RuntimeError, NotImplementedError):
-            pass
-        self._dev_sharding = jax.sharding.SingleDeviceSharding(
-            dev, memory_kind=DEVICE_KIND if DEVICE_KIND in kinds else None)
-        self._host_sharding = (
-            jax.sharding.SingleDeviceSharding(dev, memory_kind=HOST_KIND)
-            if HOST_KIND in kinds else self._dev_sharding)
-        self.k_hbm = jax.device_put(pool(hbm_pages), self._dev_sharding)
-        self.v_hbm = jax.device_put(pool(hbm_pages), self._dev_sharding)
-        self.k_host = jax.device_put(pool(host_pages), self._host_sharding)
-        self.v_host = jax.device_put(pool(host_pages), self._host_sharding)
+        self.device = device if device is not None else jax.devices()[0]
+        self.host = HostTier(self.device, axis=1)
+        pool_shape = (n_layers, hbm_pages) + self.shape
+        dev = self.host.device_sharding
+        self.k_hbm = jnp.zeros(pool_shape, dtype, device=dev)
+        self.v_hbm = jnp.zeros(pool_shape, dtype, device=dev)
 
         self.hbm_pages = hbm_pages
         self.host_pages = host_pages
@@ -150,7 +143,7 @@ class PagedKVPool:
         self.swaps_in = 0
         self.swaps_out = 0
         self.bytes_moved = 0
-        # Host<->device transfer probe: one event per staged pool-array
+        # Host<->device transfer probe: one event per batched pool-array
         # transfer (K and V count separately).  A batched N-page migration
         # costs a constant number of events per direction; the per-page
         # path costs 2 per page.  The migration-parity test asserts on it.
@@ -202,7 +195,7 @@ class PagedKVPool:
         if page.hbm_slot is not None:
             self.free_hbm.append(page.hbm_slot)
         if page.host_slot is not None:
-            self.free_host.append(page.host_slot)
+            self._release_host([page.host_slot])
 
     # ----------------------------------------------------------- sharing
     def acquire(self, page_id: int, shared: bool = False) -> Page:
@@ -274,46 +267,47 @@ class PagedKVPool:
                    tokens_used=src.tokens_used, last_used=step)
         self._next_id += 1
         self.pages[new.page_id] = new
-        self.k_hbm = self.k_hbm.at[:, slot].set(self.k_hbm[:, src.hbm_slot])
-        self.v_hbm = self.v_hbm.at[:, slot].set(self.v_hbm[:, src.hbm_slot])
+        self.k_hbm, self.v_hbm = copy_rows(
+            (self.k_hbm, self.v_hbm), [slot],
+            [gather_rows((self.k_hbm, self.v_hbm), [src.hbm_slot], axis=1)],
+            [(0, 0)], axis=1)
         seq[at] = new
         self.free(page_id)               # drop the request's old reference
         return new
 
     # ------------------------------------------------------- migrations
-    def _gather(self, src_k, src_v, src_idx):
-        """Stage M pages out of a tier as numpy: ONE gather + device_get
-        per pool array, regardless of M.
-
-        Memory-kind metadata does not survive eager slicing on the CPU
-        backend (the slice stays physically host-resident while reporting
-        "device"), so the cross-tier copy stages through numpy and lands
-        with an explicit device_put onto the destination tier's sharding
-        (``_scatter``).  On TPU this path is a jitted DMA with in/out
-        memory kinds instead.
-        """
-        if not src_idx:
-            return None
-        si = jnp.asarray(src_idx, jnp.int32)
-        return (np.asarray(jax.device_get(jnp.take(src_k, si, axis=1))),
-                np.asarray(jax.device_get(jnp.take(src_v, si, axis=1))))
-
-    def _scatter(self, dst_k, dst_v, dst_idx, staged, dst_sharding):
-        """Land staged pages on a tier: ONE device_put + scatter per pool
-        array, regardless of M."""
-        di = jnp.asarray(dst_idx, jnp.int32)
-        ksrc = jax.device_put(staged[0], dst_sharding)
-        vsrc = jax.device_put(staged[1], dst_sharding)
-        dst_k = dst_k.at[:, di].set(ksrc)
-        dst_v = dst_v.at[:, di].set(vsrc)
+    def _park(self, pages: Sequence["Page"], host_slots: Sequence[int]):
+        """HBM -> host: one slab per allocating request (its pages travel
+        together), ONE batched transfer per pool array for all of them.
+        Reads the pages' current HBM slots, so call it before those slots
+        are written again."""
+        groups: Dict[int, List[Tuple[int, int]]] = {}
+        for page, slot in zip(pages, host_slots):
+            groups.setdefault(page.request_id, []).append(
+                (page.hbm_slot, slot))
+        self.host.park([
+            ([s for _, s in pairs],
+             gather_rows((self.k_hbm, self.v_hbm), [h for h, _ in pairs],
+                         axis=1))
+            for pairs in groups.values()])
         self.transfer_events += 2            # one per pool array (K, V)
-        return dst_k, dst_v
 
-    def _move_pages(self, src_k, src_v, src_idx, dst_k, dst_v, dst_idx,
-                    dst_sharding):
-        """One-directional batched move: gather-stage then scatter."""
-        staged = self._gather(src_k, src_v, src_idx)
-        return self._scatter(dst_k, dst_v, dst_idx, staged, dst_sharding)
+    def _land(self, hbm_slots: Sequence[int], staged, locs) -> None:
+        """Write staged rows (``HostTier.fetch`` output, or one numpy-fed
+        slab) into HBM slots in place."""
+        self.k_hbm, self.v_hbm = copy_rows(
+            (self.k_hbm, self.v_hbm), hbm_slots, staged, locs, axis=1)
+
+    def _unpark(self, host_slots: Sequence[int]):
+        """host -> device: ONE batched transfer per pool array of the slabs
+        holding the pages; ``_land`` writes them into HBM."""
+        staged, locs = self.host.fetch(host_slots)
+        self.transfer_events += 2
+        return staged, locs
+
+    def _release_host(self, slots: Sequence[int]) -> None:
+        self.host.release(slots)
+        self.free_host.extend(slots)
 
     def swap_out_many(self, page_ids: Sequence[int]):
         """HBM -> host, one batched transfer for the whole id list.
@@ -327,9 +321,7 @@ class PagedKVPool:
             raise MemoryError("host pool exhausted")
         src = [self.pages[pid].hbm_slot for pid in ids]
         dst = [self.free_host.pop() for _ in ids]
-        self.k_host, self.v_host = self._move_pages(
-            self.k_hbm, self.v_hbm, src,
-            self.k_host, self.v_host, dst, self._host_sharding)
+        self._park([self.pages[pid] for pid in ids], dst)
         for pid, si, di in zip(ids, src, dst):
             page = self.pages[pid]
             self.free_hbm.append(si)
@@ -352,12 +344,10 @@ class PagedKVPool:
                 f"ServeConfig.hbm_pages")
         src = [self.pages[pid].host_slot for pid in ids]
         dst = [self.free_hbm.pop() for _ in ids]
-        self.k_hbm, self.v_hbm = self._move_pages(
-            self.k_host, self.v_host, src,
-            self.k_hbm, self.v_hbm, dst, self._dev_sharding)
-        for pid, si, di in zip(ids, src, dst):
+        self._land(dst, *self._unpark(src))
+        self._release_host(src)
+        for pid, di in zip(ids, dst):
             page = self.pages[pid]
-            self.free_host.append(si)
             page.host_slot, page.hbm_slot = None, di
         self.swaps_in += len(ids)
         self.bytes_moved += self.page_bytes * len(ids)
@@ -396,20 +386,17 @@ class PagedKVPool:
         in_src = [self.pages[pid].host_slot for pid in ins]
         # Stage BOTH directions before any scatter: a destination slot may
         # be a just-freed source slot of the opposite direction.
-        out_stage = self._gather(self.k_hbm, self.v_hbm, out_src)
-        in_stage = self._gather(self.k_host, self.v_host, in_src)
+        if ins:
+            in_stage = self._unpark(in_src)
+        out_pages = [self.pages[pid] for pid in outs]
         self.free_hbm.extend(out_src)
-        self.free_host.extend(in_src)
+        self._release_host(in_src)
         in_dst = [self.free_hbm.pop() for _ in ins]
         out_dst = [self.free_host.pop() for _ in outs]
         if outs:
-            self.k_host, self.v_host = self._scatter(
-                self.k_host, self.v_host, out_dst, out_stage,
-                self._host_sharding)
+            self._park(out_pages, out_dst)
         if ins:
-            self.k_hbm, self.v_hbm = self._scatter(
-                self.k_hbm, self.v_hbm, in_dst, in_stage,
-                self._dev_sharding)
+            self._land(in_dst, *in_stage)
         for pid, di in zip(outs, out_dst):
             page = self.pages[pid]
             page.hbm_slot, page.host_slot = None, di
@@ -423,9 +410,9 @@ class PagedKVPool:
     # ------------------------------------------------- cross-pool handoff
     def export_pages(self, page_ids: Sequence[int]) -> PageExport:
         """Stage pages out of this pool for import into another (warm live
-        migration).  One batched gather per source tier regardless of page
-        count; the source pool is left untouched — the exporter releases
-        its references separately once the handoff lands."""
+        migration).  One gather per source tier regardless of page count;
+        the source pool is left untouched — the exporter releases its
+        references separately once the handoff lands."""
         ids = list(dict.fromkeys(page_ids))
         missing = [pid for pid in ids if pid not in self.pages]
         if missing:
@@ -438,13 +425,16 @@ class PagedKVPool:
         fast_rows = [i for i, p in enumerate(pages) if p.hbm_slot is not None]
         slow_rows = [i for i, p in enumerate(pages) if p.hbm_slot is None]
         if fast_rows:
-            staged = self._gather(self.k_hbm, self.v_hbm,
-                                  [pages[i].hbm_slot for i in fast_rows])
-            k[:, fast_rows], v[:, fast_rows] = staged
+            kf, vf = jax.device_get(gather_rows(
+                (self.k_hbm, self.v_hbm),
+                [pages[i].hbm_slot for i in fast_rows], axis=1))
+            k[:, fast_rows] = kf[:, :len(fast_rows)]
+            v[:, fast_rows] = vf[:, :len(fast_rows)]
         if slow_rows:
-            staged = self._gather(self.k_host, self.v_host,
-                                  [pages[i].host_slot for i in slow_rows])
-            k[:, slow_rows], v[:, slow_rows] = staged
+            k_rows, v_rows = self.host.read(
+                [pages[i].host_slot for i in slow_rows])
+            k[:, slow_rows] = np.stack(k_rows, axis=1)
+            v[:, slow_rows] = np.stack(v_rows, axis=1)
         self.exported_pages += n
         return PageExport(
             page_ids=[p.page_id for p in pages],
@@ -462,7 +452,8 @@ class PagedKVPool:
         handoff), overflows to the other tier otherwise, and the whole
         import raises ``MemoryError`` — before moving any data — when the
         pools combined cannot hold it (callers fall back to cold
-        recompute).  One batched scatter per destination tier."""
+        recompute).  One batched transfer per pool array per destination
+        tier."""
         if (export.n_layers, tuple(export.shape)) != (self.n_layers,
                                                       tuple(self.shape)):
             raise ValueError(
@@ -499,13 +490,12 @@ class PagedKVPool:
             slots = [free.pop() for _ in rows]
             staged = (export.k[:, rows], export.v[:, rows])
             if is_fast:
-                self.k_hbm, self.v_hbm = self._scatter(
-                    self.k_hbm, self.v_hbm, slots, staged,
-                    self._dev_sharding)
+                self._land(slots, [tuple(jax.device_put(
+                    staged, self.host.device_sharding))],
+                    [(0, j) for j in range(len(rows))])
             else:
-                self.k_host, self.v_host = self._scatter(
-                    self.k_host, self.v_host, slots, staged,
-                    self._host_sharding)
+                self.host.park([(slots, staged)])
+            self.transfer_events += 2
             for i, slot in zip(rows, slots):
                 page = Page(
                     page_id=self._next_id, request_id=request_id,

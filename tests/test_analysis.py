@@ -115,8 +115,8 @@ def _collect(devices, body):
 def test_while_loop_collectives_expand_by_trip_count():
     out = _collect(8, """
         from repro.launch.analysis import collective_bytes
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((8,), ("model",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("model",))
         L = 7
         def f(x, ws):
             def body(x, w):
@@ -148,8 +148,8 @@ def test_while_loop_collectives_expand_by_trip_count():
 def test_direct_collectives_counted_once():
     out = _collect(8, """
         from repro.launch.analysis import collective_bytes
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((8,), ("d",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("d",))
         def f(x):
             return jax.lax.with_sharding_constraint(
                 x.sum(axis=0, keepdims=True) + x, NamedSharding(mesh, P()))
@@ -176,10 +176,10 @@ def dryrun_train_artifact(tmp_path_factory):
     if os.path.exists(real):
         return real
     from repro.launch.dryrun import run_cell
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
 
     outdir = str(tmp_path_factory.mktemp("dryrun") / "pod256")
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rec = run_cell("llama3_2_1b", "train_4k", mesh, "pod256", outdir,
                    trace_only=True)
     assert rec["status"] == "ok", rec.get("error")

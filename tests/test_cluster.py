@@ -94,11 +94,11 @@ def make_pool(seed=0):
 
 
 def page_bytes(pool, page):
-    src_k = pool.k_hbm if page.hbm_slot is not None else pool.k_host
-    src_v = pool.v_hbm if page.hbm_slot is not None else pool.v_host
-    slot = page.hbm_slot if page.hbm_slot is not None else page.host_slot
-    return (np.asarray(src_k[:, slot]).tobytes(),
-            np.asarray(src_v[:, slot]).tobytes())
+    if page.hbm_slot is not None:
+        k, v = pool.k_hbm[:, page.hbm_slot], pool.v_hbm[:, page.hbm_slot]
+    else:
+        (k,), (v,) = pool.host.read([page.host_slot])
+    return np.asarray(k).tobytes(), np.asarray(v).tobytes()
 
 
 def test_export_import_roundtrip_bitwise_and_tier_preserving():
@@ -393,3 +393,58 @@ def test_cluster_serving_summary_aggregates_and_nests(model_and_params):
     run_to_completion(solo, hs)
     flat = serving_summary(solo.cluster)
     assert "replicas" not in flat and flat["cluster_replicas"] == 1
+
+
+def test_replicas_get_their_own_devices_and_drain_across_them():
+    """``LLM(replicas=4)`` on four devices puts replica i's params and
+    pools on device i; draining one warm-migrates its pages to replicas on
+    other devices, and every stream stays bitwise equal to one replica."""
+    from test_dist_collectives import run_in_subprocess
+
+    out = run_in_subprocess("""
+        import dataclasses
+        from repro.configs import get_smoke
+        from repro.models import build_model
+        from repro.serve import LLM, SamplingParams, ServeConfig
+
+        cfg = dataclasses.replace(get_smoke("llama3_2_1b"), remat=False)
+        model = build_model(cfg)
+        params = model.init(jax.random.PRNGKey(0))
+        sc = ServeConfig(max_batch=2, page_size=4, hbm_pages=16,
+                         host_pages=32, max_pages_per_seq=8)
+        rng = np.random.default_rng(0)
+        prompts = [[int(t) for t in rng.integers(1, cfg.vocab, n)]
+                   for n in (9, 6, 11, 7, 5, 10, 8, 6)]
+
+        def run(replicas, drain_at=None):
+            llm = LLM(model, params, sc, replicas=replicas)
+            hs = [llm.submit(p, SamplingParams(max_tokens=6), request_id=i)
+                  for i, p in enumerate(prompts)]
+            steps, exported = 0, 0
+            while not all(h.finished for h in hs):
+                if steps == drain_at:
+                    victim = max(llm.cluster.replicas,
+                                 key=lambda r: len(r.engine.requests))
+                    llm.cluster.drain(victim.replica_id)
+                    exported = victim.engine.pool.exported_pages
+                llm.step()
+                steps += 1
+            return llm, [h.result().token_ids for h in hs], exported
+
+        _, want, _ = run(1)
+        llm, got, exported = run(4, drain_at=2)
+        placement = []
+        for rep in llm.cluster.replicas:
+            e = rep.engine
+            devs = {e.device} | e.pool.k_hbm.devices()
+            for a in jax.tree.leaves(e.params):
+                devs |= a.devices()
+            placement.append(devs)
+        assert all(len(d) == 1 for d in placement), placement
+        assert len(set.union(*placement)) == 4, placement
+        assert exported > 0
+        assert llm.cluster.migrations_warm > 0
+        assert got == want
+        print("replicas on own devices OK")
+    """, devices=4)
+    assert "replicas on own devices OK" in out

@@ -18,19 +18,6 @@ from repro.core.placement import JaxArenaPlacer, memory_kind_of
 MB = 2**20
 
 
-def has_host_memory():
-    try:
-        kinds = [m.kind for m in jax.devices()[0].addressable_memories()]
-        return "pinned_host" in kinds
-    except (AttributeError, RuntimeError, IndexError, NotImplementedError):
-        return False
-
-
-pytestmark = pytest.mark.skipif(
-    not has_host_memory(), reason="backend lacks pinned_host memory kind"
-)
-
-
 def build(cap_bytes, first_touch=False):
     reg = SiteRegistry()
     mgr = ArenaManager(

@@ -121,11 +121,6 @@ def test_overlap_replaces_allgather_with_permutes():
     """)
 
 
-from conftest import has_host_memory
-
-
-@pytest.mark.skipif(not has_host_memory(),
-                    reason="backend lacks pinned_host memory kind")
 def test_gdt_placement_on_sharded_params():
     """Tier migration composes with mesh sharding: a sharded array keeps its
     PartitionSpec across a host-tier roundtrip."""

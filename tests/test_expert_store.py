@@ -48,6 +48,27 @@ def counts(*experts):
     return c
 
 
+def test_host_tier_roundtrip_keeps_memory_kinds_and_bits():
+    """Every block's authoritative copy is a pinned_host array per weight,
+    bitwise the caller's block; a dispatch lands it in the device-kind
+    cache bitwise."""
+    params, st = make_store(4)
+    names = ("w_gate", "w_up", "w_down")
+    assert st.host.memory_kinds() == {"pinned_host"}
+    for l in range(L):
+        for e in range(E):
+            rows = st.host.read([st.chunk_id(l, e)])
+            for (row,), name in zip(rows, names):
+                assert np.array_equal(row, np.asarray(params[name][l, e]))
+    slot_map = st.dispatch(1, counts(0, 3), step=1)
+    caches = (st.w_gate_cache, st.w_up_cache, st.w_down_cache)
+    for e in (0, 3):
+        for cache, name in zip(caches, names):
+            assert cache.sharding.memory_kind == "device"
+            assert np.array_equal(np.asarray(cache[slot_map[e]]),
+                                  np.asarray(params[name][1, e]))
+
+
 def test_init_rejects_zero_slots():
     with pytest.raises(ValueError, match="expert_cache_size"):
         make_store(0)
@@ -195,8 +216,19 @@ def test_tiered_bitwise_equal_across_cache_sizes(moe_model,
     path match the resident path bitwise, at cache sizes from all-fit (16)
     down to heavy eviction churn (8 of 16 blocks)."""
     llm = make_llm(moe_model, expert_offchip=True, expert_cache_size=cache)
+    engine = llm.cluster.replicas[0].engine
+    ffn, slot_maps = engine._t_ffn, []
+
+    def recording_ffn(*args):
+        slot_maps.append(np.asarray(args[5]))
+        return ffn(*args)
+
+    engine._t_ffn = recording_ffn
     got = drive(llm, PROMPTS, PLIST)
     assert_equal_runs(resident_reference, got)
+    # The grouped GEMM only ever names cache rows, even for experts that
+    # only masked rows route to: on a TPU a -1 row is an out-of-bounds DMA.
+    assert min(int(m.min()) for m in slot_maps) >= 0
     st = llm.engine.expert_store
     if cache < 16:
         assert st.evictions > 0, "sweep must actually churn the cache"

@@ -22,7 +22,7 @@ def test_ring_ragged_all_to_all_matches_oracle_and_numpy():
         from repro.launch.mesh import make_test_mesh
         from repro.dist.collectives import (
             ring_ragged_all_to_all, ragged_all_to_all_reference,
-            shard_map_compat)
+            shard_map_unchecked)
 
         n = 8
         mesh = make_test_mesh(data=1, model=n)
@@ -45,7 +45,7 @@ def test_ring_ragged_all_to_all_matches_oracle_and_numpy():
                 chunk_rows=R, out_rows=out_rows)
             return a[None], b[None]
 
-        f = jax.jit(shard_map_compat(
+        f = jax.jit(shard_map_unchecked(
             body, mesh,
             in_specs=(P("model"), P("model"), P("model")),
             out_specs=(P("model"), P("model"))))

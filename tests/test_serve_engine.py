@@ -252,6 +252,30 @@ def run_session_workload(model, params, policy, seed=0):
     return eng.stats()
 
 
+@pytest.mark.parametrize("policy", ["gdt", "lru"])
+def test_bulk_victim_ranking_equals_repeated_pick(model_and_params, policy):
+    """``pick_many`` ranks once; it must name the same victims, in the same
+    order, as picking one page at a time (the base-class definition)."""
+    from repro.serve.eviction import EvictionPolicy, make_eviction_policy
+
+    model, params = model_and_params
+    eng = Engine(model, params,
+                 ServeConfig(max_batch=2, page_size=4, hbm_pages=24,
+                             host_pages=64, policy=policy, interval_steps=2))
+    for rid in range(4):
+        eng.add_request(rid, [2, 7, 1, 8, 2, 8, 1, 8][:5 + rid], max_new=12)
+        eng.pause(rid)
+    for rid in (0, 2):
+        eng.resume(rid)
+    for _ in range(5):
+        eng.step()
+    cands = [p for p in eng.pool.pages.values() if p.hbm_slot is not None]
+    policy_obj = make_eviction_policy(policy)
+    for n in (1, 3, len(cands)):
+        assert policy_obj.pick_many(cands, eng, n) == \
+            EvictionPolicy.pick_many(policy_obj, cands, eng, n)
+
+
 def test_gdt_policy_beats_fifo_on_sessions(model_and_params):
     model, params = model_and_params
     s_gdt = run_session_workload(model, params, "gdt")

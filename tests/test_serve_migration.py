@@ -41,8 +41,13 @@ def page_state(pool):
 
 
 def pool_bits(pool):
-    return tuple(np.asarray(a).tobytes()
-                 for a in (pool.k_hbm, pool.v_hbm, pool.k_host, pool.v_host))
+    """HBM pools whole, the host tier slot by slot."""
+    slots = sorted(s for s in range(pool.host_pages) if s in pool.host)
+    k_rows, v_rows = pool.host.read(slots) if slots else ([], [])
+    host = tuple((s, k.tobytes(), v.tobytes())
+                 for s, k, v in zip(slots, k_rows, v_rows))
+    return (np.asarray(pool.k_hbm).tobytes(),
+            np.asarray(pool.v_hbm).tobytes(), host)
 
 
 def make_plan(placement):
@@ -199,3 +204,30 @@ def test_migration_storm_leaves_decode_unchanged():
     stormy, swaps = run(storm=True)
     assert swaps > 0
     assert stormy == calm, "migration storm changed decode output"
+
+
+def test_host_tier_roundtrip_keeps_memory_kinds_and_bits():
+    """HBM -> pinned_host -> HBM: swapped pages land in pinned_host slabs,
+    the HBM pool stays on the device kind, and the bytes come back
+    bitwise."""
+    pool = make_pool(seed=11)
+    ids = [p.page_id for p in pool.pages.values() if p.hbm_slot is not None]
+    before = {pid: (np.asarray(pool.k_hbm[:, pool.pages[pid].hbm_slot]),
+                    np.asarray(pool.v_hbm[:, pool.pages[pid].hbm_slot]))
+              for pid in ids}
+    pool.swap_out_many(ids)
+    assert pool.host.memory_kinds() == {"pinned_host"}
+    for pid in ids:
+        (k,), (v,) = pool.host.read([pool.pages[pid].host_slot])
+        assert np.array_equal(k, before[pid][0])
+        assert np.array_equal(v, before[pid][1])
+    pool.swap_in_many(ids)
+    assert pool.k_hbm.sharding.memory_kind == "device"
+    assert pool.v_hbm.sharding.memory_kind == "device"
+    for pid in ids:
+        slot = pool.pages[pid].hbm_slot
+        assert np.array_equal(np.asarray(pool.k_hbm[:, slot]), before[pid][0])
+        assert np.array_equal(np.asarray(pool.v_hbm[:, slot]), before[pid][1])
+    held = {p.host_slot for p in pool.pages.values()} - {None}
+    assert {s for s in range(pool.host_pages) if s in pool.host} == held, \
+        "a freed host slot must leave the host tier"

@@ -39,11 +39,6 @@ def test_paper_pipeline_end_to_end_on_simulator():
     assert online.bytes_migrated > 0
 
 
-from conftest import has_host_memory
-
-
-@pytest.mark.skipif(not has_host_memory(),
-                    reason="backend lacks pinned_host memory kind")
 def test_training_with_guidance_is_lossless_and_offloads():
     cfg = dataclasses.replace(get_smoke("llama3_2_1b"), remat=False)
     model = build_model(cfg)

@@ -85,11 +85,6 @@ def test_int8_compression_tracks_uncompressed():
     assert li[-1] < li[0]          # and it is actually improving
 
 
-from conftest import has_host_memory
-
-
-@pytest.mark.skipif(not has_host_memory(),
-                    reason="backend lacks pinned_host memory kind")
 def test_gdt_offload_preserves_numerics_and_migrates():
     """Under a tight HBM budget the controller offloads cold groups (adam
     moments mostly); loss trajectory must match the non-tiered run exactly
