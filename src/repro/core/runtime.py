@@ -48,6 +48,7 @@ from .hwmodel import HardwareModel
 from .profiler import IntervalProfile, OnlineProfiler
 from .recommend import TierAssignment, recommend
 from .skirental import MigrationDecision, decide
+from .spans import span
 
 
 # ------------------------------------------------------------------ config
@@ -280,13 +281,15 @@ class GuidanceRuntime:
     # ------------------------------------------------------------------ hooks
     def on_step(self) -> Optional[IntervalEvent]:
         """Call once per runtime step; fires MaybeMigrate at the interval."""
-        if not self.config.enabled:
-            return None
-        self._steps_since_interval += 1
-        if self._steps_since_interval < self.config.interval_steps:
-            return None
-        self._steps_since_interval = 0
-        return self.maybe_migrate()
+        with span("repro.guidance.on_step",
+                  backend=getattr(self.backend, "name", "")):
+            if not self.config.enabled:
+                return None
+            self._steps_since_interval += 1
+            if self._steps_since_interval < self.config.interval_steps:
+                return None
+            self._steps_since_interval = 0
+            return self.maybe_migrate()
 
     # ------------------------------------------------------------ MaybeMigrate
     def maybe_migrate(self) -> Optional[IntervalEvent]:

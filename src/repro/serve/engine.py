@@ -92,6 +92,7 @@ from ..core import TPU_V5E, GuidanceConfig, GuidanceRuntime, HardwareModel, Move
 from ..core.fragmentation import ChunkStats
 from ..core.profiler import ArenaProfile, IntervalProfile
 from ..core.runtime import MigrationPlan
+from ..core.spans import span
 from ..dist.sharding import active_mesh
 from ..models.layers import lm_head, mlp, rmsnorm, rope
 from ..models.moe import apply_dropless_flat, moe_decode, route_tokens
@@ -419,17 +420,10 @@ class Engine:
         if cfg.expert_offchip:
             E = self.model.moe_cfg.padded_experts
             moe_params = params["layers"]["moe"]
-            # Overlap window for the modeled prefetch clock: the attention
-            # weight bytes each layer reads before the next FFN needs its
-            # experts.
-            attn_bytes = (4 * mc.d_model * model.attn_cfg.n_heads
-                          * model.attn_cfg.head_dim
-                          * moe_params["w_gate"].dtype.itemsize)
             self.expert_store = ExpertStore(
                 moe_params, mc.n_layers, E,
                 cfg.expert_cache_size or mc.n_layers * E,
-                double_buffer=cfg.expert_double_buffer, hw=hw,
-                window_bytes=attn_bytes, device=self.device)
+                double_buffer=cfg.expert_double_buffer, device=self.device)
             # The dense expert stacks live in the store's tiers now; serve
             # against a param view holding only the router.  ``params``
             # itself is untouched (replicas re-derive their own stores);
@@ -502,6 +496,7 @@ class Engine:
         self.starved_steps = 0         # request-steps skipped for capacity
         self.truncations = 0           # requests finished early for capacity
         self.saved_prefill_tokens = 0  # prompt tokens served from the cache
+        self.host_syncs = 0            # blocking device->host reads (_read)
         # Per-finish_reason totals (monotonic — surviving pop_finished
         # drains), reported through stats() and serving_summary.
         self.finish_counts: Dict[str, int] = {
@@ -865,8 +860,8 @@ class Engine:
         store = self.expert_store
         E = self.model.moe_cfg.padded_experts
         counts = np.bincount(
-            np.asarray(experts).reshape(len(token_mask), -1)
-            [token_mask].reshape(-1), minlength=E)
+            self._read("route", experts, layer=l).reshape(
+                len(token_mask), -1)[token_mask].reshape(-1), minlength=E)
         slot_map = store.dispatch(l, counts, self.step_count)
         nb = store.take_rental_bytes()
         if nb and self.expert_runtime is not None:
@@ -875,8 +870,10 @@ class Engine:
         # them at row 0: those rows' outputs are zeroed by lane_mask, and a
         # -1 would be an out-of-bounds weight DMA (the TPU halts on it).
         slots = jnp.asarray(np.maximum(slot_map, 0))
-        x = self._t_ffn(x, h2, store.w_gate_cache, store.w_up_cache,
-                        store.w_down_cache, slots, gates, experts, lane_mask)
+        with span("repro.dispatch.ffn", layer=l):
+            x = self._t_ffn(x, h2, store.w_gate_cache, store.w_up_cache,
+                            store.w_down_cache, slots, gates, experts,
+                            lane_mask)
         # Double buffer: stage the next dispatch's predicted working set
         # while this one's grouped GEMM is in flight.  Within a step the
         # prediction is speculative gating — the next layer's router run
@@ -903,8 +900,10 @@ class Engine:
         live = int(np.sum(token_mask))
         if not live:
             return
-        probs = np.asarray(self._t_spec_route(self.params, x, jnp.int32(l)))
-        mass = probs[np.asarray(token_mask)].sum(axis=0)
+        probs = self._read(
+            "spec", self._t_spec_route(self.params, x, jnp.int32(l)),
+            layer=l)
+        mass = probs[token_mask].sum(axis=0)
         order = np.argsort(-mass, kind="stable")
         cap = min(mcfg.padded_experts, live * mcfg.top_k + 2)
         self.expert_store.prefetch(
@@ -912,20 +911,22 @@ class Engine:
 
     def _tiered_decode(self, tokens, page_table, lengths, write_slot,
                        write_off, active, seeds, temperature, top_k, top_p,
-                       use_sampler):
+                       use_sampler, mask):
+        """``mask`` is the host copy of ``active``."""
         x = self._t_embed(self.params, tokens)
         kq, vq = self.pool.k_hbm, self.pool.v_hbm
         lane = active[:, None, None]
-        mask = np.asarray(active)
         for l in range(self.model.cfg.n_layers):
-            x, h2, gates, experts, kq, vq = self._t_decode_attn(
-                self.params, kq, vq, x, jnp.int32(l), page_table, lengths,
-                write_slot, write_off, active)
+            with span("repro.dispatch.attn", layer=l):
+                x, h2, gates, experts, kq, vq = self._t_decode_attn(
+                    self.params, kq, vq, x, jnp.int32(l), page_table,
+                    lengths, write_slot, write_off, active)
             x = self._tiered_ffn(l, x, h2, gates, experts, lane, mask,
                                  wrap_prefetch=False)
         tail = self._t_tail_sampled if use_sampler else self._t_tail_greedy
-        logits, next_tokens = tail(self.params, x, lengths, active, seeds,
-                                   temperature, top_k, top_p)
+        with span("repro.dispatch.tail"):
+            logits, next_tokens = tail(self.params, x, lengths, active,
+                                       seeds, temperature, top_k, top_p)
         # The sampled token IS the next step's layer-0 residual stream
         # (x = embed(token)), so the one dispatch the in-step speculation
         # cannot see — the wrap-around to the next step's first layer —
@@ -938,16 +939,17 @@ class Engine:
         return logits, next_tokens, kq, vq
 
     def _tiered_prefill(self, tokens, page_table, slots, offs, n_real,
-                        start):
+                        start, mask):
+        """``mask`` is the host copy of the prologue's ``valid``."""
         x, positions, lengths, valid = self._t_prefill_prologue(
             self.params, tokens, n_real, start)
         kq, vq = self.pool.k_hbm, self.pool.v_hbm
         lane = valid[None, :, None]
-        mask = np.asarray(valid)
         for l in range(self.model.cfg.n_layers):
-            x, h2, gates, experts, kq, vq = self._t_prefill_attn(
-                self.params, kq, vq, x, jnp.int32(l), page_table, slots,
-                offs, positions, lengths, valid)
+            with span("repro.dispatch.attn", layer=l):
+                x, h2, gates, experts, kq, vq = self._t_prefill_attn(
+                    self.params, kq, vq, x, jnp.int32(l), page_table, slots,
+                    offs, positions, lengths, valid)
             x = self._tiered_ffn(l, x, h2, gates, experts, lane, mask)
         return kq, vq
 
@@ -959,9 +961,19 @@ class Engine:
                 jnp.asarray(slots), jnp.asarray(offs), jnp.int32(n_real),
                 jnp.int32(start))
         if self.expert_store is not None:
-            return self._tiered_prefill(*args)
-        return self._prefill(self.params, self.pool.k_hbm, self.pool.v_hbm,
-                             *args)
+            return self._tiered_prefill(
+                *args, mask=np.arange(len(tokens)) < n_real)
+        with span("repro.dispatch.step"):
+            return self._prefill(self.params, self.pool.k_hbm,
+                                 self.pool.v_hbm, *args)
+
+    def _read(self, what: str, value, **args) -> np.ndarray:
+        """A blocking device->host read on the step path: the host waits
+        here for the device work ``value`` depends on.  Counted in
+        ``host_syncs`` and spanned ``repro.sync.<what>``."""
+        self.host_syncs += 1
+        with span(f"repro.sync.{what}", **args):
+            return np.asarray(value)
 
     # ========================================================== requests
     def add_request(self, request_id: int, prompt: List[int],
@@ -1223,7 +1235,9 @@ class Engine:
         if self.cfg.prefill == "one_shot" and budget.prefill_tokens > 0:
             self._begin_prefill(req)
         else:
-            self._prefill_request(req)
+            with span("repro.engine.prefill", request_id=req.request_id,
+                      tokens=len(req.context) - 1):
+                self._prefill_request(req)
             req.state = "active"
         req.last_scheduled = self.step_count
 
@@ -1274,7 +1288,9 @@ class Engine:
                 break
             n_ingest = len(req.context) - 1
             n = int(min(budget, n_ingest - req.pos))
-            self._prefill_chunk(req, n)
+            with span("repro.engine.prefill", request_id=req.request_id,
+                      tokens=n):
+                self._prefill_chunk(req, n)
             budget -= n
             self.scheduler.on_tokens(req, n, self)
             if req.pos >= n_ingest:
@@ -1313,8 +1329,11 @@ class Engine:
                          and p.page_id not in exclude]
                 victims = self.eviction.pick_many(cands, self, shortfall)
                 if len(victims) < shortfall:
-                    raise MemoryError("no evictable page")  # unreachable:
-            self.pool.exchange(victims, missing)      # chunk pages <= usable
+                    # Unreachable: chunk pages <= usable.
+                    raise MemoryError("no evictable page")
+            with span("repro.kv.exchange",
+                      pages=len(victims) + len(missing)):
+                self.pool.exchange(victims, missing)
             self._note_swap_in(len(missing))
             my_pages = self.pool.request_pages(rid)
         by_index = {p.index_in_seq: p for p in my_pages}
@@ -1414,7 +1433,8 @@ class Engine:
                    if self.pool.pages[pid].hbm_slot is None]
         if missing:
             self._ensure_free_hbm(len(missing), needed=hit_ids)
-            self.pool.swap_in_many(missing)
+            with span("repro.kv.exchange", pages=len(missing)):
+                self.pool.swap_in_many(missing)
             self.swap_in_events += len(missing)
             # A hit on a demoted prefix is a rental payment against the
             # PREFIX controller's ledger (it made the demotion call).
@@ -1552,7 +1572,9 @@ class Engine:
             if len(victims) < shortfall:
                 raise MemoryError("no evictable page")   # unreachable under
         if victims or missing:                           # scheduler budgets
-            self.pool.exchange(victims, missing)
+            with span("repro.kv.exchange",
+                      pages=len(victims) + len(missing)):
+                self.pool.exchange(victims, missing)
             self._note_swap_in(len(missing))
         for r in reqs:
             idx = r.pos // P
@@ -1568,15 +1590,18 @@ class Engine:
                  if p.hbm_slot is not None and p.page_id not in exclude]
         victims = self.eviction.pick_many(cands, self, shortfall)
         if len(victims) < shortfall:
-            raise MemoryError("no evictable page")   # unreachable under
-        self.pool.swap_out_many(victims)             # scheduler budgets
+            # Unreachable under scheduler budgets.
+            raise MemoryError("no evictable page")
+        with span("repro.kv.exchange", pages=len(victims)):
+            self.pool.swap_out_many(victims)
 
     # ============================================================ stepping
     def _decode_one(self, req: Request, token: int) -> int:
         """Single-request decode (the chunked-prefill oracle path)."""
         self._prepare_batch([req])
         self.prefill_dispatches += 1
-        return self._run_batch([(req, token)])[0]
+        with span("repro.engine.decode", rows=1):
+            return self._run_batch([(req, token)])[0]
 
     def _schedule(self) -> List[Request]:
         """Pack active requests (policy decode order) under the HBM-slot
@@ -1613,33 +1638,37 @@ class Engine:
         """One engine step: admit, advance interleaved prefills, schedule,
         decode, bookkeeping."""
         self.step_count += 1
-        self.scheduler.on_step(self)
-        self._admit_waiting()
-        self._advance_prefills()
-        sched = self._schedule()
-        if not sched and any(r.state == "active"
-                             for r in self.requests.values()):
-            self._reclaim_logical_pages()
-            sched = self._schedule()
-        out: Dict[int, int] = {}
-        if sched:
-            pairs = []
-            for r in sched:
-                nxt = (r.generated[-1] if r.generated
-                       else (r.tokens[-1] if r.tokens else 1))
-                pairs.append((r, nxt))
-            self._prepare_batch(sched)
-            toks = self._run_batch(pairs)
-            for r, t in zip(sched, toks):
-                r.generated.append(int(t))
-                self.scheduler.on_tokens(r, 1, self)
-                out[r.request_id] = int(t)
-                if int(t) in r.params.stop_token_ids:
-                    self._finish(r, reason="stop")
-                elif len(r.generated) >= r.max_new:
-                    self._finish(r, reason="length")
-        self._tick_controllers()
-        return out
+        with span("repro.engine.step", step=self.step_count):
+            self.scheduler.on_step(self)
+            with span("repro.engine.admit"):
+                self._admit_waiting()
+                self._advance_prefills()
+            with span("repro.engine.schedule"):
+                sched = self._schedule()
+                if not sched and any(r.state == "active"
+                                     for r in self.requests.values()):
+                    self._reclaim_logical_pages()
+                    sched = self._schedule()
+            out: Dict[int, int] = {}
+            if sched:
+                pairs = []
+                for r in sched:
+                    nxt = (r.generated[-1] if r.generated
+                           else (r.tokens[-1] if r.tokens else 1))
+                    pairs.append((r, nxt))
+                self._prepare_batch(sched)
+                with span("repro.engine.decode", rows=len(pairs)):
+                    toks = self._run_batch(pairs)
+                for r, t in zip(sched, toks):
+                    r.generated.append(int(t))
+                    self.scheduler.on_tokens(r, 1, self)
+                    out[r.request_id] = int(t)
+                    if int(t) in r.params.stop_token_ids:
+                        self._finish(r, reason="stop")
+                    elif len(r.generated) >= r.max_new:
+                        self._finish(r, reason="length")
+            self._tick_controllers()
+            return out
 
     def _tick_controllers(self) -> None:
         """MaybeMigrate for every guidance controller, in a FIXED order:
@@ -1728,17 +1757,18 @@ class Engine:
                 jnp.asarray(top_p))
         if self.expert_store is not None:
             logits, toks, nk, nv = self._tiered_decode(
-                *args, use_sampler=not greedy)
+                *args, use_sampler=not greedy, mask=active)
         else:
             decode = self._decode_greedy if greedy else self._decode_sampled
-            logits, toks, nk, nv = decode(
-                self.params, self.pool.k_hbm, self.pool.v_hbm, *args)
+            with span("repro.dispatch.step"):
+                logits, toks, nk, nv = decode(
+                    self.params, self.pool.k_hbm, self.pool.v_hbm, *args)
         self.pool.k_hbm, self.pool.v_hbm = nk, nv
         if self.cfg.keep_logits:
-            logits_np = np.asarray(logits)
+            logits_np = self._read("tokens", logits)
             for i, (req, _) in enumerate(pairs):
                 self.last_logits[req.request_id] = logits_np[i]
-        toks = np.asarray(toks)
+        toks = self._read("tokens", toks)
         return [int(toks[i]) for i in range(len(pairs))]
 
     # --------------------------------------------------------- telemetry
@@ -1771,6 +1801,7 @@ class Engine:
             **expert,
             "saved_prefill_tokens": self.saved_prefill_tokens,
             "steps": self.step_count,
+            "host_syncs": self.host_syncs,
             "swap_ins": self.pool.swaps_in,
             "swap_outs": self.pool.swaps_out,
             "bytes_moved": self.pool.bytes_moved,

@@ -44,14 +44,11 @@ program differently).  A working set that cannot fit raises
 ``ExpertCacheMissError`` naming ``ServeConfig.expert_cache_size`` — never
 a silent wrong-weight dispatch.
 
-Modeled decode time: the engine cannot observe real PCIe overlap on a CPU
-test host, so the store accumulates a deterministic modeled clock in the
-``StepCostModel`` idiom (deliberately round constants): each dispatch adds
-its weight-read time at fast-tier bandwidth to ``m_compute_s``; a blocking
-demand fetch adds transfer time at slow-tier bandwidth plus a fixed launch
-latency to ``m_blocked_s``; a committed prefetch adds only the part of
-that cost the overlap window (the previous dispatch's compute plus two
-dispatch launches) could not hide.
+The store keeps counts, not times.  What its work costs is read from a
+profiler trace of a chip run: ``dispatch`` and ``prefetch`` run under the
+spans ``repro.expert.dispatch`` and ``repro.expert.prefetch``, and each
+host-to-HBM transfer and its cache row writes under
+``repro.expert.transfer`` (``core/spans.py``).
 """
 
 from __future__ import annotations
@@ -65,14 +62,9 @@ import numpy as np
 
 from ..core import MigrationPlan, MoveStats
 from ..core.fragmentation import ChunkStats
-from ..core.hwmodel import TPU_V5E, HardwareModel
 from ..core.profiler import ArenaProfile, IntervalProfile
+from ..core.spans import span
 from .tier_io import HostTier, copy_rows
-
-# Modeled-time constants (StepCostModel idiom: deliberately round numbers
-# so overlap effects are deterministic on any host).
-FETCH_LATENCY_S = 10e-6       # per batched host->HBM staged transfer
-DISPATCH_OVERHEAD_S = 20e-6   # per jitted dispatch launch
 
 _WEIGHTS = ("w_gate", "w_up", "w_down")
 
@@ -107,7 +99,6 @@ class _PendingFetch:
     experts: List[int]
     slots: List[int]
     staged: tuple                 # (staged slabs, locations): HostTier.fetch
-    hide_s: float                 # modeled overlap window at issue time
 
 
 class ExpertStore:
@@ -121,8 +112,7 @@ class ExpertStore:
 
     def __init__(self, moe_params: Mapping[str, jax.Array], n_layers: int,
                  n_experts: int, cache_slots: int, *,
-                 double_buffer: bool = True, hw: HardwareModel = TPU_V5E,
-                 window_bytes: int = 0, device=None):
+                 double_buffer: bool = True, device=None):
         if cache_slots <= 0:
             raise ValueError(
                 f"ExpertStore needs at least one cache slot, got "
@@ -132,8 +122,6 @@ class ExpertStore:
         self.n_experts = E
         self.cache_slots = min(cache_slots, L * E)
         self.double_buffer = double_buffer
-        self.hw = hw
-        self.window_bytes = int(window_bytes)
         self.device = device if device is not None else jax.devices()[0]
         weights = [moe_params[name] for name in _WEIGHTS]
         for name, w in zip(_WEIGHTS, weights):
@@ -166,7 +154,6 @@ class ExpertStore:
         self._last_prefetched: Dict[int, FrozenSet[int]] = {}
         self.dispatching: Dict[int, FrozenSet[int]] = {}
         self.prev_needed: Dict[int, List[int]] = {}
-        self._last_window_s = 0.0
         self._rental_bytes = 0
         self.reset_counters()
 
@@ -196,8 +183,6 @@ class ExpertStore:
         self.evictions = 0
         self.bytes_fetched = 0
         self.transfer_events = 0
-        self.m_compute_s = 0.0
-        self.m_blocked_s = 0.0
 
     def take_rental_bytes(self) -> int:
         """Drain bytes demand-fetched since the last drain — the engine
@@ -211,16 +196,18 @@ class ExpertStore:
         """ONE batched host->device transfer per weight array of the layer
         slabs holding the blocks.  Returns ``HostTier.fetch``'s staging;
         the cache writes happen at `_install`."""
-        staged = self.host.fetch([self.chunk_id(l, e) for l, e in pairs])
+        with span("repro.expert.transfer", blocks=len(pairs)):
+            staged = self.host.fetch([self.chunk_id(l, e) for l, e in pairs])
         self.transfer_events += len(_WEIGHTS)
         self.bytes_fetched += self.block_bytes * len(pairs)
         return staged
 
     def _install(self, pairs: Sequence[Tuple[int, int]], slots: Sequence[int],
                  staged, step: int, *, prefetched: bool) -> None:
-        self.w_gate_cache, self.w_up_cache, self.w_down_cache = copy_rows(
-            (self.w_gate_cache, self.w_up_cache, self.w_down_cache),
-            slots, *staged, axis=0)
+        with span("repro.expert.transfer", blocks=len(pairs)):
+            self.w_gate_cache, self.w_up_cache, self.w_down_cache = (
+                copy_rows((self.w_gate_cache, self.w_up_cache,
+                           self.w_down_cache), slots, *staged, axis=0))
         for (l, e), s in zip(pairs, slots):
             b = self.blocks[(l, e)]
             b.slot = int(s)
@@ -271,9 +258,6 @@ class ExpertStore:
         self._reserved.difference_update(pend.slots)
         pairs = [(layer, e) for e in pend.experts]
         self._install(pairs, pend.slots, pend.staged, step, prefetched=True)
-        cost = (len(pairs) * self.block_bytes
-                / (self.hw.slow.read_bw_GBps * 1e9) + FETCH_LATENCY_S)
-        self.m_blocked_s += max(0.0, cost - pend.hide_s)
         self._last_prefetched[layer] = frozenset(pend.experts)
 
     def prefetch(self, layer: int, step: int,
@@ -288,47 +272,48 @@ class ExpertStore:
         step's first dispatch, whose input token does not exist yet) the
         store falls back to recency + the guidance profile's hottest
         blocks.  Returns the number of blocks put in flight."""
-        if not self.double_buffer or layer in self._pending:
-            return 0
-        if predicted is not None:
-            want_set = [int(e) for e in predicted]
-            targets = [e for e in want_set if not self.is_resident(layer, e)]
-        else:
-            prev = self.prev_needed.get(layer)
-            if not prev:
-                return 0                  # never dispatched: no prediction
-            want = len(prev)
-            # Recency first (last dispatch of this layer), then the layer's
-            # hottest blocks by profile access count — guided prefetch.
-            ranked = sorted(
-                (self.blocks[(layer, e)] for e in range(self.n_experts)
-                 if e not in prev),
-                key=lambda b: (-b.accesses, b.expert))
-            want_set = list(prev)
-            targets = [e for e in prev if not self.is_resident(layer, e)]
-            targets += [b.expert for b in ranked
-                        if b.slot is None][:max(want - len(targets), 0)]
-            targets = targets[:want]
-        if not targets:
-            return 0
-        # A prefetch must not evict what it is predicting around: protect
-        # the current dispatch's pins AND the predicted set's already-
-        # resident members (evicting those would turn forecast hits into
-        # the very demand misses the buffer exists to avoid).
-        protect = self._pinned | frozenset(
-            self.blocks[(layer, e)].slot for e in want_set
-            if self.blocks[(layer, e)].slot is not None)
-        slots = self._acquire_slots(len(targets), protect)
-        if len(slots) < len(targets):
-            self.dropped_prefetches += len(targets) - len(slots)
-            targets = targets[:len(slots)]
-        if not targets:
-            return 0
-        staged = self._transfer([(layer, e) for e in targets])
-        self._reserved.update(slots)
-        self._pending[layer] = _PendingFetch(
-            layer, targets, slots, staged, self._last_window_s)
-        return len(targets)
+        with span("repro.expert.prefetch", layer=layer):
+            if not self.double_buffer or layer in self._pending:
+                return 0
+            if predicted is not None:
+                want_set = [int(e) for e in predicted]
+                targets = [e for e in want_set
+                           if not self.is_resident(layer, e)]
+            else:
+                prev = self.prev_needed.get(layer)
+                if not prev:
+                    return 0                  # never dispatched: no prediction
+                want = len(prev)
+                # Recency first (last dispatch of this layer), then the layer's
+                # hottest blocks by profile access count — guided prefetch.
+                ranked = sorted(
+                    (self.blocks[(layer, e)] for e in range(self.n_experts)
+                     if e not in prev),
+                    key=lambda b: (-b.accesses, b.expert))
+                want_set = list(prev)
+                targets = [e for e in prev if not self.is_resident(layer, e)]
+                targets += [b.expert for b in ranked
+                            if b.slot is None][:max(want - len(targets), 0)]
+                targets = targets[:want]
+            if not targets:
+                return 0
+            # A prefetch must not evict what it is predicting around: protect
+            # the current dispatch's pins AND the predicted set's already-
+            # resident members (evicting those would turn forecast hits into
+            # the very demand misses the buffer exists to avoid).
+            protect = self._pinned | frozenset(
+                self.blocks[(layer, e)].slot for e in want_set
+                if self.blocks[(layer, e)].slot is not None)
+            slots = self._acquire_slots(len(targets), protect)
+            if len(slots) < len(targets):
+                self.dropped_prefetches += len(targets) - len(slots)
+                targets = targets[:len(slots)]
+            if not targets:
+                return 0
+            staged = self._transfer([(layer, e) for e in targets])
+            self._reserved.update(slots)
+            self._pending[layer] = _PendingFetch(layer, targets, slots, staged)
+            return len(targets)
 
     # ------------------------------------------------------------ dispatch
     def dispatch(self, layer: int, counts, step: int) -> np.ndarray:
@@ -342,57 +327,48 @@ class ExpertStore:
         reads must never lose its slot mid-dispatch), demand-fetch the
         misses in one batched transfer, then account.
         """
-        counts = np.asarray(counts)
-        assert counts.shape == (self.n_experts,), counts.shape
-        needed = [int(e) for e in np.nonzero(counts)[0]]
-        self._commit_pending(layer, step)
+        with span("repro.expert.dispatch", layer=layer):
+            counts = np.asarray(counts)
+            assert counts.shape == (self.n_experts,), counts.shape
+            needed = [int(e) for e in np.nonzero(counts)[0]]
+            self._commit_pending(layer, step)
 
-        committed = self._last_prefetched.pop(layer, frozenset())
-        self.prefetch_hits += len(committed.intersection(needed))
+            committed = self._last_prefetched.pop(layer, frozenset())
+            self.prefetch_hits += len(committed.intersection(needed))
 
-        resident_slots = frozenset(
-            self.blocks[(layer, e)].slot for e in needed
-            if self.blocks[(layer, e)].slot is not None)
-        missing = [e for e in needed
-                   if self.blocks[(layer, e)].slot is None]
-        if missing:
-            slots = self._acquire_slots(len(missing), resident_slots)
-            if len(slots) < len(missing):
-                for s in slots:           # undo: nothing was transferred
-                    self._free.append(s)
-                have = len(needed) - len(missing) + len(slots)
-                raise ExpertCacheMissError(
-                    f"expert cache cannot hold layer {layer}'s dispatch "
-                    f"working set: {len(needed)} distinct experts routed "
-                    f"but only {have} fit a {self.cache_slots}-slot cache "
-                    f"({len(self._reserved)} reserved by in-flight "
-                    f"prefetch); raise ServeConfig.expert_cache_size or "
-                    f"disable ServeConfig.expert_offchip")
-            staged = self._transfer([(layer, e) for e in missing])
-            self._install([(layer, e) for e in missing], slots, staged,
-                          step, prefetched=False)
-            self.m_blocked_s += (
-                len(missing) * self.block_bytes
-                / (self.hw.slow.read_bw_GBps * 1e9) + FETCH_LATENCY_S)
+            resident_slots = frozenset(
+                self.blocks[(layer, e)].slot for e in needed
+                if self.blocks[(layer, e)].slot is not None)
+            missing = [e for e in needed
+                       if self.blocks[(layer, e)].slot is None]
+            if missing:
+                slots = self._acquire_slots(len(missing), resident_slots)
+                if len(slots) < len(missing):
+                    for s in slots:           # undo: nothing was transferred
+                        self._free.append(s)
+                    have = len(needed) - len(missing) + len(slots)
+                    raise ExpertCacheMissError(
+                        f"expert cache cannot hold layer {layer}'s dispatch "
+                        f"working set: {len(needed)} distinct experts routed "
+                        f"but only {have} fit a {self.cache_slots}-slot cache "
+                        f"({len(self._reserved)} reserved by in-flight "
+                        f"prefetch); raise ServeConfig.expert_cache_size or "
+                        f"disable ServeConfig.expert_offchip")
+                staged = self._transfer([(layer, e) for e in missing])
+                self._install([(layer, e) for e in missing], slots, staged,
+                              step, prefetched=False)
 
-        slot_map = np.full(self.n_experts, -1, dtype=np.int32)
-        for e in needed:
-            b = self.blocks[(layer, e)]
-            b.accesses += float(counts[e])
-            b.last_used = step
-            slot_map[e] = b.slot
-        self._pinned = frozenset(
-            int(slot_map[e]) for e in needed)
-        self.dispatching[layer] = frozenset(needed)
-        self.prev_needed[layer] = needed
-
-        t_comp = ((self.window_bytes + len(needed) * self.block_bytes)
-                  / (self.hw.fast.read_bw_GBps * 1e9))
-        self.m_compute_s += t_comp + DISPATCH_OVERHEAD_S
-        # Overlap a prefetch issued *now* can hide: this dispatch's weight
-        # reads plus the two jitted launches before the next FFN needs it.
-        self._last_window_s = t_comp + 2 * DISPATCH_OVERHEAD_S
-        return slot_map
+            slot_map = np.full(self.n_experts, -1, dtype=np.int32)
+            for e in needed:
+                b = self.blocks[(layer, e)]
+                b.accesses += float(counts[e])
+                b.last_used = step
+                slot_map[e] = b.slot
+            self._pinned = frozenset(
+                int(slot_map[e]) for e in needed)
+            self.dispatching[layer] = frozenset(needed)
+            self.prev_needed[layer] = needed
+            return slot_map
 
     # ----------------------------------------------------- controller face
     def drop_many(self, pairs: Sequence[Tuple[int, int]]
@@ -416,14 +392,15 @@ class ExpertStore:
         """Promote blocks into *free* slots only (one batched transfer);
         the controller never evicts on promote — refusals are reported
         back so the plan reflects reality."""
-        want = [(l, e) for l, e in pairs if not self.is_resident(l, e)]
-        room = len(self._free)
-        take, refused = want[:room], want[room:]
-        if take:
-            slots = [self._free.pop() for _ in take]
-            staged = self._transfer(take)
-            self._install(take, slots, staged, step, prefetched=True)
-        return take, refused
+        with span("repro.expert.prefetch"):
+            want = [(l, e) for l, e in pairs if not self.is_resident(l, e)]
+            room = len(self._free)
+            take, refused = want[:room], want[room:]
+            if take:
+                slots = [self._free.pop() for _ in take]
+                staged = self._transfer(take)
+                self._install(take, slots, staged, step, prefetched=True)
+            return take, refused
 
     def reweight(self, decay: float) -> None:
         for b in self.blocks.values():
