@@ -411,7 +411,7 @@ class Engine:
             self.prefix_cache = PrefixCache(
                 self.pool, cfg.page_size, min_pages=cfg.min_prefix_pages)
         # Guided expert-weight tiering: the ExpertStore exists whenever
-        # expert weights are off-chip (LRU demand management works under
+        # expert weights are off-chip (its demand management works under
         # any policy); the third GuidanceRuntime rides only under gdt,
         # like the KV and prefix controllers.
         self.expert_store: Optional[ExpertStore] = None
@@ -847,7 +847,7 @@ class Engine:
                     wrap_prefetch=True):
         """Host half of one tiered layer: sync the routed picks, make them
         resident (commit the in-flight prefetch / demand-fetch misses /
-        LRU-evict), dispatch the FFN against cache slots, then put the
+        evict), dispatch the FFN against cache slots, then put the
         NEXT layer's predicted experts in flight while this FFN runs.
 
         ``token_mask`` (host bool, one per routed token) excludes padded
@@ -1795,6 +1795,8 @@ class Engine:
             "expert_evictions": es.evictions,
             "expert_bytes_fetched": es.bytes_fetched,
             "expert_transfer_events": es.transfer_events,
+            "expert_needed_blocks": es.needed_blocks,
+            "expert_resident_hits": es.resident_hits,
         } if es is not None else {}
         return {
             **prefix,

@@ -15,7 +15,13 @@ Three layers live here:
   layers' slabs holding the blocks, then in-place cache row writes.
   Expert weights never change, so *demotion is metadata-only* — the slot
   is released and the host copy stays authoritative; ``bytes_demoted``
-  counts the logical bytes leaving the fast tier.
+  counts the logical bytes leaving the fast tier.  A full cache gives up
+  cold blocks first (not named in their layer's most recent dispatch),
+  least recently used first, then hot blocks by their layer's next
+  dispatch in the fixed layer cycle, farthest first: Belady's rule on
+  the one future the store knows, so a cyclic working set larger than
+  the cache keeps whole layers resident instead of evicting each block
+  just before its next use.
 
 * **Double-buffered prefetch** — while layer L's grouped GEMM dispatch is
   in flight, the predicted working set for the next layer is staged onto
@@ -183,6 +189,8 @@ class ExpertStore:
         self.evictions = 0
         self.bytes_fetched = 0
         self.transfer_events = 0
+        self.needed_blocks = 0        # blocks dispatches read
+        self.resident_hits = 0        # ... already resident beforehand
 
     def take_rental_bytes(self) -> int:
         """Drain bytes demand-fetched since the last drain — the engine
@@ -230,23 +238,38 @@ class ExpertStore:
         self.evictions += 1
         return s
 
-    def _evictable(self, protect: FrozenSet[int]) -> List[ExpertBlock]:
+    def _evictable(self, protect: FrozenSet[int],
+                   layer: int) -> List[ExpertBlock]:
+        """Unprotected residents in victim order, for a fetch serving
+        ``layer``.  Layers dispatch in a fixed cycle, so a block named in
+        its layer's most recent dispatch (hot) is next used at that
+        layer's next dispatch: Belady's rule evicts the farthest first.
+        Blocks that dispatch did not name (cold) have no known next use
+        and go before any hot block, least recently used first."""
         out = [b for b in self.blocks.values()
                if b.slot is not None and b.slot not in protect
                and b.slot not in self._reserved]
-        # LRU with a total deterministic order.
-        out.sort(key=lambda b: (b.last_used, b.layer, b.expert))
+        L = self.n_layers
+
+        def order(b: ExpertBlock):
+            if b.expert not in self.dispatching.get(b.layer, ()):
+                return (0, b.last_used, b.layer, b.expert)
+            return (1, -((b.layer - layer) % L), b.layer, b.expert)
+
+        out.sort(key=order)
         return out
 
-    def _acquire_slots(self, n: int, protect: FrozenSet[int]) -> List[int]:
-        """Take ``n`` cache slots: free list first, then LRU eviction of
-        unprotected residents.  Returns fewer than ``n`` when the cache is
-        too pinned — callers decide whether that is an error."""
+    def _acquire_slots(self, n: int, protect: FrozenSet[int],
+                       layer: int) -> List[int]:
+        """Take ``n`` cache slots for ``layer``: free list first, then
+        eviction of unprotected residents in ``_evictable``'s order.
+        Returns fewer than ``n`` when the cache is too pinned — callers
+        decide whether that is an error."""
         slots: List[int] = []
         while self._free and len(slots) < n:
             slots.append(self._free.pop())
         if len(slots) < n:
-            for b in self._evictable(protect)[:n - len(slots)]:
+            for b in self._evictable(protect, layer)[:n - len(slots)]:
                 slots.append(self._evict(b))
         return slots
 
@@ -304,7 +327,7 @@ class ExpertStore:
             protect = self._pinned | frozenset(
                 self.blocks[(layer, e)].slot for e in want_set
                 if self.blocks[(layer, e)].slot is not None)
-            slots = self._acquire_slots(len(targets), protect)
+            slots = self._acquire_slots(len(targets), protect, layer)
             if len(slots) < len(targets):
                 self.dropped_prefetches += len(targets) - len(slots)
                 targets = targets[:len(slots)]
@@ -331,6 +354,9 @@ class ExpertStore:
             counts = np.asarray(counts)
             assert counts.shape == (self.n_experts,), counts.shape
             needed = [int(e) for e in np.nonzero(counts)[0]]
+            self.needed_blocks += len(needed)
+            self.resident_hits += sum(
+                1 for e in needed if self.blocks[(layer, e)].slot is not None)
             self._commit_pending(layer, step)
 
             committed = self._last_prefetched.pop(layer, frozenset())
@@ -342,7 +368,8 @@ class ExpertStore:
             missing = [e for e in needed
                        if self.blocks[(layer, e)].slot is None]
             if missing:
-                slots = self._acquire_slots(len(missing), resident_slots)
+                slots = self._acquire_slots(len(missing), resident_slots,
+                                            layer)
                 if len(slots) < len(missing):
                     for s in slots:           # undo: nothing was transferred
                         self._free.append(s)

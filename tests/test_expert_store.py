@@ -29,16 +29,17 @@ from repro.serve import (
 L, E, D, F = 2, 4, 4, 4
 
 
-def make_store(cache_slots, double_buffer=False):
+def make_store(cache_slots, double_buffer=False, n_layers=L,
+               store=ExpertStore):
     key = jax.random.PRNGKey(0)
     ks = jax.random.split(key, 3)
     moe_params = {
-        "w_gate": jax.random.normal(ks[0], (L, E, D, F), jnp.float32),
-        "w_up": jax.random.normal(ks[1], (L, E, D, F), jnp.float32),
-        "w_down": jax.random.normal(ks[2], (L, E, F, D), jnp.float32),
+        "w_gate": jax.random.normal(ks[0], (n_layers, E, D, F), jnp.float32),
+        "w_up": jax.random.normal(ks[1], (n_layers, E, D, F), jnp.float32),
+        "w_down": jax.random.normal(ks[2], (n_layers, E, F, D), jnp.float32),
     }
-    return moe_params, ExpertStore(moe_params, L, E, cache_slots,
-                                   double_buffer=double_buffer)
+    return moe_params, store(moe_params, n_layers, E, cache_slots,
+                             double_buffer=double_buffer)
 
 
 def counts(*experts):
@@ -156,6 +157,112 @@ def test_demotion_is_metadata_only_and_refetch_is_bitwise():
     again = np.asarray(st.w_down_cache)[m1[0]]
     assert np.array_equal(first, again)
     assert np.array_equal(again, np.asarray(params["w_down"])[0, 0])
+
+
+class LRUStore(ExpertStore):
+    """The store with the plain least-recently-used victim order, the
+    baseline the next-use order is measured against."""
+
+    def _evictable(self, protect, layer):
+        out = [b for b in self.blocks.values()
+               if b.slot is not None and b.slot not in protect
+               and b.slot not in self._reserved]
+        out.sort(key=lambda b: (b.last_used, b.layer, b.expert))
+        return out
+
+
+def decode_step(st, step, route):
+    """One decode step in the tiered pipeline's order: dispatch layer l,
+    then prefetch layer l + 1 with its routed set as the forecast; the
+    wrap-around prefetch of layer 0 last.  Returns the blocks fetched."""
+    before = st.prefetch_fetches + st.demand_fetches
+    n = st.n_layers
+    for l in range(n):
+        st.dispatch(l, counts(*route(step, l)), step=step)
+        nl = (l + 1) % n
+        if nl:
+            st.prefetch(nl, step, predicted=route(step, nl))
+    st.prefetch(0, step, predicted=route(step + 1, 0))
+    return st.prefetch_fetches + st.demand_fetches - before
+
+
+def test_cyclic_scan_keeps_whole_layers_resident_across_steps():
+    """Every layer routes all E experts and the cache holds half the
+    blocks: LRU evicts each block just before its next use, the next-use
+    order streams about half the layers and keeps the rest whole."""
+    n, slots = 8, 16
+    _, st = make_store(slots, double_buffer=True, n_layers=n)
+    decode_step(st, 0, lambda s, l: range(E))              # warm-up
+    for step in range(1, 6):
+        st.reset_counters()
+        fetched = decode_step(st, step, lambda s, l: range(E))
+        assert fetched <= n * E - slots + 2 * E, step
+        assert st.demand_fetches == 0
+        assert 0 < st.resident_hits <= st.needed_blocks == n * E
+        for l in range(n):
+            held = sum(st.is_resident(l, e) for e in range(E))
+            assert held in (0, E), (step, l, held)
+    _, lru = make_store(slots, double_buffer=True, n_layers=n, store=LRUStore)
+    decode_step(lru, 0, lambda s, l: range(E))
+    lru.reset_counters()
+    assert decode_step(lru, 1, lambda s, l: range(E)) == n * E
+    assert lru.resident_hits == 0
+
+
+def test_skewed_routing_that_fits_fetches_nothing_after_warm_up():
+    """Every layer reuses experts 0 and 1, and in the first four steps two
+    layers a step also route one more: the hot set fits the cache, the
+    next-use order fetches and evicts what LRU does, and once the extras
+    stop nothing moves."""
+    n, slots = 8, 20
+    rng = np.random.default_rng(4)
+    extra = {(s, int(l)): int(rng.integers(2, E))
+             for s in range(4) for l in rng.choice(n, 2, replace=False)}
+
+    def route(step, l):
+        return [0, 1] + ([extra[(step, l)]] if (step, l) in extra else [])
+
+    runs = []
+    for cls in (ExpertStore, LRUStore):
+        _, st = make_store(slots, double_buffer=True, n_layers=n, store=cls)
+        fetched = [decode_step(st, step, route) for step in range(9)]
+        assert fetched[4:] == [0] * 5, fetched
+        runs.append((fetched, st.evictions))
+    assert runs[0] == runs[1]
+    assert runs[0][1] > 0
+
+
+def test_cold_block_is_evicted_before_any_hot_block():
+    _, st = make_store(3)
+    st.dispatch(0, counts(0), step=1)          # (0,0) hot, oldest
+    st.dispatch(1, counts(0, 1), step=2)
+    st.dispatch(1, counts(1), step=3)          # (1,0) cold, newer
+    st.dispatch(1, counts(1, 2), step=4)       # needs a slot
+    assert st.is_resident(0, 0) and not st.is_resident(1, 0)
+    assert st.evictions == 1
+
+
+def test_hot_blocks_go_farthest_next_use_first():
+    _, st = make_store(4, n_layers=4)
+    for l in range(4):
+        st.dispatch(l, counts(0), step=1)      # every block hot, cache full
+    # Serving layer 1 (expert 1): layer 0's next dispatch is the farthest
+    # in the cycle 1, 2, 3, 0, though its block is the least recent.
+    st.dispatch(1, counts(0, 1), step=2)
+    assert not st.is_resident(0, 0)
+    assert all(st.is_resident(l, 0) for l in (1, 2, 3))
+
+
+def test_resident_hits_count_neither_prefetched_nor_demanded_blocks():
+    _, st = make_store(4, double_buffer=True)
+    st.dispatch(0, counts(0, 1), step=1)       # two demand fetches
+    st.prefetch(1, step=1, predicted=[2])
+    st.dispatch(1, counts(2), step=1)          # committed prefetch
+    st.dispatch(0, counts(0, 1), step=2)       # both resident already
+    assert st.needed_blocks == 5
+    assert st.resident_hits == 2
+    st.reset_counters()
+    assert st.needed_blocks == st.resident_hits == 0
 
 
 # ============================================== engine-level equivalence
@@ -313,3 +420,5 @@ def test_serving_summary_reports_expert_counters(moe_model):
     assert stats["expert_cache_slots"] == 8
     assert stats["expert_demand_fetches"] > 0
     assert stats["expert_bytes_fetched"] > 0
+    assert 0 <= stats["expert_resident_hits"] \
+        <= stats["expert_needed_blocks"] > 0
