@@ -113,7 +113,7 @@ def prefill_throughput(mode: str, prompt_len: int):
     # Block on the KV pools: the one-shot path is a single async jitted
     # dispatch, so without a sync the timer would measure dispatch
     # overhead, not the ingest itself (chunked syncs every token anyway).
-    jax.block_until_ready((eng.pool.k_hbm, eng.pool.v_hbm))
+    jax.block_until_ready(eng.pool.hbm)
     t_ingest = time.perf_counter() - t0
     handle.next_delta()                   # streaming first token
     ttft = time.perf_counter() - t0
@@ -199,7 +199,7 @@ def prefix_share_ttft(share: float, prompt_len: int, page_size: int = 4):
         handle = llm.submit(prompt_with_tail(seed),
                             SamplingParams(max_tokens=2),
                             request_id=2 + trial)
-        jax.block_until_ready((eng.pool.k_hbm, eng.pool.v_hbm))
+        jax.block_until_ready(eng.pool.hbm)
         handle.next_delta()
         ttft = min(ttft, time.perf_counter() - t0)
         while llm.is_live(2 + trial):

@@ -231,15 +231,16 @@ def kernel_check(engine, sizes: Sizes, tiered: bool) -> Dict[str, bool]:
     S = 1 << (sizes.prompt_max - 1).bit_length()
     sd = jax.ShapeDtypeStruct
     i32, f32 = jnp.int32, jnp.float32
-    k, v, p = engine.pool.k_hbm, engine.pool.v_hbm, engine.params
+    pools, p = engine.pool.hbm, engine.params
+    dt = pools[0].dtype
     if not tiered:
         progs = {
             "decode": engine._decode_greedy.lower(
-                p, k, v, sd((B, 1), i32), sd((B, MP), i32), sd((B,), i32),
+                p, pools, sd((B, 1), i32), sd((B, MP), i32), sd((B,), i32),
                 sd((B,), i32), sd((B,), i32), sd((B,), bool), sd((B,), i32),
                 sd((B,), f32), sd((B,), i32), sd((B,), f32)),
             "prefill": engine._prefill.lower(
-                p, k, v, sd((S,), i32), sd((MP,), i32), sd((S,), i32),
+                p, pools, sd((S,), i32), sd((MP,), i32), sd((S,), i32),
                 sd((S,), i32), sd((), i32), sd((), i32)),
         }
     else:
@@ -247,15 +248,15 @@ def kernel_check(engine, sizes: Sizes, tiered: bool) -> Dict[str, bool]:
         st = engine.expert_store
         progs = {
             "decode_attn": engine._t_decode_attn.lower(
-                p, k, v, sd((B, 1, d), k.dtype), sd((), i32),
+                p, pools, sd((B, 1, d), dt), sd((), i32),
                 sd((B, MP), i32), sd((B,), i32), sd((B,), i32),
                 sd((B,), i32), sd((B,), bool)),
             "prefill_attn": engine._t_prefill_attn.lower(
-                p, k, v, sd((1, S, d), k.dtype), sd((), i32),
+                p, pools, sd((1, S, d), dt), sd((), i32),
                 sd((MP,), i32), sd((S,), i32), sd((S,), i32), sd((S,), i32),
                 sd((S,), i32), sd((S,), bool)),
             "ffn": engine._t_ffn.lower(
-                sd((B, 1, d), k.dtype), sd((B, 1, d), k.dtype),
+                sd((B, 1, d), dt), sd((B, 1, d), dt),
                 st.w_gate_cache, st.w_up_cache, st.w_down_cache,
                 sd((st.n_experts,), i32),
                 sd((B, engine.model.moe_cfg.top_k), f32),
@@ -314,7 +315,8 @@ def phase_a(model, params, prompts, sizes: Sizes, clock, device,
         kinds: Dict[str, set] = {"hbm": set(), "host": set()}
 
         def watch(rnd, _gen):
-            kinds["hbm"].add(engine.pool.k_hbm.sharding.memory_kind)
+            kinds["hbm"] |= {a.sharding.memory_kind
+                             for a in engine.pool.hbm}
             kinds["host"] |= engine.pool.host.memory_kinds()
 
         streams, logits = run_sessions(llm, prompts, sizes, track=track,
@@ -468,7 +470,7 @@ def phase_replicas(model, params, prompts, sizes: Sizes, n: int):
     placement = []
     for rep in reps:
         e = rep.engine
-        devs = {e.device} | e.pool.k_hbm.devices() | e.pool.v_hbm.devices()
+        devs = {e.device}.union(*(a.devices() for a in e.pool.hbm))
         for a in jax.tree.leaves(e.params):
             devs |= a.devices()
         placement.append(sorted(str(d) for d in devs))

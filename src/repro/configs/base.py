@@ -3,7 +3,7 @@
 Each ``configs/<arch>.py`` defines ``CONFIG`` (the exact published
 configuration) and ``SMOKE`` (a reduced same-family configuration for CPU
 tests).  ``get(arch)`` / ``get_smoke(arch)`` resolve by id; ``ARCHS`` lists
-all ten assigned architectures.
+all supported architectures.
 
 ``long_500k`` applicability (DESIGN.md Sec. 5): sub-quadratic decode memory
 is required, so only the SSM/hybrid/windowed archs run it; pure
@@ -28,6 +28,7 @@ ARCHS: List[str] = [
     "granite_moe_3b_a800m",
     "phi3_vision_4_2b",
     "xlstm_350m",
+    "deepseek_v2_lite",
 ]
 
 # Archs whose long_500k cell runs (sub-quadratic decode state).

@@ -97,17 +97,21 @@ def _pad_to(x, mult, axis):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "window", "block_q", "block_k", "interpret"))
+    static_argnames=("causal", "window", "block_q", "block_k", "interpret",
+                     "scale"))
 def flash_attention_fwd(q, k, v, causal: bool = True,
                         window: Optional[int] = None,
                         block_q: int = DEFAULT_BLOCK_Q,
                         block_k: int = DEFAULT_BLOCK_K,
-                        interpret: bool = False):
-    """q: (B,Sq,H,dh); k/v: (B,Sk,K,dh) with H = G*K.  Returns (B,Sq,H,dh)."""
+                        interpret: bool = False,
+                        scale: Optional[float] = None):
+    """q: (B,Sq,H,dh); k/v: (B,Sk,K,dh) with H = G*K.  Returns (B,Sq,H,dh).
+    ``scale`` defaults to 1/sqrt(dh)."""
     B, Sq, H, dh = q.shape
     _, Sk, K, _ = k.shape
     G = H // K
-    scale = 1.0 / np.sqrt(dh)
+    if scale is None:
+        scale = 1.0 / np.sqrt(dh)
 
     # Layout: fold heads into the grid's leading dim; pad dh to lanes.
     dh_p = ((dh + 127) // 128) * 128
@@ -149,26 +153,28 @@ def flash_attention_fwd(q, k, v, causal: bool = True,
     return out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention_pallas(q, k, v, causal: bool = True,
                            window: Optional[int] = None,
-                           interpret: bool = False):
+                           interpret: bool = False,
+                           scale: Optional[float] = None):
     return flash_attention_fwd(q, k, v, causal=causal, window=window,
-                               interpret=interpret)
+                               interpret=interpret, scale=scale)
 
 
-def _fa_fwd(q, k, v, causal, window, interpret):
+def _fa_fwd(q, k, v, causal, window, interpret, scale):
     out = flash_attention_fwd(q, k, v, causal=causal, window=window,
-                              interpret=interpret)
+                              interpret=interpret, scale=scale)
     return out, (q, k, v)
 
 
-def _fa_bwd(causal, window, interpret, res, g):
+def _fa_bwd(causal, window, interpret, scale, res, g):
     q, k, v = res
     # Exact recompute backward via the jnp oracle (block-sparse Pallas
     # backward is future work; this keeps numerics bit-comparable).
     def f(q, k, v):
-        return ref.mha_reference(q, k, v, causal=causal, window=window)
+        return ref.mha_reference(q, k, v, causal=causal, window=window,
+                                 scale=scale)
 
     _, vjp = jax.vjp(f, q, k, v)
     return vjp(g)

@@ -55,6 +55,20 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
+def _ff_block(f: int, block_f: int) -> int:
+    """The ff tile: ``block_f`` (at most ``f`` rounded up to 128 lanes),
+    stepped down by 128 lanes until it divides that rounding.  The expert
+    weights then need no padding beyond it — a pad would copy every
+    expert's weights (the whole HBM expert cache, when it is the operand)
+    on every call — and the tile stays within VMEM at wide ``d`` (1,408 =
+    11 x 128 gives 128)."""
+    f128 = _round_up(f, 128)
+    bf = min(block_f, f128)
+    while f128 % bf and bf > 128:
+        bf -= 128
+    return bf
+
+
 def _pad_axis(x, size: int, axis: int):
     pad = size - x.shape[axis]
     if pad == 0:
@@ -140,7 +154,7 @@ def _grouped_ffn_fwd(x, w_gate, w_up, w_down, group_sizes, group_experts, *,
     E, _, f = w_gate.shape
     G = group_sizes.shape[0]
     d_p = _round_up(d, 128)
-    bf = min(block_f, _round_up(f, 128))
+    bf = _ff_block(f, block_f)
     f_p = _round_up(f, bf)
     T_p = _round_up(T, block_t)
     tiles_m = T_p // block_t

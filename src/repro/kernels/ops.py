@@ -35,14 +35,19 @@ def current_mode() -> str:
 
 
 def flash_attention(q, k, v, causal: bool = True,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None,
+                    scale: Optional[float] = None):
+    """q: (B,Sq,H,dh); k/v: (B,Sk,K,dh).  ``scale`` defaults to
+    1/sqrt(dh)."""
     mode = current_mode()
     if mode == "reference":
-        return ref.mha_reference(q, k, v, causal=causal, window=window)
+        return ref.mha_reference(q, k, v, causal=causal, window=window,
+                                 scale=scale)
     from .flash_attention import flash_attention_pallas
 
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                  interpret=(mode == "interpret"))
+                                  interpret=(mode == "interpret"),
+                                  scale=scale)
 
 
 def paged_attention(q, k_pool, v_pool, page_table, lengths,
@@ -77,6 +82,21 @@ def paged_prefill(q, k_pool, v_pool, page_table, lengths,
     return paged_prefill_pallas(q, k_pool, v_pool, page_table, lengths,
                                 window=window,
                                 interpret=(mode == "interpret"))
+
+
+def paged_mla_decode(q, pool, page_table, lengths, scale: float, dv: int):
+    """Absorbed multi-head latent attention decode over ONE paged latent
+    pool (keys and values share it).  q: (B,H,L); pool: (N,P,L);
+    page_table: (B,MP) int32; lengths: (B,).  Returns (B,H,dv)."""
+    mode = current_mode()
+    if mode == "reference":
+        return ref.paged_mla_decode_reference(q, pool, page_table, lengths,
+                                              scale=scale, dv=dv)
+    from .paged_attention import paged_mla_decode_pallas
+
+    return paged_mla_decode_pallas(q, pool, page_table, lengths,
+                                   scale=scale, dv=dv,
+                                   interpret=(mode == "interpret"))
 
 
 def moe_grouped_ffn(x, w_gate, w_up, w_down, group_sizes,

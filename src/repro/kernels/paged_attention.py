@@ -19,6 +19,12 @@ dh lanes in its kv head's lane block and zeros elsewhere, so one
 (H, K*dh) x (P, K*dh)^T matmul yields every head's scores against its own
 kv head, and the (H, P) x (P, K*dh) value matmul leaves head h's output in
 that same lane block, which the wrapper selects.
+
+Absorbed multi-head latent attention (``paged_mla_decode_pallas``) runs
+the same page walk over ONE latent pool: every head's absorbed query
+(H, L) scores against the page's (P, L) latent rows with the model's
+softmax scale, and the values are the latent's first ``dv`` lanes of the
+same tile, so each page is read once for keys and values both.
 """
 
 from __future__ import annotations
@@ -40,6 +46,26 @@ def _kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
             m_scr, l_scr, acc_scr, *,
             page_size: int, dh: int, window: Optional[int],
             shared_table: bool):
+    _page_step(table_ref, len_ref, q_ref, lambda: k_ref[0],
+               lambda k: v_ref[0].astype(F32), o_ref, m_scr, l_scr, acc_scr,
+               page_size=page_size, scale=1.0 / np.sqrt(dh),
+               window=window, shared_table=shared_table)
+
+
+def _mla_kernel(table_ref, len_ref, q_ref, kv_ref, o_ref,
+                m_scr, l_scr, acc_scr, *,
+                page_size: int, scale: float, dv: int):
+    _page_step(table_ref, len_ref, q_ref, lambda: kv_ref[0],
+               lambda k: k[:, :dv], o_ref, m_scr, l_scr, acc_scr,
+               page_size=page_size, scale=scale, window=None,
+               shared_table=False)
+
+
+def _page_step(table_ref, len_ref, q_ref, keys, values, o_ref,
+               m_scr, l_scr, acc_scr, *, page_size: int, scale: float,
+               window: Optional[int], shared_table: bool):
+    """One (row, page) grid step of the online softmax: ``keys()`` reads
+    the page's key tile, ``values(k)`` its value tile."""
     b = pl.program_id(0)
     p = pl.program_id(1)
     np_ = pl.num_programs(1)
@@ -57,11 +83,11 @@ def _kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(valid_page)
     def _attend():
         q = q_ref[0].astype(F32)                       # (H, K*dh) block-diag
-        k = k_ref[0].astype(F32)                       # (P, K*dh)
-        v = v_ref[0].astype(F32)
+        k = keys().astype(F32)                         # (P, K*dh)
+        v = values(k)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=F32)   # (H, P)
-        s = s * (1.0 / np.sqrt(dh))
+        s = s * scale
         pos = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         ok = pos < length
@@ -167,3 +193,44 @@ def paged_attention_pallas(q, k_pool, v_pool, page_table, lengths,
     (-1 = unused); lengths: (B,).  Returns (B,H,dh)."""
     return _paged_call(q, k_pool, v_pool, page_table, lengths,
                        window=window, interpret=interpret, shared_table=False)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "dv", "interpret"))
+def paged_mla_decode_pallas(q, pool, page_table, lengths, scale: float,
+                            dv: int, interpret: bool = False):
+    """Absorbed MLA decode: q (R, H, L) absorbed queries; pool (N, P, L)
+    one layer's latent pages; page_table (R, MP) int32 (-1 = unused);
+    lengths (R,).  Scores q.latent * ``scale``; values the latent's first
+    ``dv`` lanes.  Returns (R, H, dv).
+
+    The pool's tiles are its whole (P, L) rows, so no lane padding (and no
+    copy of the pool) is needed at L = 576."""
+    R, H, L = q.shape
+    P = pool.shape[1]
+    MP = page_table.shape[1]
+
+    def page_index(b, p, table, lens):
+        return (jnp.maximum(table[b, p], 0), 0, 0)
+
+    return pl.pallas_call(
+        functools.partial(_mla_kernel, page_size=P, scale=scale, dv=dv),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,      # page_table, lengths
+            grid=(R, MP),
+            in_specs=[
+                pl.BlockSpec((1, H, L),
+                             lambda b, p, table, lens: (b, 0, 0)),
+                pl.BlockSpec((1, P, L), page_index),
+            ],
+            out_specs=pl.BlockSpec((1, H, dv),
+                                   lambda b, p, table, lens: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((H, 1), F32),
+                pltpu.VMEM((H, 1), F32),
+                pltpu.VMEM((H, dv), F32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((R, H, dv), q.dtype),
+        interpret=interpret,
+    )(page_table, lengths, q, pool)

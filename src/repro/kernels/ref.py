@@ -95,6 +95,28 @@ def paged_prefill_reference(q, k_pool, v_pool, page_table, lengths,
                                      window=window)
 
 
+def paged_mla_decode_reference(q, pool, page_table, lengths, scale: float,
+                               dv: int) -> jax.Array:
+    """Absorbed multi-head latent attention over paged latents — jnp
+    oracle.  q: (B, H, L) absorbed queries; pool: (N, P, L) one
+    layer's latent pages; page_table: (B, MP) int32 (-1 = unused);
+    lengths: (B,).  Scores are q.latent * ``scale``, values the latent's
+    first ``dv`` lanes.  Returns (B, H, dv)."""
+    B, H, L = q.shape
+    P = pool.shape[1]
+    MP = page_table.shape[1]
+    lat = pool[jnp.maximum(page_table, 0)]
+    lat = lat.reshape(B, MP * P, L).astype(F32)
+    pos = jnp.arange(MP * P)[None, :]
+    valid = (pos < lengths[:, None]) & jnp.repeat(page_table >= 0, P,
+                                                   axis=1)
+    s = jnp.einsum("bhl,bsl->bhs", q.astype(F32), lat) * scale
+    s = jnp.where(valid[:, None], s, NEG_INF)
+    w = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bhs,bsv->bhv", w, lat[..., :dv])
+    return o.astype(q.dtype)
+
+
 # ---------------------------------------------------- grouped-expert GEMM
 def moe_grouped_ffn_reference(x, w_gate, w_up, w_down, group_sizes,
                               group_experts=None):

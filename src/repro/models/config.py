@@ -30,6 +30,26 @@ class ModelConfig:
     moe_ep_axis_size: int = 16            # ep expert-pad target; must be a
                                           # multiple of the mesh model axis
     capacity_factor: float = 1.0          # capacity path only
+    # False: the top-k gates keep their softmax mass (DeepSeek-V2);
+    # True renormalizes them to sum to one (Mixtral, granite-MoE).
+    norm_topk_prob: bool = True
+    # Always-on shared experts (DeepSeekMoE): one SwiGLU of width
+    # n_shared_experts * d_ff beside the routed ones, in every MoE layer.
+    n_shared_experts: int = 0
+    # Leading dense layers (DeepSeek's first_k_dense_replace): SwiGLU of
+    # width dense_d_ff in place of the experts; n_layers counts them.
+    first_dense_layers: int = 0
+    dense_d_ff: int = 0
+    # Multi-head latent attention (DeepSeek-V2 Sec. 2.1), on when
+    # kv_lora_rank > 0: keys and values come from one cached latent of
+    # kv_lora_rank + qk_rope_head_dim values per token; head_dim is
+    # qk_nope_head_dim + qk_rope_head_dim, the query's width.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope scaling of the rope dims (None: plain rope).
+    yarn: Optional["Yarn"] = None
     # SSM / hybrid
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -54,6 +74,15 @@ class ModelConfig:
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def moe_layers(self) -> int:
+        """Layers whose FFN is the routed experts."""
+        return self.n_layers - self.first_dense_layers
+
     def validate(self) -> "ModelConfig":
         assert self.n_heads % max(self.kv_heads, 1) == 0, "GQA grouping"
         if self.family == "moe":
@@ -62,11 +91,31 @@ class ModelConfig:
                 self.moe_dispatch
             if self.moe_parallelism == "ep":
                 assert self.moe_ep_axis_size > 0, self.moe_ep_axis_size
+        if self.first_dense_layers:
+            assert self.family == "moe" and self.dense_d_ff > 0
+            assert 0 < self.first_dense_layers < self.n_layers
+        if self.mla:
+            assert (self.resolved_head_dim
+                    == self.qk_nope_head_dim + self.qk_rope_head_dim), \
+                "MLA head_dim is qk_nope_head_dim + qk_rope_head_dim"
+            assert self.qk_rope_head_dim % 2 == 0 and self.v_head_dim > 0
         if self.family == "hybrid":
             assert self.ssm_state > 0 and self.attn_every > 0
         if self.family == "encdec":
             assert self.enc_layers > 0
         return self
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling (arXiv:2309.00071), as DeepSeek-V2's
+    ``rope_scaling`` states it."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)

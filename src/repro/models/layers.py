@@ -10,7 +10,8 @@ axes via ``constrain`` so the same code partitions on any mesh.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+import math
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,14 +36,20 @@ def rmsnorm(p, x, eps: float = 1e-6):
 
 
 # -------------------------------------------------------------------- rope
-def rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0):
-    """x: (..., S, H, dh); positions: (..., S)."""
+def rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
+         freqs: Optional[np.ndarray] = None, mscale: float = 1.0):
+    """x: (..., S, H, dh); positions: (..., S).  Half-split pairs (lane i
+    with lane i + dh/2).  ``freqs`` (dh/2,) overrides the plain inverse
+    frequencies (YaRN, ``yarn_freqs``); ``mscale`` scales cos and sin."""
     dh = x.shape[-1]
     half = dh // 2
-    freqs = (theta ** (-np.arange(0, half, dtype=np.float32) / half))
+    if freqs is None:
+        freqs = (theta ** (-np.arange(0, half, dtype=np.float32) / half))
     angles = positions[..., :, None].astype(F32) * freqs  # (..., S, half)
     cos = jnp.cos(angles)[..., :, None, :]
     sin = jnp.sin(angles)[..., :, None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
@@ -96,16 +103,20 @@ def _mask(q_pos, k_pos, cfg: AttnConfig, cross: bool):
     return allowed
 
 
-def _attend(q, k, v, mask, cfg: AttnConfig, q_chunk: int = 1024):
+def _attend(q, k, v, mask, cfg: AttnConfig, q_chunk: int = 1024,
+            scale: Optional[float] = None):
     """Grouped-query attention with chunked-q exact softmax.
 
-    q: (B, Sq, H, dh); k/v: (B, Sk, K, dh).  Memory per chunk is
-    O(B*H*q_chunk*Sk), never O(Sq*Sk).
+    q/k: (B, S, H|K, dh); v: (B, Sk, K, dv).  Memory per chunk is
+    O(B*H*q_chunk*Sk), never O(Sq*Sk).  ``scale`` defaults to
+    1/sqrt(dh).
     """
     B, Sq, H, dh = q.shape
     K = k.shape[2]
     G = H // K
-    scale = 1.0 / np.sqrt(dh)
+    dv = v.shape[-1]
+    if scale is None:
+        scale = 1.0 / np.sqrt(dh)
     qg = q.reshape(B, Sq, K, G, dh)
 
     n_chunks = max(1, Sq // q_chunk) if Sq % q_chunk == 0 else 1
@@ -138,8 +149,8 @@ def _attend(q, k, v, mask, cfg: AttnConfig, q_chunk: int = 1024):
             lambda a: one_chunk((a[0], a[1] if mask is not None else None)),
             (qs, ms),
         )
-        out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, Sq, K, G, dh)
-    return out.reshape(B, Sq, H, dh)
+        out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, Sq, K, G, dv)
+    return out.reshape(B, Sq, H, dv)
 
 
 def attention(p, x, cfg: AttnConfig, positions, kv_x=None,
@@ -252,6 +263,178 @@ def cross_attention_decode(p, x, mem_k, mem_v, cfg: AttnConfig):
     o = jnp.einsum("bkgs,bskd->bkgd", w, mem_v.astype(F32)).astype(x.dtype)
     y = jnp.einsum("bhk,hkd->bd", o.reshape(B, H, dh), p["wo"])[:, None]
     return y
+
+
+# ------------------------------------------- multi-head latent attention
+def yarn_freqs(dim: int, theta: float, factor: float, original_max: int,
+               beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN inverse frequencies of ``dim`` rope lanes (arXiv:2309.00071,
+    as DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding`` computes them):
+    pairs that turn more than ``beta_fast`` times over the original
+    context keep their frequency, those under ``beta_slow`` turns are
+    divided by ``factor``, and a linear ramp blends the pairs between."""
+    def turns_dim(rot):
+        return (dim * math.log(original_max / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns_dim(beta_fast)), 0)
+    high = min(math.ceil(turns_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    expo = np.arange(0, dim, 2, dtype=np.float32) / dim
+    extra = 1.0 / (theta ** expo)
+    inter = 1.0 / (factor * theta ** expo)
+    keep = 1.0 - ramp
+    return (inter * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: 0.1 * mscale * ln(factor) + 1."""
+    if factor <= 1 or not mscale:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 Sec.
+    2.1) without query compression.  Per token one latent of
+    ``kv_lora_rank + qk_rope`` values is cached: the normed compressed KV
+    ``c`` and the rotated shared rope key ``k_pe``.  Head h's key is
+    [c W_uk[h], k_pe], its value c W_uv[h]."""
+    d_model: int
+    n_heads: int
+    kv_lora_rank: int
+    qk_nope: int
+    qk_rope: int
+    v_head_dim: int
+    rope_theta: float = 10000.0
+    yarn: Optional[Any] = None        # models.config.Yarn
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope
+
+    def rope_freqs(self) -> Optional[np.ndarray]:
+        y = self.yarn
+        if y is None:
+            return None
+        return yarn_freqs(self.qk_rope, self.rope_theta, y.factor,
+                          y.original_max_position, y.beta_fast, y.beta_slow)
+
+    def rope_mscale(self) -> float:
+        y = self.yarn
+        if y is None:
+            return 1.0
+        return (yarn_mscale(y.factor, y.mscale)
+                / yarn_mscale(y.factor, y.mscale_all_dim))
+
+    def softmax_scale(self) -> float:
+        """(qk_nope + qk_rope)^-1/2, times YaRN's mscale squared."""
+        scale = 1.0 / math.sqrt(self.qk_nope + self.qk_rope)
+        y = self.yarn
+        if y is not None and y.mscale_all_dim:
+            m = yarn_mscale(y.factor, y.mscale_all_dim)
+            scale *= m * m
+        return scale
+
+
+def mla_defs(cfg: MLAConfig):
+    d, H, C = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope, cfg.qk_rope, cfg.v_head_dim
+    # W_kv_b's two halves are ``wk`` and ``wv``, shaped (C, H, dh) so the
+    # contraction axis leads; every std is 1/sqrt(fan-in).
+    return {
+        "wq": ArrayDef((d, H, dn + dr), ("embed", "heads", None),
+                       scale=1.0 / np.sqrt(d)),
+        "wkv_a": ArrayDef((d, C + dr), ("embed", None)),
+        "kv_norm": {"scale": ArrayDef((C,), (None,), init="ones")},
+        "wk": ArrayDef((C, H, dn), (None, "heads", None),
+                       scale=1.0 / np.sqrt(C)),
+        "wv": ArrayDef((C, H, dv), (None, "heads", None),
+                       scale=1.0 / np.sqrt(C)),
+        "wo": ArrayDef((H, dv, d), ("heads", None, "embed"),
+                       scale=1.0 / np.sqrt(H * dv)),
+    }
+
+
+def mla_project(p, h, positions, cfg: MLAConfig):
+    """h: (..., S, d); positions (..., S).  Returns the query's nope and
+    rotated rope parts (..., S, H, qk_nope|qk_rope) and the token's cache
+    latent (..., S, kv_lora_rank + qk_rope): [rmsnorm(c), rope(k_pe)]."""
+    C = cfg.kv_lora_rank
+    freqs, ms = cfg.rope_freqs(), cfg.rope_mscale()
+    q = jnp.einsum("...sd,dhk->...shk", h, p["wq"])
+    q_nope = q[..., :cfg.qk_nope]
+    q_pe = rope(q[..., cfg.qk_nope:], positions, cfg.rope_theta, freqs, ms)
+    kv = jnp.einsum("...sd,dc->...sc", h, p["wkv_a"])
+    c = rmsnorm(p["kv_norm"], kv[..., :C])
+    k_pe = rope(kv[..., None, C:], positions, cfg.rope_theta, freqs,
+                ms)[..., 0, :]
+    return q_nope, q_pe, jnp.concatenate([c, k_pe], axis=-1)
+
+
+def mla_unabsorbed(p, q_nope, q_pe, lat, cfg: MLAConfig):
+    """Per-head queries, keys and values of the plain form: q (.., Sq, H,
+    qk_nope+qk_rope), k (.., Sk, H, qk_nope+qk_rope), v (.., Sk, H, dv)."""
+    C = cfg.kv_lora_rank
+    c, k_pe = lat[..., :C], lat[..., C:]
+    k_nope = jnp.einsum("...c,chn->...hn", c, p["wk"])
+    v = jnp.einsum("...c,chv->...hv", c, p["wv"])
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_pe[..., None, :],
+                                  k_nope.shape[:-1] + k_pe.shape[-1:])],
+        axis=-1)
+    return jnp.concatenate([q_nope, q_pe], axis=-1), k, v
+
+
+def mla_absorb(p, q_nope, q_pe):
+    """The absorbed query (..., H, kv_lora_rank + qk_rope): q_nope W_uk^T
+    beside q_pe, whose product with a cached latent is the plain score."""
+    return jnp.concatenate(
+        [jnp.einsum("...hn,chn->...hc", q_nope, p["wk"]), q_pe], axis=-1)
+
+
+def mla_values(p, o_lat):
+    """Attention output over latents (..., H, kv_lora_rank) to head
+    values (..., H, dv): o_lat W_uv."""
+    return jnp.einsum("...hc,chv->...hv", o_lat, p["wv"])
+
+
+def mla_attention(p, x, cfg: MLAConfig, positions, q_chunk: int = 1024):
+    """Causal MLA for training/prefill, in the plain (un-absorbed) form."""
+    q_nope, q_pe, lat = mla_project(p, x, positions, cfg)
+    q, k, v = mla_unabsorbed(p, q_nope, q_pe, lat, cfg)
+    pos = positions[0]
+    mask = pos[None, :] <= pos[:, None]
+    out = _attend(q, k, v, mask, None, q_chunk=q_chunk,
+                  scale=cfg.softmax_scale())
+    out = constrain(out, ("batch", "seq", "heads", None))
+    y = jnp.einsum("bshv,hvd->bsd", out, p["wo"])
+    return constrain(y, ("batch", "seq", "embed"))
+
+
+def mla_attention_decode(p, x, cache, pos, cfg: MLAConfig):
+    """One-token decode against a dense latent cache, absorbed: update the
+    cache at ``pos`` and attend over it.  x: (B, 1, d); cache: (B, S_max,
+    kv_lora_rank + qk_rope).  Returns (y, new_cache)."""
+    B, S_max, _ = cache.shape
+    positions = jnp.full((B, 1), pos, jnp.int32)
+    q_nope, q_pe, lat = mla_project(p, x, positions, cfg)
+    cache = jax.lax.dynamic_update_slice(cache, lat.astype(cache.dtype),
+                                         (0, pos, 0))
+    q_lat = mla_absorb(p, q_nope[:, 0], q_pe[:, 0])          # (B, H, L)
+    s = jnp.einsum("bhc,bsc->bhs", q_lat.astype(F32), cache.astype(F32))
+    s *= cfg.softmax_scale()
+    s = jnp.where((jnp.arange(S_max) <= pos)[None, None], s, NEG_INF)
+    w = jax.nn.softmax(s, axis=-1)
+    o_lat = jnp.einsum("bhs,bsc->bhc", w,
+                       cache[..., :cfg.kv_lora_rank].astype(F32))
+    o = mla_values(p, o_lat.astype(x.dtype))
+    y = jnp.einsum("bhv,hvd->bd", o, p["wo"])[:, None]
+    return constrain(y, ("batch", "seq", "embed")), cache
 
 
 # --------------------------------------------------------------------- mlp

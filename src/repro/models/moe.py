@@ -60,6 +60,7 @@ class MoEConfig:
     dispatch: str = "dropless"       # "dropless" | "capacity"
     parallelism: str = "tp"          # "tp" | "ep"
     ep_axis_size: int = 16           # ep pad target; validated vs the mesh
+    norm_topk_prob: bool = True      # renormalize the top-k gates to sum 1
 
     @property
     def padded_experts(self) -> int:
@@ -106,8 +107,8 @@ def route_tokens(router, x2d, cfg: MoEConfig) -> Tuple[jax.Array, jax.Array]:
 
     This is THE routing function — prefill, chunked prefill and decode all
     call it on their flattened token sets (the ep shard_map path included).
-    It looks at one token at a time (softmax over experts, top-k,
-    renormalize), so the token->expert assignment is bitwise-identical no
+    It looks at one token at a time (softmax over experts, top-k, the
+    gates renormalized when ``cfg.norm_topk_prob``), so the token->expert assignment is bitwise-identical no
     matter how the token stream is chunked into batches.
     """
     E = cfg.padded_experts
@@ -133,7 +134,8 @@ def route_tokens(router, x2d, cfg: MoEConfig) -> Tuple[jax.Array, jax.Array]:
             jnp.arange(E)[None, :] == e[:, None], -jnp.inf, remaining)
     gates = jnp.stack(gate_cols, axis=-1)                       # (T, k)
     experts = jnp.stack(expert_cols, axis=-1)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
     return gates, experts
 
 

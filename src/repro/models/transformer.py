@@ -28,6 +28,7 @@ from .common import ArrayDef, abstract_params, init_params, stack
 from .config import ModelConfig, ShapeConfig
 from .layers import (
     AttnConfig,
+    MLAConfig,
     chunked_lm_loss,
     attention,
     attention_decode,
@@ -39,6 +40,10 @@ from .layers import (
     embed_defs,
     lm_head,
     lm_head_defs,
+    mla_attention,
+    mla_attention_decode,
+    mla_defs,
+    mla_project,
     mlp,
     mlp_defs,
     rmsnorm,
@@ -77,6 +82,11 @@ class Model:
             rope_theta=cfg.rope_theta,
             window=cfg.window,
         )
+        self.mla_cfg = MLAConfig(
+            d_model=cfg.d_model, n_heads=cfg.n_heads,
+            kv_lora_rank=cfg.kv_lora_rank, qk_nope=cfg.qk_nope_head_dim,
+            qk_rope=cfg.qk_rope_head_dim, v_head_dim=cfg.v_head_dim,
+            rope_theta=cfg.rope_theta, yarn=cfg.yarn) if cfg.mla else None
         if cfg.family == "moe":
             self.moe_cfg = MoEConfig(
                 d_model=cfg.d_model, d_ff=cfg.d_ff, n_experts=cfg.n_experts,
@@ -84,6 +94,7 @@ class Model:
                 dispatch=cfg.moe_dispatch,
                 parallelism=cfg.moe_parallelism,
                 ep_axis_size=cfg.moe_ep_axis_size,
+                norm_topk_prob=cfg.norm_topk_prob,
             )
         if cfg.family == "hybrid":
             self.ssm_cfg = SSMConfig(
@@ -94,15 +105,32 @@ class Model:
             self.xl_cfg = XLSTMConfig(d_model=cfg.d_model, n_heads=cfg.n_heads)
 
     # ================================================================ defs
+    def _attn_defs(self):
+        if self.mla_cfg is not None:
+            return mla_defs(self.mla_cfg)
+        return attention_defs(self.attn_cfg)
+
     def _dense_layer_defs(self):
-        d = {"ln1": rmsnorm_defs(self.cfg.d_model),
-             "attn": attention_defs(self.attn_cfg),
-             "ln2": rmsnorm_defs(self.cfg.d_model)}
-        if self.cfg.family == "moe":
+        cfg = self.cfg
+        d = {"ln1": rmsnorm_defs(cfg.d_model),
+             "attn": self._attn_defs(),
+             "ln2": rmsnorm_defs(cfg.d_model)}
+        if cfg.family == "moe":
             d["moe"] = moe_defs(self.moe_cfg)
+            if cfg.n_shared_experts:
+                d["shared"] = mlp_defs(cfg.d_model,
+                                       cfg.n_shared_experts * cfg.d_ff)
         else:
-            d["mlp"] = mlp_defs(self.cfg.d_model, self.cfg.d_ff)
+            d["mlp"] = mlp_defs(cfg.d_model, cfg.d_ff)
         return d
+
+    def _lead_layer_defs(self):
+        """A leading dense layer of a MoE model: SwiGLU of dense_d_ff."""
+        cfg = self.cfg
+        return {"ln1": rmsnorm_defs(cfg.d_model),
+                "attn": self._attn_defs(),
+                "ln2": rmsnorm_defs(cfg.d_model),
+                "mlp": mlp_defs(cfg.d_model, cfg.dense_d_ff)}
 
     def _shared_attn_defs(self):
         return {"ln1": rmsnorm_defs(self.cfg.d_model),
@@ -118,7 +146,11 @@ class Model:
             "head": lm_head_defs(cfg.d_model, cfg.vocab),
         }
         if cfg.family in ("dense", "moe", "vlm"):
-            out["layers"] = stack(self._dense_layer_defs(), cfg.n_layers)
+            nd = cfg.first_dense_layers
+            if nd:
+                out["dense_layers"] = stack(self._lead_layer_defs(), nd)
+            out["layers"] = stack(self._dense_layer_defs(),
+                                  cfg.n_layers - nd)
             if cfg.family == "vlm":
                 out["vision_adapter"] = {
                     "w": ArrayDef((cfg.d_model, cfg.d_model),
@@ -171,17 +203,46 @@ class Model:
     def _maybe_remat(self, fn):
         return jax.checkpoint(fn) if self.cfg.remat else fn
 
+    def _self_attention(self, p, h, positions):
+        if self.mla_cfg is not None:
+            return mla_attention(p, h, self.mla_cfg, positions)
+        return attention(p, h, self.attn_cfg, positions)
+
+    def _ffn(self, lp, h2, decode: bool = False):
+        """A layer's FFN: the routed experts (plus the shared ones), or the
+        dense MLP of a dense model or a MoE model's leading layer."""
+        if "moe" in lp:
+            d = (moe_decode if decode else moe)(lp["moe"], h2, self.moe_cfg)
+            if "shared" in lp:
+                d = d + mlp(lp["shared"], h2)
+            return d
+        return mlp(lp["mlp"], h2)
+
     def _dense_body(self, x, lp, positions):
-        h = x + attention(lp["attn"], rmsnorm(lp["ln1"], x), self.attn_cfg,
-                          positions)
-        if self.cfg.family == "moe":
-            return h + moe(lp["moe"], rmsnorm(lp["ln2"], h), self.moe_cfg)
-        return h + mlp(lp["mlp"], rmsnorm(lp["ln2"], h))
+        h = x + self._self_attention(lp["attn"], rmsnorm(lp["ln1"], x),
+                                     positions)
+        return h + self._ffn(lp, rmsnorm(lp["ln2"], h))
+
+    def scan_stacks(self, body, x, params, caches=()):
+        """``jax.lax.scan`` of ``body(x, (lp, *cache_l))`` over the layer
+        stacks in depth order — a MoE model's leading dense layers, then
+        ``layers`` — with ``caches`` (stacked over all layers) split to
+        match.  Returns (x, the per-layer outputs stacked over all
+        layers)."""
+        nd = self.cfg.first_dense_layers
+        if not nd:
+            return jax.lax.scan(body, x, (params["layers"], *caches))
+        x, lead = jax.lax.scan(
+            body, x, (params["dense_layers"], *(c[:nd] for c in caches)))
+        x, rest = jax.lax.scan(
+            body, x, (params["layers"], *(c[nd:] for c in caches)))
+        return x, jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
+                               lead, rest)
 
     def _run_dense_stack(self, params, x, positions):
         body = self._maybe_remat(
-            lambda x, lp: (self._dense_body(x, lp, positions), None))
-        x, _ = jax.lax.scan(body, x, params["layers"])
+            lambda x, xs: (self._dense_body(x, xs[0], positions), None))
+        x, _ = self.scan_stacks(body, x, params)
         return x
 
     def _shared_attn_apply(self, sp, x, positions):
@@ -339,6 +400,11 @@ class Model:
             return {"k": ArrayDef(shape, kv_axes, cfg.dtype, init="zeros"),
                     "v": ArrayDef(shape, kv_axes, cfg.dtype, init="zeros")}
 
+        if cfg.mla:
+            return {"kv": {"lat": ArrayDef(
+                (cfg.n_layers, batch, seq, self.mla_cfg.latent_dim),
+                ("layers", "batch", "kv_seq", None), cfg.dtype,
+                init="zeros")}}
         if cfg.family in ("dense", "moe", "vlm"):
             return {"kv": kv(cfg.n_layers)}
         if cfg.family == "hybrid":
@@ -398,7 +464,20 @@ class Model:
         Returns (logits (B, vocab), new cache)."""
         cfg = self.cfg
         x = embed(params["embed"], tokens)
-        if cfg.family in ("dense", "moe", "vlm"):
+        if cfg.mla:
+            def body(x, xs):
+                lp, cl = xs
+                y, cl = mla_attention_decode(
+                    lp["attn"], rmsnorm(lp["ln1"], x), cl, pos,
+                    self.mla_cfg)
+                x = x + y
+                return x + self._ffn(lp, rmsnorm(lp["ln2"], x),
+                                     decode=True), (cl,)
+
+            x, (nl,) = self.scan_stacks(body, x, params,
+                                         (cache["kv"]["lat"],))
+            cache = {**cache, "kv": {"lat": nl}}
+        elif cfg.family in ("dense", "moe", "vlm"):
             ring = (cfg.window is not None
                     and cache["kv"]["k"].shape[2] <= cfg.window)
 
@@ -409,18 +488,14 @@ class Model:
                 y, ck, cv = dec(lp["attn"], h, ck, cv, pos, self.attn_cfg)
                 x = x + y
                 h2 = rmsnorm(lp["ln2"], x)
-                if cfg.family == "moe":
-                    # moe_decode == moe: decode shares the routing function
-                    # and grouped GEMM with prefill, so a token's expert
-                    # assignment never depends on how the stream is chunked.
-                    x = x + moe_decode(lp["moe"], h2, self.moe_cfg)
-                else:
-                    x = x + mlp(lp["mlp"], h2)
+                # moe_decode == moe: decode shares the routing function
+                # and grouped GEMM with prefill, so a token's expert
+                # assignment never depends on how the stream is chunked.
+                x = x + self._ffn(lp, h2, decode=True)
                 return x, (ck, cv)
 
-            x, (nk, nv) = jax.lax.scan(
-                body, x, (params["layers"], cache["kv"]["k"],
-                          cache["kv"]["v"]))
+            x, (nk, nv) = self.scan_stacks(
+                body, x, params, (cache["kv"]["k"], cache["kv"]["v"]))
             cache = {**cache, "kv": {"k": nk, "v": nv}}
         elif cfg.family == "hybrid":
             x, cache = self._decode_hybrid(params, cache, x, pos)
@@ -690,20 +765,30 @@ class Model:
             x, (ks, vs, xks, xvs) = jax.lax.scan(
                 self._maybe_remat(body), x, params["dec_layers"])
             cache = {"kv": {"k": ks, "v": vs}, "xk": xks, "xv": xvs}
+        elif cfg.mla:
+            def body(x, xs):
+                lp = xs[0]
+                h = rmsnorm(lp["ln1"], x)
+                _, _, lat = mla_project(lp["attn"], h, positions,
+                                        self.mla_cfg)
+                x = x + self._self_attention(lp["attn"], h, positions)
+                return x + self._ffn(lp, rmsnorm(lp["ln2"], x)), (lat,)
+
+            x, (lats,) = self.scan_stacks(self._maybe_remat(body), x,
+                                           params)
+            cache = {**cache, "kv": {"lat": lats}}
         else:
-            def body(x, lp):
+            def body(x, xs):
+                lp = xs[0]
                 h = rmsnorm(lp["ln1"], x)
                 k, v = kv_of(lp["attn"], h, positions)
                 x = x + attention(lp["attn"], h, acfg, positions)
                 h2 = rmsnorm(lp["ln2"], x)
-                if cfg.family == "moe":
-                    x = x + moe(lp["moe"], h2, self.moe_cfg)
-                else:
-                    x = x + mlp(lp["mlp"], h2)
+                x = x + self._ffn(lp, h2)
                 return x, (k, v)
 
-            x, (ks, vs) = jax.lax.scan(
-                self._maybe_remat(body), x, params["layers"])
+            x, (ks, vs) = self.scan_stacks(self._maybe_remat(body), x,
+                                            params)
             if cfg.window is not None and S > cfg.window:
                 # Ring-ify: only the last `window` tokens matter; place each
                 # at its ring slot (pos % W) so decode continues seamlessly.

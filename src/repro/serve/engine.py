@@ -47,7 +47,8 @@ chunks.  The request lifecycle is explicit:
 * **Preemption**: paused requests can lose their pages entirely (preempt by
   recompute — deterministic re-prefill of prompt+generated on resume makes
   this lossless, *because* one-shot prefill == decode bitwise) when the
-  wait-queue head needs logical pages.
+  wait-queue head needs logical pages.  Latent attention is the exception:
+  it prefills in the plain form and decodes absorbed, equal to rounding.
 * **Decoding** samples INSIDE the jitted dispatch (``ops.sample_tokens``):
   each scheduled row's ``SamplingParams`` ride along as batched
   temperature/top-k/top-p/seed arrays, and the per-row PRNG key folds the
@@ -94,7 +95,16 @@ from ..core.profiler import ArenaProfile, IntervalProfile
 from ..core.runtime import MigrationPlan
 from ..core.spans import span
 from ..dist.sharding import active_mesh
-from ..models.layers import lm_head, mlp, rmsnorm, rope
+from ..models.layers import (
+    lm_head,
+    mla_absorb,
+    mla_project,
+    mla_unabsorbed,
+    mla_values,
+    mlp,
+    rmsnorm,
+    rope,
+)
 from ..models.moe import apply_dropless_flat, moe_decode, route_tokens
 from ..models.transformer import Model
 from .eviction import make_eviction_policy
@@ -166,15 +176,16 @@ class ServeConfig:
     # resident single-scan path (on the TPU the two programs round
     # differently).
     expert_offchip: bool = False
-    # HBM cache capacity in expert blocks, shared across layers; 0 means
-    # every (layer, expert) block fits (n_layers * n_experts slots).  Must
+    # HBM cache capacity in expert blocks, shared across the MoE layers
+    # (a model's leading dense layers have no blocks); 0 means every
+    # (MoE layer, expert) block fits (moe_layers * n_experts slots).  Must
     # hold at least one dispatch's working set:
     # min(n_experts, max_batch * top_k).
     expert_cache_size: int = 0
     # Double-buffered prefetch: while layer L's grouped GEMM is in flight,
     # the predicted working set for the next layer stages on a second
-    # buffer.  Off = every miss is a blocking demand fetch (same results,
-    # more modeled stall).
+    # buffer.  Off = no prefetch: every block a dispatch needs and the
+    # cache lacks is a blocking demand fetch (same results).
     expert_double_buffer: bool = True
 
 
@@ -363,7 +374,7 @@ class Engine:
                     "across the mesh — pick one placement mechanism")
             E = model.moe_cfg.padded_experts
             floor = min(E, cfg.max_batch * model.moe_cfg.top_k)
-            size = cfg.expert_cache_size or model.cfg.n_layers * E
+            size = cfg.expert_cache_size or model.cfg.moe_layers * E
             if cfg.expert_cache_size < 0 or 0 < size < floor:
                 raise ValueError(
                     f"ServeConfig.expert_cache_size={cfg.expert_cache_size}"
@@ -379,11 +390,16 @@ class Engine:
         self.hw = hw
         self.device = device if device is not None else jax.devices()[0]
         mc = model.cfg
+        # Latent attention caches one latent per token and layer, read as
+        # keys and values both: a one-array pool of latent rows.
+        kv_heads, head_dim, n_arrays = (
+            (0, model.mla_cfg.latent_dim, 1) if mc.mla
+            else (mc.kv_heads, model.attn_cfg.head_dim, 2))
         self.pool = PagedKVPool(
             n_layers=mc.n_layers, page_size=cfg.page_size,
-            kv_heads=mc.kv_heads, head_dim=model.attn_cfg.head_dim,
+            kv_heads=kv_heads, head_dim=head_dim,
             hbm_pages=cfg.hbm_pages, host_pages=cfg.host_pages,
-            dtype=mc.dtype, device=self.device)
+            dtype=mc.dtype, device=self.device, n_arrays=n_arrays)
         self.requests: Dict[int, Request] = {}
         self.finished: Dict[int, Request] = {}
         self.wait_queue: Deque[int] = deque()
@@ -418,14 +434,17 @@ class Engine:
         self.expert_backend: Optional[ExpertBackend] = None
         self.expert_runtime: Optional[GuidanceRuntime] = None
         if cfg.expert_offchip:
+            # The store holds the routed experts of the MoE layers alone:
+            # its layer l is the model's layer first_dense_layers + l.
             E = self.model.moe_cfg.padded_experts
             moe_params = params["layers"]["moe"]
             self.expert_store = ExpertStore(
-                moe_params, mc.n_layers, E,
-                cfg.expert_cache_size or mc.n_layers * E,
+                moe_params, mc.moe_layers, E,
+                cfg.expert_cache_size or mc.moe_layers * E,
                 double_buffer=cfg.expert_double_buffer, device=self.device)
-            # The dense expert stacks live in the store's tiers now; serve
-            # against a param view holding only the router.  ``params``
+            # The routed expert stacks live in the store's tiers now; serve
+            # against a param view holding only the router (shared experts
+            # stay beside it, resident).  ``params``
             # itself is untouched (replicas re-derive their own stores);
             # a caller that needs the HBM back deletes its expert arrays.
             layers = dict(params["layers"])
@@ -554,7 +573,7 @@ class Engine:
         return backlog / per_step + n_active / self.cfg.max_batch
 
     # ================================================== shared layer body
-    def _layer_body(self, lp, x, kp, vp, *, positions, write_slot,
+    def _layer_body(self, lp, x, pools, *, positions, write_slot,
                     write_off, row_mask, lane_mask, rows, unrows, attend):
         """ONE transformer layer body shared by the jitted decode and
         one-shot prefill closures — a single definition is what keeps
@@ -565,19 +584,20 @@ class Engine:
         batch of B single-token rows, x (B,1,d); prefill: one sequence of
         S token rows, x (1,S,d)) and in the attention call.  ``rows``
         flattens a (.., ., H, dh) projection to (R, H, dh), ``unrows``
-        lifts an (R, d) result back to x's layout, ``attend(q, kp, vp)``
-        returns (R, H, dh).  Masked rows scatter zeros to the reserved
-        scratch slot and carry zero residuals — deterministic, never
-        garbage.
+        lifts an (R, d) result back to x's layout, ``attend``
+        (``_decode_attend`` / ``_prefill_attend``) returns (R, H, dv).
+        ``pools`` is the layer's slice of every pool array.  Masked rows
+        scatter zeros to the reserved scratch slot and carry zero residuals
+        — deterministic, never garbage.
         """
-        x, h2, kp, vp = self._attn_half(
-            lp, x, kp, vp, positions=positions, write_slot=write_slot,
+        x, h2, pools = self._attn_half(
+            lp, x, pools, positions=positions, write_slot=write_slot,
             write_off=write_off, row_mask=row_mask, lane_mask=lane_mask,
             rows=rows, unrows=unrows, attend=attend)
         x = self._ffn_half(lp, x, h2, lane_mask)
-        return x, kp, vp
+        return x, pools
 
-    def _attn_half(self, lp, x, kp, vp, *, positions, write_slot, write_off,
+    def _attn_half(self, lp, x, pools, *, positions, write_slot, write_off,
                    row_mask, lane_mask, rows, unrows, attend):
         """Attention through the pre-FFN rmsnorm.  Split from
         ``_layer_body`` so the tiered expert path (expert_offchip) can run
@@ -585,8 +605,17 @@ class Engine:
         host, and resume with ``_ffn_half``'s math against cache slots —
         the split point changes WHERE the jit boundary falls, never which
         ops run (on the CPU the outputs are bitwise-equal; on the TPU the
-        two programs round differently)."""
+        two programs round differently).
+
+        Latent attention writes each token's latent into its one pool and
+        ``attend(q_nope, q_pe, pool, p)`` reads it."""
+        if self.model.cfg.mla:
+            return self._mla_attn_half(
+                lp, x, pools, positions=positions, write_slot=write_slot,
+                write_off=write_off, row_mask=row_mask, lane_mask=lane_mask,
+                rows=rows, unrows=unrows, attend=attend)
         acfg = self.model.attn_cfg
+        kp, vp = pools
         h = rmsnorm(lp["ln1"], x)
         q = jnp.einsum("bsd,dhk->bshk", h, lp["attn"]["wq"])
         k1 = jnp.einsum("bsd,dhk->bshk", h, lp["attn"]["wk"])
@@ -604,16 +633,83 @@ class Engine:
                        lp["attn"]["wo"])
         x = x + jnp.where(lane_mask, unrows(y), 0)
         h2 = rmsnorm(lp["ln2"], x)
-        return x, h2, kp, vp
+        return x, h2, (kp, vp)
+
+    def _mla_attn_half(self, lp, x, pools, *, positions, write_slot,
+                       write_off, row_mask, lane_mask, rows, unrows, attend):
+        p = lp["attn"]
+        (lat_pool,) = pools
+        h = rmsnorm(lp["ln1"], x)
+        q_nope, q_pe, lat = mla_project(p, h, positions, self.model.mla_cfg)
+        lat_pool = lat_pool.at[write_slot, write_off].set(
+            jnp.where(row_mask[:, None], rows(lat), 0).astype(lat_pool.dtype))
+        o = attend(rows(q_nope), rows(q_pe), lat_pool, p)   # (R, H, dv)
+        y = jnp.einsum("rhv,hvd->rd", o, p["wo"])
+        x = x + jnp.where(lane_mask, unrows(y), 0)
+        h2 = rmsnorm(lp["ln2"], x)
+        return x, h2, (lat_pool,)
+
+    def _decode_attend(self, page_table, lengths):
+        """``attend`` of a decode dispatch: GQA over the K/V pools, or
+        latent attention absorbed (q_nope W_uk^T scores the cached latents
+        beside q_pe, the weighted latents go through W_uv)."""
+        from ..kernels.ops import paged_attention, paged_mla_decode
+
+        if not self.model.cfg.mla:
+            window = self.model.attn_cfg.window
+            return lambda q, kp, vp: paged_attention(
+                q, kp, vp, page_table, lengths, window=window)
+        mcfg = self.model.mla_cfg
+
+        def attend(q_nope, q_pe, pool, p):
+            o_lat = paged_mla_decode(
+                mla_absorb(p, q_nope, q_pe), pool, page_table, lengths,
+                scale=mcfg.softmax_scale(), dv=mcfg.kv_lora_rank)
+            return mla_values(p, o_lat)
+        return attend
+
+    def _prefill_attend(self, page_table, lengths, start):
+        """``attend`` of a prefill dispatch of S rows at absolute positions
+        ``start ..``: GQA over the K/V pools, or latent attention in the
+        plain form — the request's cached latents (the table's pages, this
+        dispatch's rows written) expanded to per-head keys and values, and
+        the flash-attention kernel over them with the S query rows at their
+        absolute positions of a zero query block as long as the context,
+        so causality alone keeps every row to its own prefix."""
+        from ..kernels.ops import flash_attention, paged_prefill
+
+        if not self.model.cfg.mla:
+            window = self.model.attn_cfg.window
+            return lambda q, kp, vp: paged_prefill(
+                q, kp, vp, page_table, lengths, window=window)
+        mcfg = self.model.mla_cfg
+
+        def attend(q_nope, q_pe, pool, p):
+            S = q_nope.shape[0]
+            ctx = pool[jnp.maximum(page_table, 0)]
+            # S zero rows past the table's end: a bucket-padded row never
+            # reaches past the context, whatever ``start`` is.
+            ctx = jnp.pad(ctx.reshape(-1, ctx.shape[-1]), ((0, S), (0, 0)))
+            q, k, v = mla_unabsorbed(p, q_nope, q_pe, ctx, mcfg)
+            qf = jax.lax.dynamic_update_slice_in_dim(
+                jnp.zeros(k.shape, q.dtype), q, start, 0)
+            dv = v.shape[-1]
+            vf = jnp.pad(v, ((0, 0), (0, 0), (0, k.shape[-1] - dv)))
+            o = flash_attention(qf[None], k[None], vf[None], causal=True,
+                                scale=mcfg.softmax_scale())[0]
+            return jax.lax.dynamic_slice_in_dim(o, start, S)[..., :dv]
+        return attend
 
     def _ffn_half(self, lp, x, h2, lane_mask):
-        """FFN + residual, the second half of ``_layer_body``."""
-        model = self.model
-        if model.cfg.family == "moe":
+        """FFN + residual, the second half of ``_layer_body``: the routed
+        experts (plus the shared ones), or a dense MLP."""
+        if "moe" in lp:
             # Same dropless routing + grouped GEMM as model.prefill, so a
             # token's expert assignment never depends on how the stream is
             # chunked or batched.
-            d = moe_decode(lp["moe"], h2, model.moe_cfg)
+            d = moe_decode(lp["moe"], h2, self.model.moe_cfg)
+            if "shared" in lp:
+                d = d + mlp(lp["shared"], h2)
         else:
             d = mlp(lp["mlp"], h2)
         return x + jnp.where(lane_mask, d, 0)
@@ -627,37 +723,34 @@ class Engine:
         (the sampler short-circuits ``temperature<=0`` to the same argmax),
         so the scheduler picks per batch and each compiles only on first
         use — a pure-greedy workload never compiles the sampled path."""
-        model = self.model
-        acfg = model.attn_cfg
-        from ..kernels.ops import paged_attention, sample_tokens
+        from ..kernels.ops import sample_tokens
 
-        def step(params, k_pool, v_pool, tokens, page_table, lengths,
+        def step(params, pools, tokens, page_table, lengths,
                  write_slot, write_off, active, seeds, temperature, top_k,
                  top_p):
-            """tokens: (B,1); page_table: (B,MP) HBM slots or -1;
-            lengths: (B,) incl. new token; write_slot/off: (B,) where the
-            new token's KV goes; active: (B,) bool — inactive rows are
-            masked to deterministic zeros rather than carrying garbage;
+            """pools: the pool's arrays; tokens: (B,1); page_table: (B,MP)
+            HBM slots or -1; lengths: (B,) incl. new token;
+            write_slot/off: (B,) where the new token's KV goes; active:
+            (B,) bool — inactive rows are masked to deterministic zeros
+            rather than carrying garbage;
             seeds/temperature/top_k/top_p: (B,) per-request sampling knobs
             (the sampler runs INSIDE this dispatch, with the next token's
             stream position ``lengths`` as the PRNG fold — the replay
             contract)."""
             x = jnp.take(params["embed"]["tok"], tokens, axis=0)  # (B,1,d)
+            attend = self._decode_attend(page_table, lengths)
 
             def body(carry, xs):
-                lp, kp, vp = xs          # kp/vp: (N,P,K,dh)
-                x, kp, vp = self._layer_body(
-                    lp, carry, kp, vp,
+                lp, *pl = xs             # pl: each pool's (N,P,K,dh)
+                return self._layer_body(
+                    lp, carry, tuple(pl),
                     positions=(lengths - 1)[:, None],
                     write_slot=write_slot, write_off=write_off,
                     row_mask=active, lane_mask=active[:, None, None],
                     rows=lambda t: t[:, 0], unrows=lambda y: y[:, None],
-                    attend=lambda q, kp, vp: paged_attention(
-                        q, kp, vp, page_table, lengths, window=acfg.window))
-                return x, (kp, vp)
+                    attend=attend)
 
-            x, (nk, nv) = jax.lax.scan(
-                body, x, (params["layers"], k_pool, v_pool))
+            x, pools = self.model.scan_stacks(body, x, params, pools)
             x = rmsnorm(params["final_ln"], x)
             logits = lm_head(params["head"], x)[:, 0]
             logits = jnp.where(active[:, None], logits, 0.0)
@@ -666,7 +759,7 @@ class Engine:
                                             temperature, top_k, top_p)
             else:
                 next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return logits, next_tokens, nk, nv
+            return logits, next_tokens, pools
 
         return step
 
@@ -675,16 +768,15 @@ class Engine:
         """One-shot prompt ingestion: a single jitted call writes S tokens'
         K/V into their page-table slots and attends with per-token causal
         lengths via ``ops.paged_prefill`` — the same layer body decode
-        runs, so the result is bitwise-equal to chunked ingestion."""
-        model = self.model
-        acfg = model.attn_cfg
-        from ..kernels.ops import paged_prefill
-
-        def prefill(params, k_pool, v_pool, tokens, page_table, slots, offs,
+        runs, so the result is bitwise-equal to chunked ingestion.  Latent
+        attention prefills in the plain form and decodes absorbed: equal
+        in exact arithmetic, not bitwise (``_prefill_attend``)."""
+        def prefill(params, pools, tokens, page_table, slots, offs,
                     n_real, start):
-            """tokens: (S,) padded suffix; page_table: (MP,) the request's
-            pages (cache-covered prefix included); slots/offs: (S,) physical
-            write target per token (the scratch slot for padded rows);
+            """pools: the pool's arrays; tokens: (S,) padded suffix;
+            page_table: (MP,) the request's pages (cache-covered prefix
+            included); slots/offs: (S,) physical write target per token
+            (the scratch slot for padded rows);
             n_real: () int32 live rows; start: () int32 absolute position of
             row 0 (0 for an uncached prompt, the covered token count after a
             prefix-cache hit — traced, so hits never recompile).  Rows
@@ -696,22 +788,20 @@ class Engine:
             valid = local < n_real
             lengths = jnp.where(valid, positions + 1, 0)
             x = jnp.take(params["embed"]["tok"], tokens[None], axis=0)
+            attend = self._prefill_attend(page_table, lengths, start)
 
             def body(carry, xs):
-                lp, kp, vp = xs
-                x, kp, vp = self._layer_body(
-                    lp, carry, kp, vp,
+                lp, *pl = xs
+                return self._layer_body(
+                    lp, carry, tuple(pl),
                     positions=positions[None],
                     write_slot=slots, write_off=offs,
                     row_mask=valid, lane_mask=valid[None, :, None],
                     rows=lambda t: t[0], unrows=lambda y: y[None],
-                    attend=lambda q, kp, vp: paged_prefill(
-                        q, kp, vp, page_table, lengths, window=acfg.window))
-                return x, (kp, vp)
+                    attend=attend)
 
-            _, (nk, nv) = jax.lax.scan(
-                body, x, (params["layers"], k_pool, v_pool))
-            return nk, nv
+            _, pools = self.model.scan_stacks(body, x, params, pools)
+            return pools
 
         return prefill
 
@@ -733,39 +823,70 @@ class Engine:
         the CPU (on the TPU the two programs round differently, so there
         the tiered result is pinned across cache sizes instead).  While
         one layer's FFN dispatch is in flight the store stages the next
-        layer's predicted experts (double buffer).
+        layer's predicted experts (double buffer).  A leading dense layer
+        is one jitted piece (attention and its MLP) with nothing to read
+        back; shared experts are added to the routed output inside the
+        FFN piece.
         """
         model = self.model
-        acfg = model.attn_cfg
         mcfg = model.moe_cfg
-        from ..kernels.ops import paged_attention, paged_prefill, sample_tokens
+        nd = model.cfg.first_dense_layers
+        from ..kernels.ops import sample_tokens
 
         def slice_layer(tree, l):
             return jax.tree_util.tree_map(
                 lambda a: jax.lax.dynamic_index_in_dim(
                     a, l, 0, keepdims=False), tree)
 
+        def put_layer(pools, pl, l):
+            return tuple(jax.lax.dynamic_update_index_in_dim(a, b, l, 0)
+                         for a, b in zip(pools, pl))
+
         def embed(params, tokens):
             return jnp.take(params["embed"]["tok"], tokens, axis=0)
 
-        def decode_attn(params, k_pool, v_pool, x, l, page_table, lengths,
-                        write_slot, write_off, active):
-            lp = slice_layer(params["layers"], l)
-            kp = jax.lax.dynamic_index_in_dim(k_pool, l, 0, keepdims=False)
-            vp = jax.lax.dynamic_index_in_dim(v_pool, l, 0, keepdims=False)
-            x, h2, kp, vp = self._attn_half(
-                lp, x, kp, vp, positions=(lengths - 1)[:, None],
-                write_slot=write_slot, write_off=write_off,
-                row_mask=active, lane_mask=active[:, None, None],
-                rows=lambda t: t[:, 0], unrows=lambda y: y[:, None],
-                attend=lambda q, kp, vp: paged_attention(
-                    q, kp, vp, page_table, lengths, window=acfg.window))
+        # ``l`` is the model's layer (the pools' index); a MoE layer's
+        # weights sit at l - first_dense_layers of ``params["layers"]``.
+        def decode_args(page_table, lengths, write_slot, write_off, active):
+            return dict(positions=(lengths - 1)[:, None],
+                        write_slot=write_slot, write_off=write_off,
+                        row_mask=active, lane_mask=active[:, None, None],
+                        rows=lambda t: t[:, 0], unrows=lambda y: y[:, None],
+                        attend=self._decode_attend(page_table, lengths))
+
+        def prefill_args(page_table, slots, offs, positions, lengths, valid):
+            return dict(positions=positions[None],
+                        write_slot=slots, write_off=offs,
+                        row_mask=valid, lane_mask=valid[None, :, None],
+                        rows=lambda t: t[0], unrows=lambda y: y[None],
+                        attend=self._prefill_attend(page_table, lengths,
+                                                    positions[0]))
+
+        def attn_route(params, pools, x, l, args):
+            lp = slice_layer(params["layers"], l - nd if nd else l)
+            pl = slice_layer(pools, l)
+            x, h2, pl = self._attn_half(lp, x, pl, **args)
             B, S, d = h2.shape
             gates, experts = route_tokens(
                 lp["moe"]["router"], h2.reshape(B * S, d), mcfg)
-            k_pool = jax.lax.dynamic_update_index_in_dim(k_pool, kp, l, 0)
-            v_pool = jax.lax.dynamic_update_index_in_dim(v_pool, vp, l, 0)
-            return x, h2, gates, experts, k_pool, v_pool
+            return x, h2, gates, experts, put_layer(pools, pl, l)
+
+        def dense_layer(params, pools, x, l, args):
+            # A leading dense layer: attention and its MLP in one piece,
+            # no expert in it and nothing for the host to read.
+            lp = slice_layer(params["dense_layers"], l)
+            x, pl = self._layer_body(lp, x, slice_layer(pools, l), **args)
+            return x, put_layer(pools, pl, l)
+
+        def decode_attn(params, pools, x, l, page_table, lengths,
+                        write_slot, write_off, active):
+            return attn_route(params, pools, x, l, decode_args(
+                page_table, lengths, write_slot, write_off, active))
+
+        def decode_dense(params, pools, x, l, page_table, lengths,
+                         write_slot, write_off, active):
+            return dense_layer(params, pools, x, l, decode_args(
+                page_table, lengths, write_slot, write_off, active))
 
         def prefill_prologue(params, tokens, n_real, start):
             S = tokens.shape[0]
@@ -776,28 +897,24 @@ class Engine:
             x = jnp.take(params["embed"]["tok"], tokens[None], axis=0)
             return x, positions, lengths, valid
 
-        def prefill_attn(params, k_pool, v_pool, x, l, page_table, slots,
-                         offs, positions, lengths, valid):
-            lp = slice_layer(params["layers"], l)
-            kp = jax.lax.dynamic_index_in_dim(k_pool, l, 0, keepdims=False)
-            vp = jax.lax.dynamic_index_in_dim(v_pool, l, 0, keepdims=False)
-            x, h2, kp, vp = self._attn_half(
-                lp, x, kp, vp, positions=positions[None],
-                write_slot=slots, write_off=offs,
-                row_mask=valid, lane_mask=valid[None, :, None],
-                rows=lambda t: t[0], unrows=lambda y: y[None],
-                attend=lambda q, kp, vp: paged_prefill(
-                    q, kp, vp, page_table, lengths, window=acfg.window))
-            B, S, d = h2.shape
-            gates, experts = route_tokens(
-                lp["moe"]["router"], h2.reshape(B * S, d), mcfg)
-            k_pool = jax.lax.dynamic_update_index_in_dim(k_pool, kp, l, 0)
-            v_pool = jax.lax.dynamic_update_index_in_dim(v_pool, vp, l, 0)
-            return x, h2, gates, experts, k_pool, v_pool
+        def prefill_attn(params, pools, x, l, page_table, slots, offs,
+                         positions, lengths, valid):
+            return attn_route(params, pools, x, l, prefill_args(
+                page_table, slots, offs, positions, lengths, valid))
 
-        def ffn(x, h2, wg, wu, wd, slot_map, gates, experts, lane_mask):
+        def prefill_dense(params, pools, x, l, page_table, slots, offs,
+                          positions, lengths, valid):
+            return dense_layer(params, pools, x, l, prefill_args(
+                page_table, slots, offs, positions, lengths, valid))
+
+        def ffn(x, h2, wg, wu, wd, slot_map, gates, experts, lane_mask,
+                shared=None, l=None):
+            """``shared``: the shared experts' stacked weights, layer ``l``
+            of them added to the routed output."""
             d = apply_dropless_flat(gates, experts, h2, wg, wu, wd, mcfg,
                                     expert_slots=slot_map)
+            if shared is not None:
+                d = d + mlp(slice_layer(shared, l), h2)
             return x + jnp.where(lane_mask, d, 0)
 
         def spec_route(params, x, nl):
@@ -837,8 +954,10 @@ class Engine:
         self._t_embed = _jit(embed)
         self._t_spec_route = _jit(spec_route)
         self._t_decode_attn = _jit(decode_attn)
+        self._t_decode_dense = _jit(decode_dense)
         self._t_prefill_prologue = _jit(prefill_prologue)
         self._t_prefill_attn = _jit(prefill_attn)
+        self._t_prefill_dense = _jit(prefill_dense)
         self._t_ffn = _jit(ffn)
         self._t_tail_greedy = _jit(make_tail(False))
         self._t_tail_sampled = _jit(make_tail(True))
@@ -849,6 +968,7 @@ class Engine:
         resident (commit the in-flight prefetch / demand-fetch misses /
         evict), dispatch the FFN against cache slots, then put the
         NEXT layer's predicted experts in flight while this FFN runs.
+        ``l`` counts MoE layers (the store's layers), from 0.
 
         ``token_mask`` (host bool, one per routed token) excludes padded
         prefill rows and inactive batch rows from the residency working
@@ -870,10 +990,13 @@ class Engine:
         # them at row 0: those rows' outputs are zeroed by lane_mask, and a
         # -1 would be an out-of-bounds weight DMA (the TPU halts on it).
         slots = jnp.asarray(np.maximum(slot_map, 0))
+        shared = self.params["layers"].get("shared")
         with span("repro.dispatch.ffn", layer=l):
-            x = self._t_ffn(x, h2, store.w_gate_cache, store.w_up_cache,
-                            store.w_down_cache, slots, gates, experts,
-                            lane_mask)
+            args = (x, h2, store.w_gate_cache, store.w_up_cache,
+                    store.w_down_cache, slots, gates, experts, lane_mask)
+            if shared is not None:
+                args += (shared, jnp.int32(l))
+            x = self._t_ffn(*args)
         # Double buffer: stage the next dispatch's predicted working set
         # while this one's grouped GEMM is in flight.  Within a step the
         # prediction is speculative gating — the next layer's router run
@@ -881,7 +1004,7 @@ class Engine:
         # wrap-around the next step's first dispatch routes a token that
         # does not exist yet, so the store falls back to recency + the
         # guidance profile.
-        nl = (l + 1) % self.model.cfg.n_layers
+        nl = (l + 1) % self.model.cfg.moe_layers
         if nl:
             self._spec_prefetch(nl, x, token_mask)
         elif wrap_prefetch:
@@ -914,14 +1037,20 @@ class Engine:
                        use_sampler, mask):
         """``mask`` is the host copy of ``active``."""
         x = self._t_embed(self.params, tokens)
-        kq, vq = self.pool.k_hbm, self.pool.v_hbm
+        pools = self.pool.hbm
         lane = active[:, None, None]
-        for l in range(self.model.cfg.n_layers):
-            with span("repro.dispatch.attn", layer=l):
-                x, h2, gates, experts, kq, vq = self._t_decode_attn(
-                    self.params, kq, vq, x, jnp.int32(l), page_table,
+        mc = self.model.cfg
+        nd, kind = mc.first_dense_layers, "mla" if mc.mla else "gqa"
+        for l in range(mc.n_layers):
+            args = (self.params, pools, x, jnp.int32(l), page_table,
                     lengths, write_slot, write_off, active)
-            x = self._tiered_ffn(l, x, h2, gates, experts, lane, mask,
+            if l < nd:
+                with span("repro.dispatch.dense", layer=l):
+                    x, pools = self._t_decode_dense(*args)
+                continue
+            with span("repro.dispatch.attn", layer=l, kind=kind):
+                x, h2, gates, experts, pools = self._t_decode_attn(*args)
+            x = self._tiered_ffn(l - nd, x, h2, gates, experts, lane, mask,
                                  wrap_prefetch=False)
         tail = self._t_tail_sampled if use_sampler else self._t_tail_greedy
         with span("repro.dispatch.tail"):
@@ -936,27 +1065,33 @@ class Engine:
         if self.expert_store.double_buffer:
             self._spec_prefetch(
                 0, self._t_embed(self.params, next_tokens[:, None]), mask)
-        return logits, next_tokens, kq, vq
+        return logits, next_tokens, pools
 
     def _tiered_prefill(self, tokens, page_table, slots, offs, n_real,
                         start, mask):
         """``mask`` is the host copy of the prologue's ``valid``."""
         x, positions, lengths, valid = self._t_prefill_prologue(
             self.params, tokens, n_real, start)
-        kq, vq = self.pool.k_hbm, self.pool.v_hbm
+        pools = self.pool.hbm
         lane = valid[None, :, None]
-        for l in range(self.model.cfg.n_layers):
-            with span("repro.dispatch.attn", layer=l):
-                x, h2, gates, experts, kq, vq = self._t_prefill_attn(
-                    self.params, kq, vq, x, jnp.int32(l), page_table, slots,
+        mc = self.model.cfg
+        nd, kind = mc.first_dense_layers, "mla" if mc.mla else "gqa"
+        for l in range(mc.n_layers):
+            args = (self.params, pools, x, jnp.int32(l), page_table, slots,
                     offs, positions, lengths, valid)
-            x = self._tiered_ffn(l, x, h2, gates, experts, lane, mask)
-        return kq, vq
+            if l < nd:
+                with span("repro.dispatch.dense", layer=l):
+                    x, pools = self._t_prefill_dense(*args)
+                continue
+            with span("repro.dispatch.attn", layer=l, kind=kind):
+                x, h2, gates, experts, pools = self._t_prefill_attn(*args)
+            x = self._tiered_ffn(l - nd, x, h2, gates, experts, lane, mask)
+        return pools
 
     def _run_prefill(self, tokens, page_table, slots, offs, n_real, start):
         """One bucketed prefill dispatch: the resident single-jit scan, or
         the layer-by-layer tiered pipeline when expert weights are
-        off-chip.  Both return the updated (nk, nv) pools."""
+        off-chip.  Both return the updated pool arrays."""
         args = (jnp.asarray(tokens), jnp.asarray(page_table),
                 jnp.asarray(slots), jnp.asarray(offs), jnp.int32(n_real),
                 jnp.int32(start))
@@ -964,8 +1099,7 @@ class Engine:
             return self._tiered_prefill(
                 *args, mask=np.arange(len(tokens)) < n_real)
         with span("repro.dispatch.step"):
-            return self._prefill(self.params, self.pool.k_hbm,
-                                 self.pool.v_hbm, *args)
+            return self._prefill(self.params, self.pool.hbm, *args)
 
     def _read(self, what: str, value, **args) -> np.ndarray:
         """A blocking device->host read on the step path: the host waits
@@ -1353,8 +1487,8 @@ class Engine:
         table = np.full((MP,), -1, np.int32)
         for p in my_pages:
             table[p.index_in_seq] = p.hbm_slot
-        nk, nv = self._run_prefill(tokens, table, slots, offs, n, start)
-        self.pool.k_hbm, self.pool.v_hbm = nk, nv
+        self.pool.hbm = self._run_prefill(tokens, table, slots, offs, n,
+                                          start)
         req.pos = start + n
         for idx in written:
             if not by_index[idx].shared:
@@ -1512,9 +1646,8 @@ class Engine:
         table = np.full((MP,), -1, np.int32)
         for p in self.pool.request_pages(rid):
             table[p.index_in_seq] = p.hbm_slot
-        nk, nv = self._run_prefill(tokens, table, slots, offs, n_suffix,
-                                   covered)
-        self.pool.k_hbm, self.pool.v_hbm = nk, nv
+        self.pool.hbm = self._run_prefill(tokens, table, slots, offs,
+                                          n_suffix, covered)
         req.pos = n_ingest
         for i, p in enumerate(pages):
             p.accesses += 1         # the dispatch's access set: every page
@@ -1756,14 +1889,13 @@ class Engine:
                 jnp.asarray(temperature), jnp.asarray(top_k),
                 jnp.asarray(top_p))
         if self.expert_store is not None:
-            logits, toks, nk, nv = self._tiered_decode(
+            logits, toks, self.pool.hbm = self._tiered_decode(
                 *args, use_sampler=not greedy, mask=active)
         else:
             decode = self._decode_greedy if greedy else self._decode_sampled
             with span("repro.dispatch.step"):
-                logits, toks, nk, nv = decode(
-                    self.params, self.pool.k_hbm, self.pool.v_hbm, *args)
-        self.pool.k_hbm, self.pool.v_hbm = nk, nv
+                logits, toks, self.pool.hbm = decode(
+                    self.params, self.pool.hbm, *args)
         if self.cfg.keep_logits:
             logits_np = self._read("tokens", logits)
             for i, (req, _) in enumerate(pairs):

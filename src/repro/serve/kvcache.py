@@ -2,7 +2,9 @@
 the paper's arenas.
 
 Layout: one global pool of pages per tier; a page holds ``page_size`` tokens
-of K and V for *all* layers: (L, page_size, K, dh).  Pages migrate between
+of K and V for *all* layers: (L, page_size, K, dh) in each of the pool's
+arrays — two (K and V) for grouped-query attention, one for multi-head
+latent attention, whose cached latent serves as keys and values both.  Pages migrate between
 the HBM pool (memory kind "device") and the host tier ("pinned_host"
 slabs, ``serve/tier_io.HostTier``) as whole blocks — they are the
 ``ChunkStats`` chunks the fragmentation engine reasons about, and each
@@ -59,8 +61,7 @@ class PageExport:
     tokens_used: List[int]
     accesses: List[float]
     fast: List[bool]              # source-tier residency (True = HBM)
-    k: np.ndarray                 # (L, n, P, K, dh)
-    v: np.ndarray
+    rows: Tuple[np.ndarray, ...]  # per pool array, (L, n, P, K, dh)
     n_layers: int
     shape: Tuple[int, ...]        # (page_size, kv_heads, head_dim)
 
@@ -79,7 +80,7 @@ class PageExport:
             tokens_used=[self.tokens_used[i] for i in rows],
             accesses=[self.accesses[i] for i in rows],
             fast=[self.fast[i] for i in rows],
-            k=self.k[:, rows], v=self.v[:, rows],
+            rows=tuple(a[:, rows] for a in self.rows),
             n_layers=self.n_layers, shape=self.shape)
 
 
@@ -112,24 +113,26 @@ class Page:
 class PagedKVPool:
     """Two-tier physical page pools + logical page bookkeeping.
 
-    The HBM tier is one ``(L, hbm_pages, P, K, dh)`` array per K and V on
-    ``device``; the host tier (``host``, a ``tier_io.HostTier``) parks
-    pages in pinned_host slabs, one slab per request per migration batch,
-    addressed by host slot.
+    The HBM tier ``hbm`` is ``n_arrays`` arrays (K and V, or one latent)
+    of ``(L, hbm_pages, P, K, dh)`` on ``device`` — ``(L, hbm_pages, P,
+    dh)`` with ``kv_heads=0``, a row of dh values per token; the host tier
+    (``host``, a ``tier_io.HostTier``) parks pages in pinned_host slabs,
+    one slab per request per migration batch, addressed by host slot.
     """
 
     def __init__(self, n_layers: int, page_size: int, kv_heads: int,
                  head_dim: int, hbm_pages: int, host_pages: int,
-                 dtype=jnp.bfloat16, device=None):
-        self.shape = (page_size, kv_heads, head_dim)
+                 dtype=jnp.bfloat16, device=None, n_arrays: int = 2):
+        self.shape = ((page_size, kv_heads, head_dim) if kv_heads
+                      else (page_size, head_dim))
         self.page_size = page_size
         self.n_layers = n_layers
         self.device = device if device is not None else jax.devices()[0]
         self.host = HostTier(self.device, axis=1)
         pool_shape = (n_layers, hbm_pages) + self.shape
         dev = self.host.device_sharding
-        self.k_hbm = jnp.zeros(pool_shape, dtype, device=dev)
-        self.v_hbm = jnp.zeros(pool_shape, dtype, device=dev)
+        self.hbm = tuple(jnp.zeros(pool_shape, dtype, device=dev)
+                         for _ in range(n_arrays))
 
         self.hbm_pages = hbm_pages
         self.host_pages = host_pages
@@ -159,7 +162,7 @@ class PagedKVPool:
         n = self.n_layers
         for s in self.shape:
             n *= s
-        return 2 * n * self.k_hbm.dtype.itemsize  # K and V
+        return len(self.hbm) * n * self.hbm[0].dtype.itemsize
 
     def allocate(self, request_id: int, index_in_seq: int,
                  step: int) -> Page:
@@ -267,9 +270,8 @@ class PagedKVPool:
                    tokens_used=src.tokens_used, last_used=step)
         self._next_id += 1
         self.pages[new.page_id] = new
-        self.k_hbm, self.v_hbm = copy_rows(
-            (self.k_hbm, self.v_hbm), [slot],
-            [gather_rows((self.k_hbm, self.v_hbm), [src.hbm_slot], axis=1)],
+        self.hbm = copy_rows(
+            self.hbm, [slot], [gather_rows(self.hbm, [src.hbm_slot], axis=1)],
             [(0, 0)], axis=1)
         seq[at] = new
         self.free(page_id)               # drop the request's old reference
@@ -287,22 +289,20 @@ class PagedKVPool:
                 (page.hbm_slot, slot))
         self.host.park([
             ([s for _, s in pairs],
-             gather_rows((self.k_hbm, self.v_hbm), [h for h, _ in pairs],
-                         axis=1))
+             gather_rows(self.hbm, [h for h, _ in pairs], axis=1))
             for pairs in groups.values()])
-        self.transfer_events += 2            # one per pool array (K, V)
+        self.transfer_events += len(self.hbm)     # one per pool array
 
     def _land(self, hbm_slots: Sequence[int], staged, locs) -> None:
         """Write staged rows (``HostTier.fetch`` output, or one numpy-fed
         slab) into HBM slots in place."""
-        self.k_hbm, self.v_hbm = copy_rows(
-            (self.k_hbm, self.v_hbm), hbm_slots, staged, locs, axis=1)
+        self.hbm = copy_rows(self.hbm, hbm_slots, staged, locs, axis=1)
 
     def _unpark(self, host_slots: Sequence[int]):
         """host -> device: ONE batched transfer per pool array of the slabs
         holding the pages; ``_land`` writes them into HBM."""
         staged, locs = self.host.fetch(host_slots)
-        self.transfer_events += 2
+        self.transfer_events += len(self.hbm)
         return staged, locs
 
     def _release_host(self, slots: Sequence[int]) -> None:
@@ -420,21 +420,19 @@ class PagedKVPool:
                 f"cannot export pages {missing}: unknown or freed ids")
         pages = [self.pages[pid] for pid in ids]
         n = len(pages)
-        k = np.zeros((self.n_layers, n) + self.shape, self.k_hbm.dtype)
-        v = np.zeros_like(k)
+        rows = tuple(np.zeros((self.n_layers, n) + self.shape, a.dtype)
+                     for a in self.hbm)
         fast_rows = [i for i, p in enumerate(pages) if p.hbm_slot is not None]
         slow_rows = [i for i, p in enumerate(pages) if p.hbm_slot is None]
         if fast_rows:
-            kf, vf = jax.device_get(gather_rows(
-                (self.k_hbm, self.v_hbm),
-                [pages[i].hbm_slot for i in fast_rows], axis=1))
-            k[:, fast_rows] = kf[:, :len(fast_rows)]
-            v[:, fast_rows] = vf[:, :len(fast_rows)]
+            got = jax.device_get(gather_rows(
+                self.hbm, [pages[i].hbm_slot for i in fast_rows], axis=1))
+            for out, g in zip(rows, got):
+                out[:, fast_rows] = g[:, :len(fast_rows)]
         if slow_rows:
-            k_rows, v_rows = self.host.read(
-                [pages[i].host_slot for i in slow_rows])
-            k[:, slow_rows] = np.stack(k_rows, axis=1)
-            v[:, slow_rows] = np.stack(v_rows, axis=1)
+            got = self.host.read([pages[i].host_slot for i in slow_rows])
+            for out, g in zip(rows, got):
+                out[:, slow_rows] = np.stack(g, axis=1)
         self.exported_pages += n
         return PageExport(
             page_ids=[p.page_id for p in pages],
@@ -442,7 +440,7 @@ class PagedKVPool:
             tokens_used=[p.tokens_used for p in pages],
             accesses=[p.accesses for p in pages],
             fast=[p.hbm_slot is not None for p in pages],
-            k=k, v=v, n_layers=self.n_layers, shape=self.shape)
+            rows=rows, n_layers=self.n_layers, shape=self.shape)
 
     def import_pages(self, export: PageExport, request_id: int,
                      step: int) -> List[Page]:
@@ -454,13 +452,14 @@ class PagedKVPool:
         pools combined cannot hold it (callers fall back to cold
         recompute).  One batched transfer per pool array per destination
         tier."""
-        if (export.n_layers, tuple(export.shape)) != (self.n_layers,
-                                                      tuple(self.shape)):
+        if ((export.n_layers, tuple(export.shape), len(export.rows))
+                != (self.n_layers, tuple(self.shape), len(self.hbm))):
             raise ValueError(
-                f"cannot import pages shaped {export.n_layers}x"
-                f"{tuple(export.shape)} into a pool shaped "
-                f"{self.n_layers}x{tuple(self.shape)}: replica engines "
-                f"must share one model/page geometry")
+                f"cannot import pages shaped {len(export.rows)}x"
+                f"{export.n_layers}x{tuple(export.shape)} into a pool "
+                f"shaped {len(self.hbm)}x{self.n_layers}x"
+                f"{tuple(self.shape)}: replica engines must share one "
+                f"model/page geometry")
         n = len(export)
         if n == 0:
             return []
@@ -488,14 +487,14 @@ class PagedKVPool:
             if not rows:
                 continue
             slots = [free.pop() for _ in rows]
-            staged = (export.k[:, rows], export.v[:, rows])
+            staged = tuple(a[:, rows] for a in export.rows)
             if is_fast:
                 self._land(slots, [tuple(jax.device_put(
                     staged, self.host.device_sharding))],
                     [(0, j) for j in range(len(rows))])
             else:
                 self.host.park([(slots, staged)])
-            self.transfer_events += 2
+            self.transfer_events += len(self.hbm)
             for i, slot in zip(rows, slots):
                 page = Page(
                     page_id=self._next_id, request_id=request_id,

@@ -83,8 +83,8 @@ def make_pool(seed=0):
     pool = PagedKVPool(n_layers=2, page_size=4, kv_heads=2, head_dim=8,
                        hbm_pages=8, host_pages=16, dtype=jnp.float32)
     rng = np.random.default_rng(seed)
-    pool.k_hbm = jnp.asarray(rng.normal(size=pool.k_hbm.shape), jnp.float32)
-    pool.v_hbm = jnp.asarray(rng.normal(size=pool.v_hbm.shape), jnp.float32)
+    pool.hbm = tuple(jnp.asarray(rng.normal(size=a.shape), jnp.float32)
+                     for a in pool.hbm)
     for idx in range(3):
         page = pool.allocate(0, idx, step=0)
         page.tokens_used = 4 if idx < 2 else 2
@@ -95,7 +95,7 @@ def make_pool(seed=0):
 
 def page_bytes(pool, page):
     if page.hbm_slot is not None:
-        k, v = pool.k_hbm[:, page.hbm_slot], pool.v_hbm[:, page.hbm_slot]
+        k, v = (a[:, page.hbm_slot] for a in pool.hbm)
     else:
         (k,), (v,) = pool.host.read([page.host_slot])
     return np.asarray(k).tobytes(), np.asarray(v).tobytes()
@@ -149,7 +149,7 @@ def test_select_from_keeps_only_trailing_blocks():
     export = src.export_pages([p.page_id for p in src.request_pages(0)])
     tail = export.select_from(2)
     assert len(tail) == 1 and tail.index_in_seq == [2]
-    assert tail.k.shape[1] == 1
+    assert [a.shape[1] for a in tail.rows] == [1, 1]
 
 
 # ------------------------------------------------------------- dispatch
@@ -436,7 +436,7 @@ def test_replicas_get_their_own_devices_and_drain_across_them():
         placement = []
         for rep in llm.cluster.replicas:
             e = rep.engine
-            devs = {e.device} | e.pool.k_hbm.devices()
+            devs = {e.device} | e.pool.hbm[0].devices()
             for a in jax.tree.leaves(e.params):
                 devs |= a.devices()
             placement.append(devs)

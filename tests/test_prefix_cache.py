@@ -96,17 +96,17 @@ def test_attach_enforces_prefix_order():
 def test_copy_page_gives_private_bitwise_copy():
     pool = small_pool()
     rng = np.random.default_rng(0)
-    pool.k_hbm = jnp.asarray(rng.normal(size=pool.k_hbm.shape), jnp.float32)
-    pool.v_hbm = jnp.asarray(rng.normal(size=pool.v_hbm.shape), jnp.float32)
+    pool.hbm = tuple(jnp.asarray(rng.normal(size=a.shape), jnp.float32)
+                     for a in pool.hbm)
     (src,) = full_pages(pool, 0, 1)
     pool.attach(1, src.page_id, step=0)
-    before_k = np.asarray(pool.k_hbm[:, src.hbm_slot])
+    before_k = np.asarray(pool.hbm[0][:, src.hbm_slot])
     new = pool.copy_page(src.page_id, 1, step=1)
     assert new.page_id != src.page_id
     assert src.refcount == 1                     # writer's ref moved over
     assert pool.request_pages(1) == [new]
     assert pool.request_pages(0) == [src]
-    assert np.array_equal(np.asarray(pool.k_hbm[:, new.hbm_slot]), before_k)
+    assert np.array_equal(np.asarray(pool.hbm[0][:, new.hbm_slot]), before_k)
 
 
 # ========================================================== radix cache

@@ -64,8 +64,7 @@ def request_pages_bits(eng, rid):
     out = []
     for p in eng.pool.request_pages(rid):
         assert p.hbm_slot is not None
-        out.append(np.asarray(eng.pool.k_hbm[:, p.hbm_slot]))
-        out.append(np.asarray(eng.pool.v_hbm[:, p.hbm_slot]))
+        out.extend(np.asarray(a[:, p.hbm_slot]) for a in eng.pool.hbm)
     return out
 
 

@@ -24,8 +24,8 @@ def make_pool(seed=0):
     pool = PagedKVPool(n_layers=2, page_size=4, kv_heads=2, head_dim=8,
                        hbm_pages=8, host_pages=16, dtype=jnp.float32)
     rng = np.random.default_rng(seed)
-    pool.k_hbm = jnp.asarray(rng.normal(size=pool.k_hbm.shape), jnp.float32)
-    pool.v_hbm = jnp.asarray(rng.normal(size=pool.v_hbm.shape), jnp.float32)
+    pool.hbm = tuple(jnp.asarray(rng.normal(size=a.shape), jnp.float32)
+                     for a in pool.hbm)
     for rid in (0, 1):
         for idx in range(3):
             pool.allocate(rid, idx, step=0)
@@ -46,8 +46,7 @@ def pool_bits(pool):
     k_rows, v_rows = pool.host.read(slots) if slots else ([], [])
     host = tuple((s, k.tobytes(), v.tobytes())
                  for s, k, v in zip(slots, k_rows, v_rows))
-    return (np.asarray(pool.k_hbm).tobytes(),
-            np.asarray(pool.v_hbm).tobytes(), host)
+    return tuple(np.asarray(a).tobytes() for a in pool.hbm) + (host,)
 
 
 def make_plan(placement):
@@ -100,7 +99,7 @@ def test_batched_roundtrip_preserves_contents():
     pool = make_pool(seed=7)
     resident = [p.page_id for p in pool.pages.values()
                 if p.hbm_slot is not None]
-    before = {pid: np.asarray(pool.k_hbm[:, pool.pages[pid].hbm_slot])
+    before = {pid: np.asarray(pool.hbm[0][:, pool.pages[pid].hbm_slot])
               for pid in resident}
     t0, s_in, s_out = pool.transfer_events, pool.swaps_in, pool.swaps_out
     pool.swap_out_many(resident)
@@ -110,7 +109,7 @@ def test_batched_roundtrip_preserves_contents():
     assert pool.swaps_out - s_out == len(resident)
     assert pool.swaps_in - s_in == len(resident)
     for pid in resident:
-        after = np.asarray(pool.k_hbm[:, pool.pages[pid].hbm_slot])
+        after = np.asarray(pool.hbm[0][:, pool.pages[pid].hbm_slot])
         assert np.array_equal(before[pid], after)
 
 
@@ -212,8 +211,8 @@ def test_host_tier_roundtrip_keeps_memory_kinds_and_bits():
     bitwise."""
     pool = make_pool(seed=11)
     ids = [p.page_id for p in pool.pages.values() if p.hbm_slot is not None]
-    before = {pid: (np.asarray(pool.k_hbm[:, pool.pages[pid].hbm_slot]),
-                    np.asarray(pool.v_hbm[:, pool.pages[pid].hbm_slot]))
+    before = {pid: tuple(np.asarray(a[:, pool.pages[pid].hbm_slot])
+                         for a in pool.hbm)
               for pid in ids}
     pool.swap_out_many(ids)
     assert pool.host.memory_kinds() == {"pinned_host"}
@@ -222,12 +221,12 @@ def test_host_tier_roundtrip_keeps_memory_kinds_and_bits():
         assert np.array_equal(k, before[pid][0])
         assert np.array_equal(v, before[pid][1])
     pool.swap_in_many(ids)
-    assert pool.k_hbm.sharding.memory_kind == "device"
-    assert pool.v_hbm.sharding.memory_kind == "device"
+    assert [a.sharding.memory_kind for a in pool.hbm] == ["device"] * 2
     for pid in ids:
         slot = pool.pages[pid].hbm_slot
-        assert np.array_equal(np.asarray(pool.k_hbm[:, slot]), before[pid][0])
-        assert np.array_equal(np.asarray(pool.v_hbm[:, slot]), before[pid][1])
+        k, v = (np.asarray(a[:, slot]) for a in pool.hbm)
+        assert np.array_equal(k, before[pid][0])
+        assert np.array_equal(v, before[pid][1])
     held = {p.host_slot for p in pool.pages.values()} - {None}
     assert {s for s in range(pool.host_pages) if s in pool.host} == held, \
         "a freed host slot must leave the host tier"

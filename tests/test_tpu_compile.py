@@ -1,5 +1,6 @@
 """The serving kernels compile for a TPU v5e at granite-moe-3b-a800m's
-widths — checked here, without the chip, against a described v5e topology.
+and DeepSeek-V2-Lite's widths — checked here, without the chip, against a
+described v5e topology.
 
 Each test compiles one Pallas kernel at the shapes the chip run serves and
 asserts the compiled program holds the Mosaic kernel (``tpu_custom_call``).
@@ -9,6 +10,7 @@ a module fixture, never at import, so every test worker collects the same
 tests and only the worker that runs this file loads the TPU library.
 """
 
+import functools
 import os
 
 import jax
@@ -16,9 +18,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.moe_gemm import moe_grouped_ffn_pallas
 from repro.kernels.paged_attention import (
     paged_attention_pallas,
+    paged_mla_decode_pallas,
     paged_prefill_pallas,
 )
 
@@ -29,6 +33,13 @@ N_PAGES, PAGE, MP = 1024, 16, 132
 S = 2048
 D, F, E, TOP_K = 1536, 512, 40, 8
 BF16, I32 = jnp.bfloat16, jnp.int32
+# DeepSeek-V2-Lite: 16 heads, a cached latent of 512 + 64, query and key
+# heads of 128 + 64, value heads of 128; 544 pages per sequence.
+MLA_H, MLA_L, MLA_C, MLA_QK, MLA_MP = 16, 576, 512, 192, 544
+MLA_SCALE = 1.5896 / 192 ** 0.5
+# Its experts: width 1,408 (11 x 128 lanes) at d_model 2,048, a cache of
+# 192 blocks, a decode batch of 32 rows with 6 picks each.
+MLA_D, MLA_F, MLA_SLOTS, MLA_E = 2048, 1408, 192, 64
 
 
 @pytest.fixture(scope="module")
@@ -73,3 +84,40 @@ def test_moe_grouped_ffn_compiles_for_v5e(one_chip):
         ((S * TOP_K, D), BF16), ((E, D, F), BF16), ((E, D, F), BF16),
         ((E, F, D), BF16), ((E,), I32))
     assert "tpu_custom_call" in text
+
+
+def test_paged_mla_decode_compiles_for_v5e(one_chip):
+    text = _compiled_text(
+        functools.partial(paged_mla_decode_pallas, scale=MLA_SCALE,
+                          dv=MLA_C), one_chip,
+        ((32, MLA_H, MLA_L), BF16), ((N_PAGES, PAGE, MLA_L), BF16),
+        ((32, MLA_MP), I32), ((32,), I32))
+    assert "tpu_custom_call" in text
+
+
+def test_flash_attention_mla_prefill_compiles_for_v5e(one_chip):
+    """The latent model's plain prefill: query block as long as the table's
+    context plus the bucket, heads of 192, the model's softmax scale."""
+    T = MLA_MP * PAGE + S
+    text = _compiled_text(
+        lambda q, k, v: flash_attention_pallas(q, k, v, True, None, False,
+                                               MLA_SCALE), one_chip,
+        ((1, T, MLA_H, MLA_QK), BF16), ((1, T, MLA_H, MLA_QK), BF16),
+        ((1, T, MLA_H, MLA_QK), BF16))
+    assert "tpu_custom_call" in text
+
+
+def test_moe_grouped_ffn_compiles_at_deepseek_widths_without_padding(
+        one_chip):
+    """At d 2,048 and width 1,408 the kernel fits VMEM, and no pad copies
+    the 192-block expert cache on each call."""
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in (
+                ((32 * 6, MLA_D), BF16), ((MLA_SLOTS, MLA_D, MLA_F), BF16),
+                ((MLA_SLOTS, MLA_D, MLA_F), BF16),
+                ((MLA_SLOTS, MLA_F, MLA_D), BF16), ((MLA_E,), I32),
+                ((MLA_E,), I32))]
+    compiled = jax.jit(moe_grouped_ffn_pallas).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    cache = MLA_SLOTS * MLA_D * MLA_F * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < cache // 8
