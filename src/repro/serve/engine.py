@@ -496,9 +496,14 @@ class Engine:
                         num_fragments=cfg.num_fragments,
                         skip_empty_intervals=True),
                     clock=lambda: self.step_count)
-        self._decode_greedy = _jit(self._build_decode(with_sampler=False))
-        self._decode_sampled = _jit(self._build_decode(with_sampler=True))
-        self._prefill = _jit(self._build_prefill())
+        # Every program that returns the pools updated takes them donated
+        # (argument 1), so XLA writes them in place instead of copying them
+        # whole; ``self.pool.hbm`` is re-bound to what it returns.
+        self._decode_greedy = _jit(self._build_decode(with_sampler=False),
+                                   donate_argnums=1)
+        self._decode_sampled = _jit(self._build_decode(with_sampler=True),
+                                    donate_argnums=1)
+        self._prefill = _jit(self._build_prefill(), donate_argnums=1)
         if self.expert_store is not None:
             self._build_tiered_closures()
         self.last_logits: Dict[int, np.ndarray] = {}
@@ -827,6 +832,16 @@ class Engine:
         is one jitted piece (attention and its MLP) with nothing to read
         back; shared experts are added to the routed output inside the
         FFN piece.
+
+        Ownership of the pools: every piece that writes a layer's K/V (or
+        latent) rows takes the whole pool tuple donated (argument 1) and
+        returns it, so XLA updates the one layer's slice in place instead
+        of copying every pool array whole on each call.  The donated
+        arrays are dead once the call is dispatched, so the caller re-binds
+        ``self.pool.hbm`` to the returned tuple after EVERY piece, not once
+        per step: an error between pieces (``ExpertCacheMissError`` in
+        ``_tiered_ffn``) then leaves the pool naming live arrays.  Nothing
+        else may hold a pool array across a piece.
         """
         model = self.model
         mcfg = model.moe_cfg
@@ -953,11 +968,11 @@ class Engine:
 
         self._t_embed = _jit(embed)
         self._t_spec_route = _jit(spec_route)
-        self._t_decode_attn = _jit(decode_attn)
-        self._t_decode_dense = _jit(decode_dense)
+        self._t_decode_attn = _jit(decode_attn, donate_argnums=1)
+        self._t_decode_dense = _jit(decode_dense, donate_argnums=1)
         self._t_prefill_prologue = _jit(prefill_prologue)
-        self._t_prefill_attn = _jit(prefill_attn)
-        self._t_prefill_dense = _jit(prefill_dense)
+        self._t_prefill_attn = _jit(prefill_attn, donate_argnums=1)
+        self._t_prefill_dense = _jit(prefill_dense, donate_argnums=1)
         self._t_ffn = _jit(ffn)
         self._t_tail_greedy = _jit(make_tail(False))
         self._t_tail_sampled = _jit(make_tail(True))
@@ -1035,21 +1050,22 @@ class Engine:
     def _tiered_decode(self, tokens, page_table, lengths, write_slot,
                        write_off, active, seeds, temperature, top_k, top_p,
                        use_sampler, mask):
-        """``mask`` is the host copy of ``active``."""
+        """``mask`` is the host copy of ``active``.  Re-binds
+        ``self.pool.hbm`` after each piece (the pieces take it donated)."""
         x = self._t_embed(self.params, tokens)
-        pools = self.pool.hbm
         lane = active[:, None, None]
         mc = self.model.cfg
         nd, kind = mc.first_dense_layers, "mla" if mc.mla else "gqa"
         for l in range(mc.n_layers):
-            args = (self.params, pools, x, jnp.int32(l), page_table,
+            args = (self.params, self.pool.hbm, x, jnp.int32(l), page_table,
                     lengths, write_slot, write_off, active)
             if l < nd:
                 with span("repro.dispatch.dense", layer=l):
-                    x, pools = self._t_decode_dense(*args)
+                    x, self.pool.hbm = self._t_decode_dense(*args)
                 continue
             with span("repro.dispatch.attn", layer=l, kind=kind):
-                x, h2, gates, experts, pools = self._t_decode_attn(*args)
+                x, h2, gates, experts, self.pool.hbm = \
+                    self._t_decode_attn(*args)
             x = self._tiered_ffn(l - nd, x, h2, gates, experts, lane, mask,
                                  wrap_prefetch=False)
         tail = self._t_tail_sampled if use_sampler else self._t_tail_greedy
@@ -1065,41 +1081,43 @@ class Engine:
         if self.expert_store.double_buffer:
             self._spec_prefetch(
                 0, self._t_embed(self.params, next_tokens[:, None]), mask)
-        return logits, next_tokens, pools
+        return logits, next_tokens
 
     def _tiered_prefill(self, tokens, page_table, slots, offs, n_real,
                         start, mask):
-        """``mask`` is the host copy of the prologue's ``valid``."""
+        """``mask`` is the host copy of the prologue's ``valid``.  Re-binds
+        ``self.pool.hbm`` after each piece, as ``_tiered_decode`` does."""
         x, positions, lengths, valid = self._t_prefill_prologue(
             self.params, tokens, n_real, start)
-        pools = self.pool.hbm
         lane = valid[None, :, None]
         mc = self.model.cfg
         nd, kind = mc.first_dense_layers, "mla" if mc.mla else "gqa"
         for l in range(mc.n_layers):
-            args = (self.params, pools, x, jnp.int32(l), page_table, slots,
-                    offs, positions, lengths, valid)
+            args = (self.params, self.pool.hbm, x, jnp.int32(l), page_table,
+                    slots, offs, positions, lengths, valid)
             if l < nd:
                 with span("repro.dispatch.dense", layer=l):
-                    x, pools = self._t_prefill_dense(*args)
+                    x, self.pool.hbm = self._t_prefill_dense(*args)
                 continue
             with span("repro.dispatch.attn", layer=l, kind=kind):
-                x, h2, gates, experts, pools = self._t_prefill_attn(*args)
+                x, h2, gates, experts, self.pool.hbm = \
+                    self._t_prefill_attn(*args)
             x = self._tiered_ffn(l - nd, x, h2, gates, experts, lane, mask)
-        return pools
 
     def _run_prefill(self, tokens, page_table, slots, offs, n_real, start):
         """One bucketed prefill dispatch: the resident single-jit scan, or
         the layer-by-layer tiered pipeline when expert weights are
-        off-chip.  Both return the updated pool arrays."""
+        off-chip.  Both write the pools in place (donated) and re-bind
+        ``self.pool.hbm``."""
         args = (jnp.asarray(tokens), jnp.asarray(page_table),
                 jnp.asarray(slots), jnp.asarray(offs), jnp.int32(n_real),
                 jnp.int32(start))
         if self.expert_store is not None:
-            return self._tiered_prefill(
-                *args, mask=np.arange(len(tokens)) < n_real)
-        with span("repro.dispatch.step"):
-            return self._prefill(self.params, self.pool.hbm, *args)
+            self._tiered_prefill(*args, mask=np.arange(len(tokens)) < n_real)
+        else:
+            with span("repro.dispatch.step"):
+                self.pool.hbm = self._prefill(self.params, self.pool.hbm,
+                                              *args)
 
     def _read(self, what: str, value, **args) -> np.ndarray:
         """A blocking device->host read on the step path: the host waits
@@ -1487,8 +1505,7 @@ class Engine:
         table = np.full((MP,), -1, np.int32)
         for p in my_pages:
             table[p.index_in_seq] = p.hbm_slot
-        self.pool.hbm = self._run_prefill(tokens, table, slots, offs, n,
-                                          start)
+        self._run_prefill(tokens, table, slots, offs, n, start)
         req.pos = start + n
         for idx in written:
             if not by_index[idx].shared:
@@ -1646,8 +1663,7 @@ class Engine:
         table = np.full((MP,), -1, np.int32)
         for p in self.pool.request_pages(rid):
             table[p.index_in_seq] = p.hbm_slot
-        self.pool.hbm = self._run_prefill(tokens, table, slots, offs,
-                                          n_suffix, covered)
+        self._run_prefill(tokens, table, slots, offs, n_suffix, covered)
         req.pos = n_ingest
         for i, p in enumerate(pages):
             p.accesses += 1         # the dispatch's access set: every page
@@ -1889,7 +1905,7 @@ class Engine:
                 jnp.asarray(temperature), jnp.asarray(top_k),
                 jnp.asarray(top_p))
         if self.expert_store is not None:
-            logits, toks, self.pool.hbm = self._tiered_decode(
+            logits, toks = self._tiered_decode(
                 *args, use_sampler=not greedy, mask=active)
         else:
             decode = self._decode_greedy if greedy else self._decode_sampled

@@ -422,3 +422,60 @@ def test_serving_summary_reports_expert_counters(moe_model):
     assert stats["expert_bytes_fetched"] > 0
     assert 0 <= stats["expert_resident_hits"] \
         <= stats["expert_needed_blocks"] > 0
+
+
+def spy_pools(engine, names):
+    """Wrap the engine programs ``names``: each call's pool tuple (argument
+    1) is kept, by program, for the test to inspect."""
+    handed = {n: [] for n in names}
+    for n in names:
+        def call(*args, _fn=getattr(engine, n), _seen=handed[n]):
+            _seen.append(args[1])
+            return _fn(*args)
+        setattr(engine, n, call)
+    return handed
+
+
+@pytest.mark.parametrize("offchip, programs", [
+    (False, ("_prefill", "_decode_greedy", "_decode_sampled")),
+    (True, ("_t_prefill_attn", "_t_decode_attn")),
+], ids=["resident", "tiered"])
+def test_programs_take_the_pools_donated(moe_model, offchip, programs):
+    """Every program that returns the pools updated consumes the arrays it
+    is handed (XLA writes them in place, never copies them whole), and the
+    pool names live arrays after every step."""
+    kw = dict(expert_offchip=True, expert_cache_size=8) if offchip else {}
+    llm = make_llm(moe_model, **kw)
+    engine = llm.cluster.replicas[0].engine
+    handed = spy_pools(engine, programs)
+    prompts = [[3, 1, 4, 1, 5, 9], [2, 7, 1, 8, 2, 8]]
+    params = [SamplingParams(max_tokens=6),
+              SamplingParams(max_tokens=3, temperature=0.8, seed=7)]
+    handles = [llm.submit(p, sp) for p, sp in zip(prompts, params)]
+    while any(not h.finished for h in handles):
+        llm.step()
+        assert not any(a.is_deleted() for a in engine.pool.hbm)
+    for name, calls in handed.items():
+        assert calls, f"{name} never ran"
+        assert all(a.is_deleted() for pools in calls for a in pools), \
+            f"{name} did not take the pools donated"
+    for a in engine.pool.hbm:
+        np.asarray(a)
+
+
+def test_cache_miss_mid_step_leaves_the_pools_live(moe_model):
+    """A prefill whose working set overflows the cache raises between the
+    layer pieces, after a piece consumed the pools it was handed: the pool
+    still names the arrays that piece returned, live and readable."""
+    llm = make_llm(moe_model, expert_offchip=True, expert_cache_size=4)
+    engine = llm.cluster.replicas[0].engine
+    handed = spy_pools(engine, ("_t_prefill_attn",))
+    with pytest.raises(ExpertCacheMissError, match="expert_cache_size"):
+        llm.submit(list(range(1, 13)), SamplingParams(max_tokens=2))
+        for _ in range(4):
+            llm.step()
+    assert all(a.is_deleted() for a in handed["_t_prefill_attn"][0])
+    pools = engine.pool.hbm
+    assert not any(a.is_deleted() for a in pools)
+    for a in pools:
+        assert np.isfinite(np.asarray(a)).all()

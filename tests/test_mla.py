@@ -337,3 +337,25 @@ def test_expert_store_covers_moe_layers_only(dsv2):
     llm.generate([PROMPTS[1]], SamplingParams(max_tokens=3))
     assert {b.layer for b in st.blocks.values()} == set(range(n_moe))
     assert st.needed_blocks > 0
+
+
+def test_dense_layer_pieces_take_the_pools_donated(dsv2):
+    """The leading dense layer's tiered pieces consume the latent pool they
+    are handed, as the MoE layers' pieces do, and the pool names live
+    arrays once the request is served."""
+    _, model, params = dsv2
+    llm = LLM(model, params, ServeConfig(
+        max_batch=2, page_size=4, hbm_pages=48, host_pages=64,
+        max_pages_per_seq=8, expert_offchip=True, expert_cache_size=0))
+    engine = llm.cluster.replicas[0].engine
+    handed = {n: [] for n in ("_t_prefill_dense", "_t_decode_dense")}
+    for n, seen in handed.items():
+        def call(*args, _fn=getattr(engine, n), _seen=seen):
+            _seen.append(args[1])
+            return _fn(*args)
+        setattr(engine, n, call)
+    llm.generate([PROMPTS[1]], SamplingParams(max_tokens=3))
+    for name, calls in handed.items():
+        assert calls, f"{name} never ran"
+        assert all(a.is_deleted() for pools in calls for a in pools), name
+    assert not any(a.is_deleted() for a in engine.pool.hbm)

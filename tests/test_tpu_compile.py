@@ -2,16 +2,21 @@
 and DeepSeek-V2-Lite's widths — checked here, without the chip, against a
 described v5e topology.
 
-Each test compiles one Pallas kernel at the shapes the chip run serves and
-asserts the compiled program holds the Mosaic kernel (``tpu_custom_call``).
-Interpret-mode tests cannot see what Mosaic refuses (unaligned reshapes,
-scalar-memory and VMEM limits); this can.  The topology is described inside
-a module fixture, never at import, so every test worker collects the same
-tests and only the worker that runs this file loads the TPU library.
+Each kernel test compiles one Pallas kernel at the shapes the chip run
+serves and asserts the compiled program holds the Mosaic kernel
+(``tpu_custom_call``).  Interpret-mode tests cannot see what Mosaic refuses
+(unaligned reshapes, scalar-memory and VMEM limits); this can.  The engine
+tests compile the tiered layer pieces at the chip cells' pool shapes and
+assert that none copies a whole pool (the pools are donated).  The
+topology is described inside a module fixture, never at import, so every
+test worker collects the same tests and only the worker that runs this
+file loads the TPU library.
 """
 
+import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -121,3 +126,74 @@ def test_moe_grouped_ffn_compiles_at_deepseek_widths_without_padding(
     assert "tpu_custom_call" in compiled.as_text()
     cache = MLA_SLOTS * MLA_D * MLA_F * 2
     assert compiled.memory_analysis().temp_size_in_bytes < cache // 8
+
+
+# The chip cells' engines: model overrides, pool shape (layers, pages,
+# page, [KV heads,] row) and arrays, decode batch, pages per sequence.
+ENGINES = {
+    "granite_moe_3b_a800m": ({}, (32, 2560, PAGE, K, DH), 2, 32, 160),
+    "deepseek_v2_lite": ({"n_layers": 7}, (7, 12288, PAGE, MLA_L), 1, 32,
+                         MLA_MP),
+}
+PREFILL_BUCKET = 512
+
+
+def _tiered_engine(arch, overrides):
+    """An engine holding only the model and its tiered layer pieces: the
+    pieces read nothing else, and the constructor would allocate the pools
+    and the weights."""
+    from repro.configs import get
+    from repro.models import build_model
+    from repro.serve.engine import Engine
+
+    engine = Engine.__new__(Engine)
+    engine.model = build_model(
+        dataclasses.replace(get(arch), remat=False, **overrides))
+    engine._build_tiered_closures()
+    return engine
+
+
+def _copies_of(text, shape):
+    """The compiled entry's ``copy``/``copy-start`` instructions whose
+    result holds an array of ``shape``."""
+    dims = "[" + ",".join(map(str, shape)) + "]"
+    return [line.strip() for line in text.splitlines()
+            if (m := re.search(r"= (.+?) copy(?:-start)?\(", line))
+            and dims in m.group(1)]
+
+
+@pytest.mark.parametrize("piece", ["decode_attn", "prefill_attn"])
+@pytest.mark.parametrize("arch", sorted(ENGINES))
+def test_tiered_piece_updates_the_pools_in_place_for_v5e(
+        one_chip, monkeypatch, arch, piece):
+    """The attention piece takes the pools donated and writes one layer's
+    rows in place: no copy of a whole pool (1.34 GB per array at granite's
+    cell, 1.59 GB at DeepSeek-V2-Lite's) on each of a step's layer calls."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_MODE", "pallas")   # the chip's kernels
+    overrides, pool, n_arrays, B, mp = ENGINES[arch]
+    engine = _tiered_engine(arch, overrides)
+    model = engine.model
+    dt, d = model.cfg.dtype, model.cfg.d_model
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(lambda a: sd(a.shape, a.dtype),
+                                    model.abstract())
+    layers = dict(params["layers"])          # the served view: experts
+    layers["moe"] = {"router": layers["moe"]["router"]}   # live off-chip
+    params = {**params, "layers": layers}
+    pools = tuple(sd(pool, dt) for _ in range(n_arrays))
+    if piece == "decode_attn":
+        rows = [sd((B,), I32)] * 3 + [sd((B,), jnp.bool_)]
+        args = (sd((B, 1, d), dt), sd((), I32), sd((B, mp), I32), *rows)
+    else:
+        S = PREFILL_BUCKET
+        rows = [sd((S,), I32)] * 4 + [sd((S,), jnp.bool_)]
+        args = (sd((1, S, d), dt), sd((), I32), sd((mp,), I32), *rows)
+    fn = getattr(engine, f"_t_{piece}")
+    text = fn.lower(params, pools, *args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not _copies_of(text, pool), _copies_of(text, pool)
