@@ -276,11 +276,10 @@ def _moe_smoke_model():
     return cfg, model, params
 
 
-def expert_tiering_decode(cache_frac: float, double_buffer: bool,
-                          rounds: int):
+def expert_tiering_decode(cache_frac: float, rounds: int):
     """Decode throughput with MoE expert FFN weights host-resident behind
     a bounded HBM cache holding ``cache_frac`` of all (layer, expert)
-    blocks, with or without the double-buffered speculative prefetch.
+    blocks, with the double-buffered speculative prefetch.
     Rotating session pairs churn the routed expert mix so the cache
     actually turns over.  Returns the tokens, the host seconds they took
     and the store's fetch counts."""
@@ -290,8 +289,7 @@ def expert_tiering_decode(cache_frac: float, double_buffer: bool,
     llm = LLM(model, params, ServeConfig(
         max_batch=2, page_size=4, hbm_pages=48, host_pages=96,
         policy="gdt", interval_steps=16, prefill_chunk_tokens=2,
-        expert_offchip=True, expert_cache_size=C,
-        expert_double_buffer=double_buffer))
+        expert_offchip=True, expert_cache_size=C))
     store = llm.engine.expert_store
     rng = np.random.default_rng(9)
     n_sessions = 6
@@ -464,24 +462,20 @@ def run(quick: bool = False):
                  cstats["cluster_requests_lost"]))
     # Expert-weight tiering: MoE decode with expert FFN blocks behind a
     # bounded HBM cache, swept over the cached fraction of all blocks,
-    # with (db) and without (sync) the double-buffered speculative
-    # prefetch.  ``derived`` = wall decode tokens/s for tokens_per_s rows,
-    # counts for fetch/hit rows.  What a fetch costs on the chip is read
-    # from a trace (``repro.expert.*`` spans), not here.
+    # with the double-buffered (db) speculative prefetch.  ``derived`` =
+    # wall decode tokens/s for tokens_per_s rows, counts for fetch/hit
+    # rows.  What a fetch costs on the chip is read from a trace
+    # (``repro.expert.*`` spans), not here.
     ex_rounds = 8 if quick else 12
     for frac in (1.0, 0.5, 0.25):
-        for db in (True, False):
-            if frac == 1.0 and not db:
-                continue          # everything resident: nothing to fetch
-            mode = "db" if db else "sync"
-            r = expert_tiering_decode(frac, db, ex_rounds)
-            tag = f"serve/expert_tiering/frac{frac:g}/{mode}"
-            rows.append((f"{tag}/decode_tokens_per_s", r["wall"] * 1e6,
-                         r["tokens"] / r["wall"]))
-            rows.append((f"{tag}/demand_fetches", r["wall"] * 1e6,
-                         r["demand_fetches"]))
-            rows.append((f"{tag}/prefetch_hits", r["wall"] * 1e6,
-                         r["prefetch_hits"]))
+        r = expert_tiering_decode(frac, ex_rounds)
+        tag = f"serve/expert_tiering/frac{frac:g}/db"
+        rows.append((f"{tag}/decode_tokens_per_s", r["wall"] * 1e6,
+                     r["tokens"] / r["wall"]))
+        rows.append((f"{tag}/demand_fetches", r["wall"] * 1e6,
+                     r["demand_fetches"]))
+        rows.append((f"{tag}/prefetch_hits", r["wall"] * 1e6,
+                     r["prefetch_hits"]))
     # SLO replay: bursty two-tenant trace, FIFO one-shot vs FIFO with
     # chunked-prefill interleaving.  ``derived`` = modeled milliseconds
     # for latency rows, fractions for goodput rows, and a 0/1 flag for the

@@ -247,14 +247,14 @@ def kernel_check(engine, sizes: Sizes, tiered: bool) -> Dict[str, bool]:
         d = engine.model.cfg.d_model
         st = engine.expert_store
         progs = {
-            "decode_attn": engine._t_decode_attn.lower(
+            "decode_attn": engine._t_attn.lower(
                 p, pools, sd((B, 1, d), dt), sd((), i32),
                 sd((B, MP), i32), sd((B,), i32), sd((B,), i32),
-                sd((B,), i32), sd((B,), bool)),
-            "prefill_attn": engine._t_prefill_attn.lower(
+                sd((B,), i32), sd((B,), bool), dispatch="decode"),
+            "prefill_attn": engine._t_attn.lower(
                 p, pools, sd((1, S, d), dt), sd((), i32),
                 sd((MP,), i32), sd((S,), i32), sd((S,), i32), sd((S,), i32),
-                sd((S,), i32), sd((S,), bool)),
+                sd((S,), i32), sd((S,), bool), dispatch="prefill"),
             "ffn": engine._t_ffn.lower(
                 sd((B, 1, d), dt), sd((B, 1, d), dt),
                 st.w_gate_cache, st.w_up_cache, st.w_down_cache,

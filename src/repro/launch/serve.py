@@ -66,9 +66,6 @@ def main():
     p.add_argument("--expert-cache-size", type=int, default=0,
                    help="HBM expert-cache capacity in blocks, shared "
                         "across layers (0 = every block fits)")
-    p.add_argument("--no-expert-double-buffer", action="store_true",
-                   help="disable the double-buffered expert prefetch: "
-                        "every cache miss becomes a blocking demand fetch")
     p.add_argument("--replicas", type=int, default=1,
                    help="engine replicas behind the router (least-loaded "
                         "dispatch; failures/drains migrate in-flight "
@@ -99,8 +96,7 @@ def main():
         enable_prefix_cache=args.prefix_cache,
         min_prefix_pages=args.min_prefix_pages,
         expert_offchip=args.offchip_experts,
-        expert_cache_size=args.expert_cache_size,
-        expert_double_buffer=not args.no_expert_double_buffer),
+        expert_cache_size=args.expert_cache_size),
         replicas=args.replicas)
 
     rng = np.random.default_rng(0)

@@ -182,11 +182,6 @@ class ServeConfig:
     # hold at least one dispatch's working set:
     # min(n_experts, max_batch * top_k).
     expert_cache_size: int = 0
-    # Double-buffered prefetch: while layer L's grouped GEMM is in flight,
-    # the predicted working set for the next layer stages on a second
-    # buffer.  Off = no prefetch: every block a dispatch needs and the
-    # cache lacks is a blocking demand fetch (same results).
-    expert_double_buffer: bool = True
 
 
 @dataclasses.dataclass
@@ -441,7 +436,7 @@ class Engine:
             self.expert_store = ExpertStore(
                 moe_params, mc.moe_layers, E,
                 cfg.expert_cache_size or mc.moe_layers * E,
-                double_buffer=cfg.expert_double_buffer, device=self.device)
+                device=self.device)
             # The routed expert stacks live in the store's tiers now; serve
             # against a param view holding only the router (shared experts
             # stay beside it, resident).  ``params``
@@ -456,54 +451,40 @@ class Engine:
         self.kv_backend: Optional[PagedKVBackend] = None
         self.runtime: Optional[GuidanceRuntime] = None
         if cfg.policy == "gdt":
-            self.kv_backend = PagedKVBackend(
-                self.pool, self.requests, clock=lambda: self.step_count)
-            self.runtime = GuidanceRuntime(
-                self.kv_backend, hw,
-                GuidanceConfig(
+            def clock():
+                return self.step_count
+
+            def guidance(fast_capacity_bytes):
+                return GuidanceConfig(
                     strategy=cfg.strategy,
-                    # The reserved scratch slot is not placeable capacity.
-                    fast_capacity_bytes=(cfg.hbm_pages - 1) * self.pool.page_bytes,
+                    fast_capacity_bytes=fast_capacity_bytes,
                     interval_steps=cfg.interval_steps,
                     decay=cfg.access_decay,
                     num_fragments=cfg.num_fragments,
-                    skip_empty_intervals=True),
-                clock=lambda: self.step_count)
+                    skip_empty_intervals=True)
+
+            # The reserved scratch slot is not placeable capacity.
+            kv_bytes = (cfg.hbm_pages - 1) * self.pool.page_bytes
+            self.kv_backend = PagedKVBackend(self.pool, self.requests, clock)
+            self.runtime = GuidanceRuntime(
+                self.kv_backend, hw, guidance(kv_bytes), clock=clock)
             if self.prefix_cache is not None:
-                self.prefix_backend = PrefixBackend(
-                    self.prefix_cache, clock=lambda: self.step_count)
+                self.prefix_backend = PrefixBackend(self.prefix_cache, clock)
                 self.prefix_runtime = GuidanceRuntime(
-                    self.prefix_backend, hw,
-                    GuidanceConfig(
-                        strategy=cfg.strategy,
-                        fast_capacity_bytes=(cfg.hbm_pages - 1)
-                        * self.pool.page_bytes,
-                        interval_steps=cfg.interval_steps,
-                        decay=cfg.access_decay,
-                        num_fragments=cfg.num_fragments,
-                        skip_empty_intervals=True),
-                    clock=lambda: self.step_count)
+                    self.prefix_backend, hw, guidance(kv_bytes), clock=clock)
             if self.expert_store is not None:
-                self.expert_backend = ExpertBackend(
-                    self.expert_store, clock=lambda: self.step_count)
+                self.expert_backend = ExpertBackend(self.expert_store, clock)
                 self.expert_runtime = GuidanceRuntime(
                     self.expert_backend, hw,
-                    GuidanceConfig(
-                        strategy=cfg.strategy,
-                        fast_capacity_bytes=self.expert_store.cache_bytes,
-                        interval_steps=cfg.interval_steps,
-                        decay=cfg.access_decay,
-                        num_fragments=cfg.num_fragments,
-                        skip_empty_intervals=True),
-                    clock=lambda: self.step_count)
+                    guidance(self.expert_store.cache_bytes), clock=clock)
         # Every program that returns the pools updated takes them donated
         # (argument 1), so XLA writes them in place instead of copying them
         # whole; ``self.pool.hbm`` is re-bound to what it returns.
-        self._decode_greedy = _jit(self._build_decode(with_sampler=False),
-                                   donate_argnums=1)
-        self._decode_sampled = _jit(self._build_decode(with_sampler=True),
-                                    donate_argnums=1)
-        self._prefill = _jit(self._build_prefill(), donate_argnums=1)
+        self._decode_greedy = _jit(
+            functools.partial(self._decode_scan, False), donate_argnums=1)
+        self._decode_sampled = _jit(
+            functools.partial(self._decode_scan, True), donate_argnums=1)
+        self._prefill = _jit(self._prefill_scan, donate_argnums=1)
         if self.expert_store is not None:
             self._build_tiered_closures()
         self.last_logits: Dict[int, np.ndarray] = {}
@@ -719,43 +700,69 @@ class Engine:
             d = mlp(lp["mlp"], h2)
         return x + jnp.where(lane_mask, d, 0)
 
-    # ========================================================= jit decode
-    def _build_decode(self, with_sampler: bool):
-        """Two jitted variants share one body: the greedy variant's epilogue
-        is a plain ``argmax`` (bitwise the pre-sampling engine, zero
-        sampling overhead on the default path); the sampled variant runs
-        the full in-dispatch sampler.  They agree bitwise on greedy rows
-        (the sampler short-circuits ``temperature<=0`` to the same argmax),
-        so the scheduler picks per batch and each compiles only on first
-        use — a pure-greedy workload never compiles the sampled path."""
-        from ..kernels.ops import sample_tokens
-
-        def step(params, pools, tokens, page_table, lengths,
-                 write_slot, write_off, active, seeds, temperature, top_k,
-                 top_p):
-            """pools: the pool's arrays; tokens: (B,1); page_table: (B,MP)
-            HBM slots or -1; lengths: (B,) incl. new token;
-            write_slot/off: (B,) where the new token's KV goes; active:
-            (B,) bool — inactive rows are masked to deterministic zeros
-            rather than carrying garbage;
-            seeds/temperature/top_k/top_p: (B,) per-request sampling knobs
-            (the sampler runs INSIDE this dispatch, with the next token's
-            stream position ``lengths`` as the PRNG fold — the replay
-            contract)."""
-            x = jnp.take(params["embed"]["tok"], tokens, axis=0)  # (B,1,d)
-            attend = self._decode_attend(page_table, lengths)
-
-            def body(carry, xs):
-                lp, *pl = xs             # pl: each pool's (N,P,K,dh)
-                return self._layer_body(
-                    lp, carry, tuple(pl),
-                    positions=(lengths - 1)[:, None],
+    # ===================================================== dispatch parts
+    # Each part of a dispatch is defined once.  The resident programs run
+    # them inside one jitted scan over the layers; the tiered pipeline jits
+    # the same definitions as its pieces.
+    def _decode_frame(self, page_table, lengths, write_slot, write_off,
+                      active):
+        """The layer keywords of a decode dispatch: a batch of B
+        single-token rows, x (B,1,d).  page_table: (B,MP) HBM slots or -1;
+        lengths: (B,) incl. the new token; write_slot/off: (B,) where the
+        new token's KV goes; active: (B,) bool — inactive rows are masked
+        to deterministic zeros rather than carrying garbage."""
+        return dict(positions=(lengths - 1)[:, None],
                     write_slot=write_slot, write_off=write_off,
                     row_mask=active, lane_mask=active[:, None, None],
                     rows=lambda t: t[:, 0], unrows=lambda y: y[:, None],
-                    attend=attend)
+                    attend=self._decode_attend(page_table, lengths))
 
-            x, pools = self.model.scan_stacks(body, x, params, pools)
+    def _prefill_frame(self, page_table, slots, offs, positions, lengths,
+                       valid):
+        """The layer keywords of a prefill dispatch: one sequence of S
+        rows, x (1,S,d), laid out by ``_prefill_prologue``.  page_table:
+        (MP,) the request's pages, cached prefix included; slots/offs: (S,)
+        each row's write target (the scratch slot for padding).  Rows
+        attend by ABSOLUTE length over the table, so a suffix-only dispatch
+        replays the whole-prompt computation bitwise."""
+        return dict(positions=positions[None],
+                    write_slot=slots, write_off=offs,
+                    row_mask=valid, lane_mask=valid[None, :, None],
+                    rows=lambda t: t[0], unrows=lambda y: y[None],
+                    attend=self._prefill_attend(page_table, lengths,
+                                                positions[0]))
+
+    def _embed(self, params, tokens):
+        return jnp.take(params["embed"]["tok"], tokens, axis=0)
+
+    def _prefill_prologue(self, params, tokens, n_real, start):
+        """tokens: (S,) the padded suffix; n_real: () int32 live rows;
+        start: () int32 absolute position of row 0 (0 for an uncached
+        prompt, the covered token count after a prefix-cache hit — traced,
+        so hits never recompile).  Returns the embedded rows and each
+        row's position, causal length (0 for padding) and liveness."""
+        S = tokens.shape[0]
+        local = jnp.arange(S, dtype=jnp.int32)
+        positions = start + local
+        valid = local < n_real
+        lengths = jnp.where(valid, positions + 1, 0)
+        return self._embed(params, tokens[None]), positions, lengths, valid
+
+    def _tail(self, with_sampler: bool):
+        """Final norm, head and next token of a decode dispatch.  The
+        greedy variant's epilogue is a plain ``argmax`` (zero sampling
+        overhead on the default path); the sampled variant runs the full
+        in-dispatch sampler.  They agree bitwise on greedy rows (the
+        sampler short-circuits ``temperature<=0`` to the same argmax), so
+        the scheduler picks per batch and each compiles only on first use
+        — a pure-greedy workload never compiles the sampled path."""
+        from ..kernels.ops import sample_tokens
+
+        def tail(params, x, lengths, active, seeds, temperature, top_k,
+                 top_p):
+            """seeds/temperature/top_k/top_p: (B,) per-request sampling
+            knobs; the sampler folds the next token's stream position
+            ``lengths`` into the PRNG — the replay contract."""
             x = rmsnorm(params["final_ln"], x)
             logits = lm_head(params["head"], x)[:, 0]
             logits = jnp.where(active[:, None], logits, 0.0)
@@ -764,51 +771,44 @@ class Engine:
                                             temperature, top_k, top_p)
             else:
                 next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return logits, next_tokens, pools
+            return logits, next_tokens
+        return tail
 
-        return step
+    def _scan_layers(self, params, pools, x, frame, *frame_args):
+        """Every layer in one scan; ``frame(*frame_args)`` builds the layer
+        keywords inside the scan body."""
+        def body(carry, xs):
+            lp, *pl = xs             # pl: each pool's (N,P,K,dh)
+            return self._layer_body(lp, carry, tuple(pl),
+                                    **frame(*frame_args))
+        return self.model.scan_stacks(body, x, params, pools)
 
-    # ======================================================== jit prefill
-    def _build_prefill(self):
+    # ================================================== resident programs
+    def _decode_scan(self, with_sampler, params, pools, tokens, page_table,
+                     lengths, write_slot, write_off, active, seeds,
+                     temperature, top_k, top_p):
+        """One decode step of the batch in one scan.  tokens: (B,1); the
+        rest as ``_decode_frame`` and ``_tail`` take them."""
+        x, pools = self._scan_layers(
+            params, pools, self._embed(params, tokens), self._decode_frame,
+            page_table, lengths, write_slot, write_off, active)
+        return (*self._tail(with_sampler)(
+            params, x, lengths, active, seeds, temperature, top_k, top_p),
+            pools)
+
+    def _prefill_scan(self, params, pools, tokens, page_table, slots, offs,
+                      n_real, start):
         """One-shot prompt ingestion: a single jitted call writes S tokens'
         K/V into their page-table slots and attends with per-token causal
         lengths via ``ops.paged_prefill`` — the same layer body decode
         runs, so the result is bitwise-equal to chunked ingestion.  Latent
         attention prefills in the plain form and decodes absorbed: equal
         in exact arithmetic, not bitwise (``_prefill_attend``)."""
-        def prefill(params, pools, tokens, page_table, slots, offs,
-                    n_real, start):
-            """pools: the pool's arrays; tokens: (S,) padded suffix;
-            page_table: (MP,) the request's pages (cache-covered prefix
-            included); slots/offs: (S,) physical write target per token
-            (the scratch slot for padded rows);
-            n_real: () int32 live rows; start: () int32 absolute position of
-            row 0 (0 for an uncached prompt, the covered token count after a
-            prefix-cache hit — traced, so hits never recompile).  Rows
-            attend by ABSOLUTE length over the page table, so a suffix-only
-            dispatch replays the whole-prompt computation bitwise."""
-            S = tokens.shape[0]
-            local = jnp.arange(S, dtype=jnp.int32)
-            positions = start + local
-            valid = local < n_real
-            lengths = jnp.where(valid, positions + 1, 0)
-            x = jnp.take(params["embed"]["tok"], tokens[None], axis=0)
-            attend = self._prefill_attend(page_table, lengths, start)
-
-            def body(carry, xs):
-                lp, *pl = xs
-                return self._layer_body(
-                    lp, carry, tuple(pl),
-                    positions=positions[None],
-                    write_slot=slots, write_off=offs,
-                    row_mask=valid, lane_mask=valid[None, :, None],
-                    rows=lambda t: t[0], unrows=lambda y: y[None],
-                    attend=attend)
-
-            _, pools = self.model.scan_stacks(body, x, params, pools)
-            return pools
-
-        return prefill
+        x, positions, lengths, valid = self._prefill_prologue(
+            params, tokens, n_real, start)
+        return self._scan_layers(
+            params, pools, x, self._prefill_frame, page_table, slots, offs,
+            positions, lengths, valid)[1]
 
     # ==================================================== tiered expert path
     def _build_tiered_closures(self):
@@ -823,15 +823,14 @@ class Engine:
         attention+router (``_attn_half`` + ``route_tokens``), a host sync
         of the picks, then a jitted FFN-from-cache + residual
         (``apply_dropless_flat`` with the slot map as ``group_experts``).
-        Every op and its order is identical to the resident scan — only
-        the jit boundaries move — which the bitwise parity tests pin on
+        Only the jit boundaries move, which the bitwise parity tests pin on
         the CPU (on the TPU the two programs round differently, so there
-        the tiered result is pinned across cache sizes instead).  While
-        one layer's FFN dispatch is in flight the store stages the next
-        layer's predicted experts (double buffer).  A leading dense layer
-        is one jitted piece (attention and its MLP) with nothing to read
-        back; shared experts are added to the routed output inside the
-        FFN piece.
+        the tiered result is pinned across cache sizes instead).  A
+        leading dense layer is one jitted piece (attention and its MLP)
+        with nothing to read back; shared experts are added to the routed
+        output inside the FFN piece.  The attention and dense pieces take
+        the dispatch kind (``"decode"`` or ``"prefill"``) as the static
+        ``dispatch`` keyword, which picks the frame of their arguments.
 
         Ownership of the pools: every piece that writes a layer's K/V (or
         latent) rows takes the whole pool tuple donated (argument 1) and
@@ -843,10 +842,10 @@ class Engine:
         ``_tiered_ffn``) then leaves the pool naming live arrays.  Nothing
         else may hold a pool array across a piece.
         """
-        model = self.model
-        mcfg = model.moe_cfg
-        nd = model.cfg.first_dense_layers
-        from ..kernels.ops import sample_tokens
+        mcfg = self.model.moe_cfg
+        nd = self.model.cfg.first_dense_layers
+        frames = {"decode": self._decode_frame,
+                  "prefill": self._prefill_frame}
 
         def slice_layer(tree, l):
             return jax.tree_util.tree_map(
@@ -857,70 +856,25 @@ class Engine:
             return tuple(jax.lax.dynamic_update_index_in_dim(a, b, l, 0)
                          for a, b in zip(pools, pl))
 
-        def embed(params, tokens):
-            return jnp.take(params["embed"]["tok"], tokens, axis=0)
-
         # ``l`` is the model's layer (the pools' index); a MoE layer's
         # weights sit at l - first_dense_layers of ``params["layers"]``.
-        def decode_args(page_table, lengths, write_slot, write_off, active):
-            return dict(positions=(lengths - 1)[:, None],
-                        write_slot=write_slot, write_off=write_off,
-                        row_mask=active, lane_mask=active[:, None, None],
-                        rows=lambda t: t[:, 0], unrows=lambda y: y[:, None],
-                        attend=self._decode_attend(page_table, lengths))
-
-        def prefill_args(page_table, slots, offs, positions, lengths, valid):
-            return dict(positions=positions[None],
-                        write_slot=slots, write_off=offs,
-                        row_mask=valid, lane_mask=valid[None, :, None],
-                        rows=lambda t: t[0], unrows=lambda y: y[None],
-                        attend=self._prefill_attend(page_table, lengths,
-                                                    positions[0]))
-
-        def attn_route(params, pools, x, l, args):
+        def attn(params, pools, x, l, *frame_args, dispatch):
+            frame = frames[dispatch](*frame_args)
             lp = slice_layer(params["layers"], l - nd if nd else l)
             pl = slice_layer(pools, l)
-            x, h2, pl = self._attn_half(lp, x, pl, **args)
+            x, h2, pl = self._attn_half(lp, x, pl, **frame)
             B, S, d = h2.shape
             gates, experts = route_tokens(
                 lp["moe"]["router"], h2.reshape(B * S, d), mcfg)
             return x, h2, gates, experts, put_layer(pools, pl, l)
 
-        def dense_layer(params, pools, x, l, args):
+        def dense(params, pools, x, l, *frame_args, dispatch):
             # A leading dense layer: attention and its MLP in one piece,
             # no expert in it and nothing for the host to read.
+            frame = frames[dispatch](*frame_args)
             lp = slice_layer(params["dense_layers"], l)
-            x, pl = self._layer_body(lp, x, slice_layer(pools, l), **args)
+            x, pl = self._layer_body(lp, x, slice_layer(pools, l), **frame)
             return x, put_layer(pools, pl, l)
-
-        def decode_attn(params, pools, x, l, page_table, lengths,
-                        write_slot, write_off, active):
-            return attn_route(params, pools, x, l, decode_args(
-                page_table, lengths, write_slot, write_off, active))
-
-        def decode_dense(params, pools, x, l, page_table, lengths,
-                         write_slot, write_off, active):
-            return dense_layer(params, pools, x, l, decode_args(
-                page_table, lengths, write_slot, write_off, active))
-
-        def prefill_prologue(params, tokens, n_real, start):
-            S = tokens.shape[0]
-            local = jnp.arange(S, dtype=jnp.int32)
-            positions = start + local
-            valid = local < n_real
-            lengths = jnp.where(valid, positions + 1, 0)
-            x = jnp.take(params["embed"]["tok"], tokens[None], axis=0)
-            return x, positions, lengths, valid
-
-        def prefill_attn(params, pools, x, l, page_table, slots, offs,
-                         positions, lengths, valid):
-            return attn_route(params, pools, x, l, prefill_args(
-                page_table, slots, offs, positions, lengths, valid))
-
-        def prefill_dense(params, pools, x, l, page_table, slots, offs,
-                          positions, lengths, valid):
-            return dense_layer(params, pools, x, l, prefill_args(
-                page_table, slots, offs, positions, lengths, valid))
 
         def ffn(x, h2, wg, wu, wd, slot_map, gates, experts, lane_mask,
                 shared=None, l=None):
@@ -951,34 +905,18 @@ class Engine:
                                 lp["moe"]["router"])
             return jax.nn.softmax(logits, axis=-1)
 
-        def make_tail(with_sampler):
-            def tail(params, x, lengths, active, seeds, temperature, top_k,
-                     top_p):
-                x = rmsnorm(params["final_ln"], x)
-                logits = lm_head(params["head"], x)[:, 0]
-                logits = jnp.where(active[:, None], logits, 0.0)
-                if with_sampler:
-                    next_tokens = sample_tokens(logits, seeds, lengths,
-                                                temperature, top_k, top_p)
-                else:
-                    next_tokens = jnp.argmax(logits, axis=-1).astype(
-                        jnp.int32)
-                return logits, next_tokens
-            return tail
-
-        self._t_embed = _jit(embed)
-        self._t_spec_route = _jit(spec_route)
-        self._t_decode_attn = _jit(decode_attn, donate_argnums=1)
-        self._t_decode_dense = _jit(decode_dense, donate_argnums=1)
-        self._t_prefill_prologue = _jit(prefill_prologue)
-        self._t_prefill_attn = _jit(prefill_attn, donate_argnums=1)
-        self._t_prefill_dense = _jit(prefill_dense, donate_argnums=1)
+        piece = functools.partial(_jit, donate_argnums=1,
+                                  static_argnames="dispatch")
+        self._t_embed = _jit(self._embed)
+        self._t_prefill_prologue = _jit(self._prefill_prologue)
+        self._t_attn = piece(attn)
+        self._t_dense = piece(dense)
         self._t_ffn = _jit(ffn)
-        self._t_tail_greedy = _jit(make_tail(False))
-        self._t_tail_sampled = _jit(make_tail(True))
+        self._t_spec_route = _jit(spec_route)
+        self._t_tail_greedy = _jit(self._tail(False))
+        self._t_tail_sampled = _jit(self._tail(True))
 
-    def _tiered_ffn(self, l, x, h2, gates, experts, lane_mask, token_mask,
-                    wrap_prefetch=True):
+    def _tiered_ffn(self, l, x, h2, gates, experts, lane_mask, token_mask):
         """Host half of one tiered layer: sync the routed picks, make them
         resident (commit the in-flight prefetch / demand-fetch misses /
         evict), dispatch the FFN against cache slots, then put the
@@ -1012,18 +950,13 @@ class Engine:
             if shared is not None:
                 args += (shared, jnp.int32(l))
             x = self._t_ffn(*args)
-        # Double buffer: stage the next dispatch's predicted working set
-        # while this one's grouped GEMM is in flight.  Within a step the
-        # prediction is speculative gating — the next layer's router run
-        # on the residual stream one attention delta early; at the
-        # wrap-around the next step's first dispatch routes a token that
-        # does not exist yet, so the store falls back to recency + the
-        # guidance profile.
+        # Double buffer: stage the next layer's predicted working set while
+        # this grouped GEMM is in flight, forecast by speculative gating
+        # (its router one attention delta early).  The callers of
+        # ``_tiered_layers`` forecast the wrap-around to the first layer.
         nl = (l + 1) % self.model.cfg.moe_layers
         if nl:
             self._spec_prefetch(nl, x, token_mask)
-        elif wrap_prefetch:
-            store.prefetch(0, self.step_count)
         return x
 
     def _spec_prefetch(self, l, x, token_mask):
@@ -1047,77 +980,61 @@ class Engine:
         self.expert_store.prefetch(
             l, self.step_count, predicted=[int(e) for e in order[:cap]])
 
-    def _tiered_decode(self, tokens, page_table, lengths, write_slot,
-                       write_off, active, seeds, temperature, top_k, top_p,
-                       use_sampler, mask):
-        """``mask`` is the host copy of ``active``.  Re-binds
-        ``self.pool.hbm`` after each piece (the pieces take it donated)."""
-        x = self._t_embed(self.params, tokens)
-        lane = active[:, None, None]
+    def _tiered_layers(self, x, dispatch, frame_args, lane_mask, token_mask):
+        """Every layer of one tiered dispatch (``dispatch`` "decode" or
+        "prefill", ``frame_args`` what its frame takes): a leading dense
+        layer is one piece; a MoE layer is its attention piece, then
+        ``_tiered_ffn``.  ``token_mask`` is the host copy of the live rows.
+        Re-binds ``self.pool.hbm`` after each piece (the pieces take it
+        donated)."""
         mc = self.model.cfg
         nd, kind = mc.first_dense_layers, "mla" if mc.mla else "gqa"
         for l in range(mc.n_layers):
-            args = (self.params, self.pool.hbm, x, jnp.int32(l), page_table,
-                    lengths, write_slot, write_off, active)
+            args = (self.params, self.pool.hbm, x, jnp.int32(l), *frame_args)
             if l < nd:
                 with span("repro.dispatch.dense", layer=l):
-                    x, self.pool.hbm = self._t_decode_dense(*args)
+                    x, self.pool.hbm = self._t_dense(*args, dispatch=dispatch)
                 continue
             with span("repro.dispatch.attn", layer=l, kind=kind):
-                x, h2, gates, experts, self.pool.hbm = \
-                    self._t_decode_attn(*args)
-            x = self._tiered_ffn(l - nd, x, h2, gates, experts, lane, mask,
-                                 wrap_prefetch=False)
+                x, h2, gates, experts, self.pool.hbm = self._t_attn(
+                    *args, dispatch=dispatch)
+            x = self._tiered_ffn(l - nd, x, h2, gates, experts, lane_mask,
+                                 token_mask)
+        return x
+
+    def _tiered_decode(self, tokens, page_table, lengths, write_slot,
+                       write_off, active, seeds, temperature, top_k, top_p,
+                       use_sampler, mask):
+        """``mask`` is the host copy of ``active``."""
+        x = self._tiered_layers(
+            self._t_embed(self.params, tokens), "decode",
+            (page_table, lengths, write_slot, write_off, active),
+            active[:, None, None], mask)
         tail = self._t_tail_sampled if use_sampler else self._t_tail_greedy
         with span("repro.dispatch.tail"):
             logits, next_tokens = tail(self.params, x, lengths, active,
                                        seeds, temperature, top_k, top_p)
         # The sampled token IS the next step's layer-0 residual stream
-        # (x = embed(token)), so the one dispatch the in-step speculation
-        # cannot see — the wrap-around to the next step's first layer —
-        # gets its own forecast here, hiding the fetch under the tail +
-        # host scheduling gap.  Batch rotation between steps makes this a
+        # (x = embed(token)): forecast the wrap-around to the next step's
+        # first layer from it, hiding the fetch under the tail + host
+        # scheduling gap.  Batch rotation between steps makes this a
         # forecast, not an oracle; mispredictions demand-fetch as usual.
-        if self.expert_store.double_buffer:
-            self._spec_prefetch(
-                0, self._t_embed(self.params, next_tokens[:, None]), mask)
+        self._spec_prefetch(
+            0, self._t_embed(self.params, next_tokens[:, None]), mask)
         return logits, next_tokens
 
     def _tiered_prefill(self, tokens, page_table, slots, offs, n_real,
                         start, mask):
-        """``mask`` is the host copy of the prologue's ``valid``.  Re-binds
-        ``self.pool.hbm`` after each piece, as ``_tiered_decode`` does."""
+        """``mask`` is the host copy of the prologue's ``valid``."""
         x, positions, lengths, valid = self._t_prefill_prologue(
             self.params, tokens, n_real, start)
-        lane = valid[None, :, None]
-        mc = self.model.cfg
-        nd, kind = mc.first_dense_layers, "mla" if mc.mla else "gqa"
-        for l in range(mc.n_layers):
-            args = (self.params, self.pool.hbm, x, jnp.int32(l), page_table,
-                    slots, offs, positions, lengths, valid)
-            if l < nd:
-                with span("repro.dispatch.dense", layer=l):
-                    x, self.pool.hbm = self._t_prefill_dense(*args)
-                continue
-            with span("repro.dispatch.attn", layer=l, kind=kind):
-                x, h2, gates, experts, self.pool.hbm = \
-                    self._t_prefill_attn(*args)
-            x = self._tiered_ffn(l - nd, x, h2, gates, experts, lane, mask)
-
-    def _run_prefill(self, tokens, page_table, slots, offs, n_real, start):
-        """One bucketed prefill dispatch: the resident single-jit scan, or
-        the layer-by-layer tiered pipeline when expert weights are
-        off-chip.  Both write the pools in place (donated) and re-bind
-        ``self.pool.hbm``."""
-        args = (jnp.asarray(tokens), jnp.asarray(page_table),
-                jnp.asarray(slots), jnp.asarray(offs), jnp.int32(n_real),
-                jnp.int32(start))
-        if self.expert_store is not None:
-            self._tiered_prefill(*args, mask=np.arange(len(tokens)) < n_real)
-        else:
-            with span("repro.dispatch.step"):
-                self.pool.hbm = self._prefill(self.params, self.pool.hbm,
-                                              *args)
+        self._tiered_layers(
+            x, "prefill", (page_table, slots, offs, positions, lengths,
+                           valid), valid[None, :, None], mask)
+        # No token follows a prefill to forecast the next step's first
+        # dispatch from: the store falls back to recency + the guidance
+        # profile.
+        self.expert_store.prefetch(0, self.step_count)
 
     def _read(self, what: str, value, **args) -> np.ndarray:
         """A blocking device->host read on the step path: the host waits
@@ -1380,37 +1297,41 @@ class Engine:
 
     def _admit(self, req: Request) -> None:
         """Move an admitted request out of the wait queue: straight to
-        ``active`` via eager whole-suffix prefill, or — with
-        chunked-prefill interleaving on — into ``prefilling``, where
-        ``_advance_prefills`` ingests budgeted chunks each step."""
+        ``active`` via eager whole-suffix prefill (one ``_prefill_chunk``
+        over the whole uncovered suffix), or — with chunked-prefill
+        interleaving on — into ``prefilling``, where ``_advance_prefills``
+        ingests budgeted chunks each step."""
         budget = self.scheduler.step_budget(self)
         if self.cfg.prefill == "one_shot" and budget.prefill_tokens > 0:
             self._begin_prefill(req)
         else:
             with span("repro.engine.prefill", request_id=req.request_id,
                       tokens=len(req.context) - 1):
-                self._prefill_request(req)
+                if self.cfg.prefill == "chunked":
+                    self._prefill_request(req)
+                else:
+                    self._begin_prefill(req)
+                    if req.state == "prefilling":
+                        self._prefill_chunk(req, len(req.context) - 1
+                                            - req.pos)
+                        self._end_prefill(req)
             req.state = "active"
         req.last_scheduled = self.step_count
 
     def _begin_prefill(self, req: Request) -> None:
-        """Start an interleaved prefill: consult the prefix cache, allocate
+        """Start a prefill: consult the prefix cache, allocate
         the WHOLE uncovered suffix's pages now (admission already budgeted
         them — allocating lazily per chunk could lose the race against
         later admissions), and park the request in ``prefilling`` state.
         Trivial ingests (empty / full cache hit) go straight to active."""
         context = req.context
         n_ingest = len(context) - 1
-        if n_ingest == 0:
-            req.pos = 0
-            req.state = "active"
-            return
         P = self.cfg.page_size
         rid = req.request_id
-        chain = self._match_prefix(req, context, n_ingest)
+        chain = self._match_prefix(req, context, n_ingest) if n_ingest else []
         covered = len(chain) * P
-        if n_ingest - covered == 0:
-            req.pos = n_ingest           # full hit: nothing to dispatch
+        if covered == n_ingest:          # nothing to dispatch
+            req.pos = n_ingest
             req.state = "active"
             return
         n_prefix_pages = covered // P
@@ -1443,18 +1364,24 @@ class Engine:
             with span("repro.engine.prefill", request_id=req.request_id,
                       tokens=n):
                 self._prefill_chunk(req, n)
+            self.prefill_chunks += 1
             budget -= n
             self.scheduler.on_tokens(req, n, self)
             if req.pos >= n_ingest:
-                chain = self._pending_chains.pop(req.request_id, [])
-                self._insert_prefix(req, req.context, n_ingest, chain)
-                req.state = "active"
-                req.last_scheduled = self.step_count
+                self._end_prefill(req)
+
+    def _end_prefill(self, req: Request) -> None:
+        """The whole prompt is in: adopt its blocks into the prefix cache
+        and make the request decode-eligible."""
+        chain = self._pending_chains.pop(req.request_id, [])
+        self._insert_prefix(req, req.context, len(req.context) - 1, chain)
+        req.state = "active"
+        req.last_scheduled = self.step_count
 
     def _prefill_chunk(self, req: Request, n: int) -> None:
         """Ingest ``req.context[req.pos : req.pos+n]`` with one bucketed
-        dispatch at absolute start ``req.pos`` — the same jitted closure as
-        one-shot prefill, so every chunking of a prompt produces
+        dispatch at absolute start ``req.pos`` — eager prefill is one such
+        chunk over the whole suffix, so every chunking of a prompt produces
         bitwise-identical K/V (rows attend by absolute length over the
         request's full page table)."""
         context = req.context
@@ -1465,53 +1392,42 @@ class Engine:
         my_pages = self.pool.request_pages(rid)
         # The dispatch's table covers every page, so the whole sequence
         # must be HBM-resident (earlier chunks' pages may have been evicted
-        # between steps — demand swap-in is a rental like any other).  Same
-        # atomic batched exchange as _prepare_batch: evictions and swap-ins
-        # stage together, so residency succeeds even when both free lists
-        # are empty (an evict-then-swap-in order would need host slots that
-        # a tightly-sized pool does not have).
-        missing = [p.page_id for p in my_pages if p.hbm_slot is None]
-        if missing:
-            shortfall = len(missing) - len(self.pool.free_hbm)
-            victims: List[int] = []
-            if shortfall > 0:
-                exclude = {p.page_id for p in my_pages}
-                cands = [p for p in self.pool.pages.values()
-                         if p.hbm_slot is not None
-                         and p.page_id not in exclude]
-                victims = self.eviction.pick_many(cands, self, shortfall)
-                if len(victims) < shortfall:
-                    # Unreachable: chunk pages <= usable.
-                    raise MemoryError("no evictable page")
-            with span("repro.kv.exchange",
-                      pages=len(victims) + len(missing)):
-                self.pool.exchange(victims, missing)
-            self._note_swap_in(len(missing))
-            my_pages = self.pool.request_pages(rid)
+        # between steps — demand swap-in is a rental like any other).
+        self._make_resident(
+            [p.page_id for p in my_pages],
+            [p.page_id for p in my_pages if p.hbm_slot is None])
         by_index = {p.index_in_seq: p for p in my_pages}
+        # Pad the token axis to a power-of-two bucket (>= one page) so jit
+        # compiles per bucket, not per chunk length.
         S = max(P, 1 << (n - 1).bit_length())
         tokens = np.zeros((S,), np.int32)
         tokens[:n] = context[start:start + n]
         slots = np.full((S,), self.scratch_slot, np.int32)
         offs = np.zeros((S,), np.int32)
-        written = set()
         for t in range(n):
             idx, off = divmod(start + t, P)
             page = by_index[idx]
             slots[t] = page.hbm_slot
             offs[t] = off
             page.tokens_used = max(page.tokens_used, off + 1)
-            written.add(idx)
         table = np.full((MP,), -1, np.int32)
         for p in my_pages:
             table[p.index_in_seq] = p.hbm_slot
-        self._run_prefill(tokens, table, slots, offs, n, start)
+        # The resident single-jit scan, or the layer-by-layer tiered
+        # pipeline: both write the pools in place and re-bind them.
+        args = (jnp.asarray(tokens), jnp.asarray(table), jnp.asarray(slots),
+                jnp.asarray(offs), jnp.int32(n), jnp.int32(start))
+        if self.expert_store is not None:
+            self._tiered_prefill(*args, mask=np.arange(S) < n)
+        else:
+            with span("repro.dispatch.step"):
+                self.pool.hbm = self._prefill(self.params, self.pool.hbm,
+                                              *args)
         req.pos = start + n
-        for idx in written:
-            if not by_index[idx].shared:
-                by_index[idx].accesses += 1   # chunk's write set
+        for idx in range(start // P, (start + n - 1) // P + 1):
+            if not by_index[idx].shared:      # the chunk's write set
+                by_index[idx].accesses += 1
         self.prefill_dispatches += 1
-        self.prefill_chunks += 1
         self.prefill_tokens += n
 
     def _preempt_one(self) -> bool:
@@ -1613,63 +1529,22 @@ class Engine:
             chain=chain)
 
     def _prefill_request(self, req: Request):
-        """Ingest ``req.context[:-1]`` (the last token is fed by the first
-        decode step).  The prefix cache is consulted first: matched blocks
-        attach by reference and only the uncovered suffix is ingested — one
-        jitted dispatch in one_shot mode (a FULL hit dispatches nothing);
-        the chunked oracle steps suffix tokens through decode."""
+        """The chunked oracle (``prefill="chunked"``): ingest
+        ``req.context[:-1]`` (the last token is fed by the first decode
+        step) by stepping it through decode one token at a time.  The
+        prefix cache is consulted first: matched blocks attach by reference
+        and only the uncovered suffix is stepped (a FULL hit steps
+        nothing)."""
         context = req.context
         n_ingest = len(context) - 1
-        if n_ingest == 0:
-            req.pos = 0
+        chain = self._match_prefix(req, context, n_ingest) if n_ingest else []
+        covered = len(chain) * self.cfg.page_size
+        req.pos = covered
+        if covered == n_ingest:          # empty, or a full hit
             return
-        P = self.cfg.page_size
-        rid = req.request_id
-        chain = self._match_prefix(req, context, n_ingest)
-        covered = len(chain) * P
-        n_suffix = n_ingest - covered
-        if n_suffix == 0:
-            # Full hit: every ingested token is already in shared pages.
-            req.pos = n_ingest
-            return
-        if self.cfg.prefill == "chunked":
-            req.pos = covered
-            for t in context[covered:-1]:
-                self._decode_one(req, t)
-            self.prefill_tokens += n_suffix
-            self._insert_prefix(req, context, n_ingest, chain)
-            return
-        MP = self.cfg.max_pages_per_seq
-        n_prefix_pages = covered // P
-        n_pages = -(-n_ingest // P) - n_prefix_pages
-        self._ensure_free_hbm(
-            n_pages, needed=[p.page_id
-                             for p in self.pool.request_pages(rid)])
-        pages = [self.pool.allocate(rid, n_prefix_pages + idx,
-                                    self.step_count)
-                 for idx in range(n_pages)]
-        # Pad the token axis to a power-of-two bucket (>= one page) so jit
-        # compiles per bucket, not per suffix length.
-        S = max(P, 1 << (n_suffix - 1).bit_length())
-        tokens = np.zeros((S,), np.int32)
-        tokens[:n_suffix] = context[covered:n_ingest]
-        slots = np.full((S,), self.scratch_slot, np.int32)
-        offs = np.zeros((S,), np.int32)
-        # ``covered`` is page-aligned, so suffix token t lands at page t//P
-        # offset t%P of the private tail.
-        for t in range(n_suffix):
-            slots[t] = pages[t // P].hbm_slot
-            offs[t] = t % P
-        table = np.full((MP,), -1, np.int32)
-        for p in self.pool.request_pages(rid):
-            table[p.index_in_seq] = p.hbm_slot
-        self._run_prefill(tokens, table, slots, offs, n_suffix, covered)
-        req.pos = n_ingest
-        for i, p in enumerate(pages):
-            p.accesses += 1         # the dispatch's access set: every page
-            p.tokens_used = min(P, n_suffix - i * P)
-        self.prefill_dispatches += 1
-        self.prefill_tokens += n_suffix
+        for t in context[covered:-1]:
+            self._decode_one(req, t)
+        self.prefill_tokens += n_ingest - covered
         self._insert_prefix(req, context, n_ingest, chain)
 
     # ------------------------------------------------------- page mgmt
@@ -1698,9 +1573,8 @@ class Engine:
         return page.hbm_slot, off
 
     def _prepare_batch(self, reqs: List[Request]):
-        """Make the whole scheduled batch resident with ONE atomic batched
-        exchange (evictions + swap-ins staged together, so it succeeds even
-        when both free lists are empty), then allocate write pages."""
+        """Make the whole scheduled batch resident, with a free slot for
+        each write page it lacks, then allocate those write pages."""
         P = self.cfg.page_size
         need_ids: List[int] = []
         missing: List[int] = []
@@ -1711,38 +1585,39 @@ class Engine:
             missing.extend(p.page_id for p in pages if p.hbm_slot is None)
             if r.pos // P >= len(pages):
                 n_alloc += 1
-        shortfall = len(missing) + n_alloc - len(self.pool.free_hbm)
-        victims: List[int] = []
-        if shortfall > 0:
-            exclude = set(need_ids)
-            cands = [p for p in self.pool.pages.values()
-                     if p.hbm_slot is not None and p.page_id not in exclude]
-            victims = self.eviction.pick_many(cands, self, shortfall)
-            if len(victims) < shortfall:
-                raise MemoryError("no evictable page")   # unreachable under
-        if victims or missing:                           # scheduler budgets
-            with span("repro.kv.exchange",
-                      pages=len(victims) + len(missing)):
-                self.pool.exchange(victims, missing)
-            self._note_swap_in(len(missing))
+        self._make_resident(need_ids, missing, n_alloc)
         for r in reqs:
             idx = r.pos // P
             if idx >= len(self.pool.request_pages(r.request_id)):
                 self.pool.allocate(r.request_id, idx, self.step_count)
 
     def _ensure_free_hbm(self, n: int, needed: List[int]):
-        shortfall = n - len(self.pool.free_hbm)
-        if shortfall <= 0:
-            return
-        exclude = set(needed)
-        cands = [p for p in self.pool.pages.values()
-                 if p.hbm_slot is not None and p.page_id not in exclude]
-        victims = self.eviction.pick_many(cands, self, shortfall)
-        if len(victims) < shortfall:
-            # Unreachable under scheduler budgets.
-            raise MemoryError("no evictable page")
-        with span("repro.kv.exchange", pages=len(victims)):
-            self.pool.swap_out_many(victims)
+        """Free ``n`` HBM slots by swapping out pages not in ``needed``."""
+        self._make_resident(needed, [], n)
+
+    def _make_resident(self, keep: Sequence[int], missing: Sequence[int],
+                       n_free: int = 0) -> None:
+        """Swap the ``missing`` pages in and leave ``n_free`` more HBM slots
+        free, evicting the policy's victims among pages not in ``keep``.
+        ONE atomic batched exchange: evictions and swap-ins stage together,
+        so it succeeds even when both free lists are empty (an
+        evict-then-swap-in order would need host slots that a
+        tightly-sized pool does not have)."""
+        shortfall = len(missing) + n_free - len(self.pool.free_hbm)
+        victims: List[int] = []
+        if shortfall > 0:
+            exclude = set(keep)
+            cands = [p for p in self.pool.pages.values()
+                     if p.hbm_slot is not None and p.page_id not in exclude]
+            victims = self.eviction.pick_many(cands, self, shortfall)
+            if len(victims) < shortfall:
+                # Unreachable under scheduler budgets.
+                raise MemoryError("no evictable page")
+        if victims or missing:
+            with span("repro.kv.exchange",
+                      pages=len(victims) + len(missing)):
+                self.pool.exchange(victims, missing)
+            self._note_swap_in(len(missing))
 
     # ============================================================ stepping
     def _decode_one(self, req: Request, token: int) -> int:

@@ -117,8 +117,7 @@ class ExpertStore:
     """
 
     def __init__(self, moe_params: Mapping[str, jax.Array], n_layers: int,
-                 n_experts: int, cache_slots: int, *,
-                 double_buffer: bool = True, device=None):
+                 n_experts: int, cache_slots: int, *, device=None):
         if cache_slots <= 0:
             raise ValueError(
                 f"ExpertStore needs at least one cache slot, got "
@@ -127,7 +126,6 @@ class ExpertStore:
         self.n_layers = L
         self.n_experts = E
         self.cache_slots = min(cache_slots, L * E)
-        self.double_buffer = double_buffer
         self.device = device if device is not None else jax.devices()[0]
         weights = [moe_params[name] for name in _WEIGHTS]
         for name, w in zip(_WEIGHTS, weights):
@@ -296,7 +294,7 @@ class ExpertStore:
         store falls back to recency + the guidance profile's hottest
         blocks.  Returns the number of blocks put in flight."""
         with span("repro.expert.prefetch", layer=layer):
-            if not self.double_buffer or layer in self._pending:
+            if layer in self._pending:
                 return 0
             if predicted is not None:
                 want_set = [int(e) for e in predicted]

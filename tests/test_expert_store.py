@@ -29,8 +29,7 @@ from repro.serve import (
 L, E, D, F = 2, 4, 4, 4
 
 
-def make_store(cache_slots, double_buffer=False, n_layers=L,
-               store=ExpertStore):
+def make_store(cache_slots, n_layers=L, store=ExpertStore):
     key = jax.random.PRNGKey(0)
     ks = jax.random.split(key, 3)
     moe_params = {
@@ -38,8 +37,7 @@ def make_store(cache_slots, double_buffer=False, n_layers=L,
         "w_up": jax.random.normal(ks[1], (n_layers, E, D, F), jnp.float32),
         "w_down": jax.random.normal(ks[2], (n_layers, E, F, D), jnp.float32),
     }
-    return moe_params, store(moe_params, n_layers, E, cache_slots,
-                             double_buffer=double_buffer)
+    return moe_params, store(moe_params, n_layers, E, cache_slots)
 
 
 def counts(*experts):
@@ -105,7 +103,7 @@ def test_working_set_overflow_raises_named_error():
 
 
 def test_prefetch_hit_skips_demand_and_miss_falls_back():
-    _, st = make_store(4, double_buffer=True)
+    _, st = make_store(4)
     st.dispatch(0, counts(0, 1), step=1)
     assert st.prefetch(1, step=1, predicted=[0, 1]) == 2
     st.dispatch(1, counts(0, 2), step=1)
@@ -115,18 +113,11 @@ def test_prefetch_hit_skips_demand_and_miss_falls_back():
 
 
 def test_prefetch_never_evicts_pins_or_its_own_forecast():
-    _, st = make_store(2, double_buffer=True)
+    _, st = make_store(2)
     st.dispatch(0, counts(0, 1), step=1)       # both slots pinned
     assert st.prefetch(1, step=1, predicted=[2, 3]) == 0
     assert st.dropped_prefetches == 2
     assert st.is_resident(0, 0) and st.is_resident(0, 1)
-
-
-def test_prefetch_disabled_in_sync_mode():
-    _, st = make_store(4, double_buffer=False)
-    st.dispatch(0, counts(0), step=1)
-    assert st.prefetch(1, step=1, predicted=[0, 1]) == 0
-    assert st.prefetch_fetches == 0
 
 
 def test_drop_many_refuses_dispatching_blocks():
@@ -191,7 +182,7 @@ def test_cyclic_scan_keeps_whole_layers_resident_across_steps():
     blocks: LRU evicts each block just before its next use, the next-use
     order streams about half the layers and keeps the rest whole."""
     n, slots = 8, 16
-    _, st = make_store(slots, double_buffer=True, n_layers=n)
+    _, st = make_store(slots, n_layers=n)
     decode_step(st, 0, lambda s, l: range(E))              # warm-up
     for step in range(1, 6):
         st.reset_counters()
@@ -202,7 +193,7 @@ def test_cyclic_scan_keeps_whole_layers_resident_across_steps():
         for l in range(n):
             held = sum(st.is_resident(l, e) for e in range(E))
             assert held in (0, E), (step, l, held)
-    _, lru = make_store(slots, double_buffer=True, n_layers=n, store=LRUStore)
+    _, lru = make_store(slots, n_layers=n, store=LRUStore)
     decode_step(lru, 0, lambda s, l: range(E))
     lru.reset_counters()
     assert decode_step(lru, 1, lambda s, l: range(E)) == n * E
@@ -224,7 +215,7 @@ def test_skewed_routing_that_fits_fetches_nothing_after_warm_up():
 
     runs = []
     for cls in (ExpertStore, LRUStore):
-        _, st = make_store(slots, double_buffer=True, n_layers=n, store=cls)
+        _, st = make_store(slots, n_layers=n, store=cls)
         fetched = [decode_step(st, step, route) for step in range(9)]
         assert fetched[4:] == [0] * 5, fetched
         runs.append((fetched, st.evictions))
@@ -254,7 +245,7 @@ def test_hot_blocks_go_farthest_next_use_first():
 
 
 def test_resident_hits_count_neither_prefetched_nor_demanded_blocks():
-    _, st = make_store(4, double_buffer=True)
+    _, st = make_store(4)
     st.dispatch(0, counts(0, 1), step=1)       # two demand fetches
     st.prefetch(1, step=1, predicted=[2])
     st.dispatch(1, counts(2), step=1)          # committed prefetch
@@ -341,15 +332,6 @@ def test_tiered_bitwise_equal_across_cache_sizes(moe_model,
         assert st.evictions > 0, "sweep must actually churn the cache"
 
 
-def test_double_buffer_equals_sync(moe_model, resident_reference):
-    """Prefetch is pure staging: a misprediction falls back to the demand
-    fetch, so db on/off both equal the resident reference bitwise."""
-    for db in (True, False):
-        llm = make_llm(moe_model, expert_offchip=True, expert_cache_size=8,
-                       expert_double_buffer=db)
-        assert_equal_runs(resident_reference, drive(llm, PROMPTS, PLIST))
-
-
 def test_chunked_prefill_tight_cache_bitwise_equal(moe_model,
                                                    resident_reference):
     """At the decode floor (4 slots) a one-shot prefill working set cannot
@@ -426,19 +408,21 @@ def test_serving_summary_reports_expert_counters(moe_model):
 
 def spy_pools(engine, names):
     """Wrap the engine programs ``names``: each call's pool tuple (argument
-    1) is kept, by program, for the test to inspect."""
-    handed = {n: [] for n in names}
+    1) is kept, by program and dispatch kind (None for the resident
+    programs), for the test to inspect."""
+    handed = {}
     for n in names:
-        def call(*args, _fn=getattr(engine, n), _seen=handed[n]):
-            _seen.append(args[1])
-            return _fn(*args)
+        def call(*args, _fn=getattr(engine, n), _n=n, **kw):
+            handed.setdefault((_n, kw.get("dispatch")), []).append(args[1])
+            return _fn(*args, **kw)
         setattr(engine, n, call)
     return handed
 
 
 @pytest.mark.parametrize("offchip, programs", [
-    (False, ("_prefill", "_decode_greedy", "_decode_sampled")),
-    (True, ("_t_prefill_attn", "_t_decode_attn")),
+    (False, (("_prefill", None), ("_decode_greedy", None),
+             ("_decode_sampled", None))),
+    (True, (("_t_attn", "prefill"), ("_t_attn", "decode"))),
 ], ids=["resident", "tiered"])
 def test_programs_take_the_pools_donated(moe_model, offchip, programs):
     """Every program that returns the pools updated consumes the arrays it
@@ -447,7 +431,7 @@ def test_programs_take_the_pools_donated(moe_model, offchip, programs):
     kw = dict(expert_offchip=True, expert_cache_size=8) if offchip else {}
     llm = make_llm(moe_model, **kw)
     engine = llm.cluster.replicas[0].engine
-    handed = spy_pools(engine, programs)
+    handed = spy_pools(engine, {n for n, _ in programs})
     prompts = [[3, 1, 4, 1, 5, 9], [2, 7, 1, 8, 2, 8]]
     params = [SamplingParams(max_tokens=6),
               SamplingParams(max_tokens=3, temperature=0.8, seed=7)]
@@ -455,10 +439,11 @@ def test_programs_take_the_pools_donated(moe_model, offchip, programs):
     while any(not h.finished for h in handles):
         llm.step()
         assert not any(a.is_deleted() for a in engine.pool.hbm)
-    for name, calls in handed.items():
-        assert calls, f"{name} never ran"
+    for program in programs:
+        calls = handed.get(program)
+        assert calls, f"{program} never ran"
         assert all(a.is_deleted() for pools in calls for a in pools), \
-            f"{name} did not take the pools donated"
+            f"{program} did not take the pools donated"
     for a in engine.pool.hbm:
         np.asarray(a)
 
@@ -469,12 +454,12 @@ def test_cache_miss_mid_step_leaves_the_pools_live(moe_model):
     still names the arrays that piece returned, live and readable."""
     llm = make_llm(moe_model, expert_offchip=True, expert_cache_size=4)
     engine = llm.cluster.replicas[0].engine
-    handed = spy_pools(engine, ("_t_prefill_attn",))
+    handed = spy_pools(engine, ("_t_attn",))
     with pytest.raises(ExpertCacheMissError, match="expert_cache_size"):
         llm.submit(list(range(1, 13)), SamplingParams(max_tokens=2))
         for _ in range(4):
             llm.step()
-    assert all(a.is_deleted() for a in handed["_t_prefill_attn"][0])
+    assert all(a.is_deleted() for a in handed["_t_attn", "prefill"][0])
     pools = engine.pool.hbm
     assert not any(a.is_deleted() for a in pools)
     for a in pools:

@@ -251,7 +251,7 @@ def make_expert_harness():
         "w_up": jax.random.normal(ks[1], (2, 4, 4, 4), jnp.float32),
         "w_down": jax.random.normal(ks[2], (2, 4, 4, 4), jnp.float32),
     }
-    store = ExpertStore(moe_params, 2, 4, 2, double_buffer=False)
+    store = ExpertStore(moe_params, 2, 4, 2)
     clock = {"step": 0}
     backend = ExpertBackend(store, clock=lambda: clock["step"])
     cap = store.cache_bytes

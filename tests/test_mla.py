@@ -348,14 +348,15 @@ def test_dense_layer_pieces_take_the_pools_donated(dsv2):
         max_batch=2, page_size=4, hbm_pages=48, host_pages=64,
         max_pages_per_seq=8, expert_offchip=True, expert_cache_size=0))
     engine = llm.cluster.replicas[0].engine
-    handed = {n: [] for n in ("_t_prefill_dense", "_t_decode_dense")}
-    for n, seen in handed.items():
-        def call(*args, _fn=getattr(engine, n), _seen=seen):
-            _seen.append(args[1])
-            return _fn(*args)
-        setattr(engine, n, call)
+    handed = {kind: [] for kind in ("prefill", "decode")}
+    dense = engine._t_dense
+
+    def call(*args, dispatch):
+        handed[dispatch].append(args[1])
+        return dense(*args, dispatch=dispatch)
+    engine._t_dense = call
     llm.generate([PROMPTS[1]], SamplingParams(max_tokens=3))
-    for name, calls in handed.items():
-        assert calls, f"{name} never ran"
-        assert all(a.is_deleted() for pools in calls for a in pools), name
+    for kind, calls in handed.items():
+        assert calls, f"the {kind} dense piece never ran"
+        assert all(a.is_deleted() for pools in calls for a in pools), kind
     assert not any(a.is_deleted() for a in engine.pool.hbm)

@@ -119,6 +119,38 @@ def test_one_shot_prefill_bitwise_equals_chunked(
     assert generated(one, 0) == generated(chunked, 0)
 
 
+def test_eager_and_interleaved_ingest_agree(model_and_params):
+    """Eager prefill is interleaved prefill in one chunk: for one prompt,
+    eager ingestion and interleaved ingestion under a budget of at least
+    the prompt leave the same page table, per-page accesses and
+    tokens_used, K/V bits and continuation, and the same stats apart from
+    the count of interleaved chunks."""
+    model, params = model_and_params
+    prompt = [(5 * j) % 50 + 1 for j in range(23)]
+
+    def run(chunk):
+        eng = Engine(model, params,
+                     ServeConfig(max_batch=2, page_size=4, hbm_pages=32,
+                                 host_pages=32, enable_prefix_cache=True,
+                                 prefill_chunk_tokens=chunk))
+        eng.add_request(0, prompt, max_new=6)
+        for _ in range(2):
+            eng.step()
+        pages = [(p.index_in_seq, p.hbm_slot, p.accesses, p.tokens_used)
+                 for p in eng.pool.request_pages(0)]
+        return (pages, request_pages_bits(eng, 0), eng.stats(),
+                generated(eng, 0))
+
+    eager, interleaved = run(0), run(len(prompt))
+    assert eager[0] == interleaved[0]
+    for a, b in zip(eager[1], interleaved[1]):
+        assert np.array_equal(a, b), "prefill K/V pages differ bitwise"
+    assert eager[3] == interleaved[3]
+    assert eager[2].pop("prefill_chunks") == 0
+    assert interleaved[2].pop("prefill_chunks") == 1
+    assert eager[2] == interleaved[2]
+
+
 def test_partial_batch_logits_match_full_batch(model_and_params):
     """Inactive batch rows are explicitly masked: a partial batch (2 live
     requests in a 4-slot batch) must produce bitwise the same logits for

@@ -162,13 +162,14 @@ def _copies_of(text, shape):
             and dims in m.group(1)]
 
 
-@pytest.mark.parametrize("piece", ["decode_attn", "prefill_attn"])
+@pytest.mark.parametrize("dispatch", ["decode", "prefill"])
 @pytest.mark.parametrize("arch", sorted(ENGINES))
 def test_tiered_piece_updates_the_pools_in_place_for_v5e(
-        one_chip, monkeypatch, arch, piece):
-    """The attention piece takes the pools donated and writes one layer's
-    rows in place: no copy of a whole pool (1.34 GB per array at granite's
-    cell, 1.59 GB at DeepSeek-V2-Lite's) on each of a step's layer calls."""
+        one_chip, monkeypatch, arch, dispatch):
+    """The attention piece, for either dispatch kind, takes the pools
+    donated and writes one layer's rows in place: no copy of a whole pool
+    (1.34 GB per array at granite's cell, 1.59 GB at DeepSeek-V2-Lite's) on
+    each of a step's layer calls."""
     from repro.kernels import ops
 
     monkeypatch.setattr(ops, "_MODE", "pallas")   # the chip's kernels
@@ -186,14 +187,14 @@ def test_tiered_piece_updates_the_pools_in_place_for_v5e(
     layers["moe"] = {"router": layers["moe"]["router"]}   # live off-chip
     params = {**params, "layers": layers}
     pools = tuple(sd(pool, dt) for _ in range(n_arrays))
-    if piece == "decode_attn":
+    if dispatch == "decode":
         rows = [sd((B,), I32)] * 3 + [sd((B,), jnp.bool_)]
         args = (sd((B, 1, d), dt), sd((), I32), sd((B, mp), I32), *rows)
     else:
         S = PREFILL_BUCKET
         rows = [sd((S,), I32)] * 4 + [sd((S,), jnp.bool_)]
         args = (sd((1, S, d), dt), sd((), I32), sd((mp,), I32), *rows)
-    fn = getattr(engine, f"_t_{piece}")
-    text = fn.lower(params, pools, *args).compile().as_text()
+    text = engine._t_attn.lower(params, pools, *args,
+                                dispatch=dispatch).compile().as_text()
     assert "tpu_custom_call" in text
     assert not _copies_of(text, pool), _copies_of(text, pool)
