@@ -956,27 +956,35 @@ class Engine:
         # ``_tiered_layers`` forecast the wrap-around to the first layer.
         nl = (l + 1) % self.model.cfg.moe_layers
         if nl:
-            self._spec_prefetch(nl, x, token_mask)
+            self._spec_prefetch(nl, lambda: x, token_mask)
         return x
 
-    def _spec_prefetch(self, l, x, token_mask):
+    def _spec_prefetch(self, l, residual, token_mask):
         """Forecast layer ``l``'s routed experts from the residual stream
-        ``x`` (speculative gating) and put the likeliest non-resident ones
-        in flight.  Live rows only — masked rows route garbage by design
-        (see ``_tiered_ffn``).  The forecast covers the rows' top-k picks
-        plus a small margin of next-likeliest experts by router mass: the
-        margin costs overlapped (hidden) bytes and buys back the picks
-        the missing attention delta flips."""
+        (``residual()``, speculative gating) and put the likeliest
+        non-resident ones in flight.  Live rows only — masked rows route
+        garbage by design (see ``_tiered_ffn``).  The forecast covers the
+        rows' top-k picks plus a small margin of next-likeliest experts by
+        router mass: the margin costs overlapped (hidden) bytes and buys
+        back the picks the missing attention delta flips.
+
+        Where that is every expert, the router's mass would only order
+        them, and reading it back waits for the FFN just enqueued: the
+        store stages whole layers instead, several ahead, with no read
+        (``ExpertStore.lead``)."""
         mcfg = self.model.moe_cfg
         live = int(np.sum(token_mask))
         if not live:
             return
+        cap = min(mcfg.padded_experts, live * mcfg.top_k + 2)
+        if cap == mcfg.padded_experts:
+            self.expert_store.lead(self.step_count)
+            return
         probs = self._read(
-            "spec", self._t_spec_route(self.params, x, jnp.int32(l)),
+            "spec", self._t_spec_route(self.params, residual(), jnp.int32(l)),
             layer=l)
         mass = probs[token_mask].sum(axis=0)
         order = np.argsort(-mass, kind="stable")
-        cap = min(mcfg.padded_experts, live * mcfg.top_k + 2)
         self.expert_store.prefetch(
             l, self.step_count, predicted=[int(e) for e in order[:cap]])
 
@@ -1020,7 +1028,7 @@ class Engine:
         # scheduling gap.  Batch rotation between steps makes this a
         # forecast, not an oracle; mispredictions demand-fetch as usual.
         self._spec_prefetch(
-            0, self._t_embed(self.params, next_tokens[:, None]), mask)
+            0, lambda: self._t_embed(self.params, next_tokens[:, None]), mask)
         return logits, next_tokens
 
     def _tiered_prefill(self, tokens, page_table, slots, offs, n_real,
@@ -1820,6 +1828,8 @@ class Engine:
             "expert_transfer_events": es.transfer_events,
             "expert_needed_blocks": es.needed_blocks,
             "expert_resident_hits": es.resident_hits,
+            "expert_committed_blocks": es.committed_blocks,
+            "expert_landed_blocks": es.landed_blocks,
         } if es is not None else {}
         return {
             **prefix,

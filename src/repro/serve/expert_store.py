@@ -23,15 +23,20 @@ Three layers live here:
   the cache keeps whole layers resident instead of evicting each block
   just before its next use.
 
-* **Double-buffered prefetch** — while layer L's grouped GEMM dispatch is
-  in flight, the predicted working set for the next layer is staged onto
-  the device on a second buffer (``prefetch``), and the cache scatter is
-  committed when that layer actually dispatches (``_commit_pending``).
-  Prediction fuses recency (that layer's previous dispatch) with the
-  guidance profile (hottest non-resident blocks by access count — the
-  same counters the controller consumes).  A misprediction falls back to
-  the blocking demand fetch, so results are bitwise-identical with the
-  prefetcher on or off.
+* **Prefetch** — while layer L's grouped GEMM dispatch is in flight, the
+  predicted working set for the next layer is staged onto the device on a
+  second buffer and takes its cache slots (``prefetch``); the cache
+  scatter is committed when that layer actually dispatches
+  (``_commit_pending``).  Prediction fuses recency (that layer's previous
+  dispatch) with the guidance profile (hottest non-resident blocks by
+  access count — the same counters the controller consumes).  Where the
+  engine's forecast names every expert, ``lead`` also puts the whole
+  slabs of the next layers that miss blocks on the link, skipping layers
+  that are resident, up to ``LEAD`` slabs staged or in flight; a slab
+  staged ahead takes no cache slot until its layer is next, so victims
+  and slots are what the one-layer lead picks.  A misprediction falls
+  back to the blocking demand fetch, so a prefetch never changes a
+  result.
 
 * **ExpertBackend** — the ``TierBackend`` face: arena = one layer's expert
   population, chunk = one expert block.  Per-dispatch ``group_sizes`` from
@@ -54,7 +59,10 @@ The store keeps counts, not times.  What its work costs is read from a
 profiler trace of a chip run: ``dispatch`` and ``prefetch`` run under the
 spans ``repro.expert.dispatch`` and ``repro.expert.prefetch``, and each
 host-to-HBM transfer and its cache row writes under
-``repro.expert.transfer`` (``core/spans.py``).
+``repro.expert.transfer`` (``core/spans.py``).  ``committed_blocks`` and
+``landed_blocks`` count the prefetched blocks committed and those whose
+slab had already landed in HBM by then: how much of the transfer the
+lead hid.
 """
 
 from __future__ import annotations
@@ -73,6 +81,9 @@ from ..core.spans import span
 from .tier_io import HostTier, copy_rows
 
 _WEIGHTS = ("w_gate", "w_up", "w_down")
+# Layer slabs on the device ahead of their commit at most: one on the link,
+# one landed and waiting.  Two keep a saturated link busy.
+LEAD = 2
 
 
 class ExpertCacheMissError(RuntimeError):
@@ -155,6 +166,10 @@ class ExpertStore:
         self._pinned: FrozenSet[int] = frozenset()   # last dispatch's slots
         self._reserved: set = set()                  # pending-prefetch slots
         self._pending: Dict[int, _PendingFetch] = {}
+        # Whole layer slabs staged ahead (``lead``), no slots taken yet:
+        # layer -> (HostTier.fetch's staged slabs, each expert's location).
+        self._ahead: Dict[int, Tuple[list, Dict[int, Tuple[int, int]]]] = {}
+        self._next = 0                  # the layer that dispatches next
         self._last_prefetched: Dict[int, FrozenSet[int]] = {}
         self.dispatching: Dict[int, FrozenSet[int]] = {}
         self.prev_needed: Dict[int, List[int]] = {}
@@ -189,6 +204,8 @@ class ExpertStore:
         self.transfer_events = 0
         self.needed_blocks = 0        # blocks dispatches read
         self.resident_hits = 0        # ... already resident beforehand
+        self.committed_blocks = 0     # prefetched blocks committed
+        self.landed_blocks = 0        # ... whose slab had landed by then
 
     def take_rental_bytes(self) -> int:
         """Drain bytes demand-fetched since the last drain — the engine
@@ -198,13 +215,25 @@ class ExpertStore:
         return nb
 
     # ------------------------------------------------------------ movement
-    def _transfer(self, pairs: Sequence[Tuple[int, int]]):
+    def _send(self, pairs: Sequence[Tuple[int, int]]):
         """ONE batched host->device transfer per weight array of the layer
-        slabs holding the blocks.  Returns ``HostTier.fetch``'s staging;
-        the cache writes happen at `_install`."""
+        slabs holding the blocks: ``HostTier.fetch``'s staging."""
         with span("repro.expert.transfer", blocks=len(pairs)):
             staged = self.host.fetch([self.chunk_id(l, e) for l, e in pairs])
         self.transfer_events += len(_WEIGHTS)
+        return staged
+
+    def _transfer(self, pairs: Sequence[Tuple[int, int]]):
+        """The staging of ``pairs`` for `_install`: from their layer's slab
+        staged ahead (``lead``) when there is one, else sent now."""
+        layers = {l for l, _ in pairs}
+        ahead = (self._ahead.pop(layers.pop(), None) if len(layers) == 1
+                 else None)
+        if ahead is None:
+            staged = self._send(pairs)
+        else:
+            slabs, where = ahead
+            staged = (slabs, [where[e] for _, e in pairs])
         self.bytes_fetched += self.block_bytes * len(pairs)
         return staged
 
@@ -277,19 +306,33 @@ class ExpertStore:
         if pend is None:
             return
         self._reserved.difference_update(pend.slots)
+        slabs, locs = pend.staged
+        landed = [all(a.is_ready() for a in slab) for slab in slabs]
+        self.committed_blocks += len(locs)
+        self.landed_blocks += sum(landed[p] for p, _ in locs)
         pairs = [(layer, e) for e in pend.experts]
         self._install(pairs, pend.slots, pend.staged, step, prefetched=True)
         self._last_prefetched[layer] = frozenset(pend.experts)
+
+    def _ranking(self, layer: int) -> List[int]:
+        """Every expert of ``layer``, likeliest first: those its last
+        dispatch named (recency), then the rest by the guidance profile's
+        access count."""
+        prev = self.prev_needed.get(layer, [])
+        rest = sorted((self.blocks[(layer, e)] for e in range(self.n_experts)
+                       if e not in prev), key=lambda b: (-b.accesses, b.expert))
+        return list(prev) + [b.expert for b in rest]
 
     def prefetch(self, layer: int, step: int,
                  predicted: Optional[Sequence[int]] = None) -> int:
         """Issue the double-buffer for ``layer``: stage its predicted
         working set onto the device while the current dispatch computes.
 
-        ``predicted`` is the engine's speculative-gating forecast (the
+        ``predicted`` is the engine's forecast, likeliest first: the
         layer's own router applied to the residual stream one attention
-        delta early, hottest first) — when given, exactly its non-resident
-        members are staged.  Without it (the wrap-around to the next
+        delta early, or every expert (``lead``) — when given, exactly its
+        non-resident members are staged, out of the layer's slab staged
+        ahead when there is one.  Without it (the wrap-around to the next
         step's first dispatch, whose input token does not exist yet) the
         store falls back to recency + the guidance profile's hottest
         blocks.  Returns the number of blocks put in flight."""
@@ -301,21 +344,14 @@ class ExpertStore:
                 targets = [e for e in want_set
                            if not self.is_resident(layer, e)]
             else:
-                prev = self.prev_needed.get(layer)
-                if not prev:
+                want_set = self.prev_needed.get(layer)
+                if not want_set:
                     return 0                  # never dispatched: no prediction
-                want = len(prev)
-                # Recency first (last dispatch of this layer), then the layer's
-                # hottest blocks by profile access count — guided prefetch.
-                ranked = sorted(
-                    (self.blocks[(layer, e)] for e in range(self.n_experts)
-                     if e not in prev),
-                    key=lambda b: (-b.accesses, b.expert))
-                want_set = list(prev)
-                targets = [e for e in prev if not self.is_resident(layer, e)]
-                targets += [b.expert for b in ranked
-                            if b.slot is None][:max(want - len(targets), 0)]
-                targets = targets[:want]
+                # Recency first (last dispatch of this layer), then the
+                # layer's hottest blocks by profile access count — guided
+                # prefetch.
+                targets = [e for e in self._ranking(layer)
+                           if not self.is_resident(layer, e)][:len(want_set)]
             if not targets:
                 return 0
             # A prefetch must not evict what it is predicting around: protect
@@ -335,6 +371,36 @@ class ExpertStore:
             self._reserved.update(slots)
             self._pending[layer] = _PendingFetch(layer, targets, slots, staged)
             return len(targets)
+
+    def lead(self, step: int) -> None:
+        """The prefetch when the forecast names every expert.  The layer
+        that dispatches next is staged whole (``prefetch`` in
+        ``_ranking``'s order) and takes its cache slots now, as under a
+        one-layer lead.  Then the slabs of the first ``LEAD`` layers in
+        cycle order that miss blocks are staged or put on the link; layers
+        whose every block is resident are skipped, so the link keeps
+        moving while they dispatch, and a slab staged ahead for a layer
+        that is no longer among them is dropped.  A slab staged ahead
+        takes no cache slot: its layer picks its victims and reserves its
+        slots when it is next (or at its dispatch), so no layer that
+        dispatches before it loses a block or a slot to it."""
+        L, E = self.n_layers, self.n_experts
+        nxt = self._next
+        self.prefetch(nxt, step, predicted=self._ranking(nxt))
+        lead: List[int] = []
+        for d in range(L):
+            m = (nxt + d) % L
+            if any(self.blocks[(m, e)].slot is None for e in range(E)):
+                lead.append(m)
+                if len(lead) == LEAD:
+                    break
+        for m in [m for m in self._ahead if m not in lead]:
+            del self._ahead[m]      # resident by now, or nearer layers miss
+        for m in lead:
+            if m not in self._pending and m not in self._ahead:
+                with span("repro.expert.prefetch", layer=m):
+                    slabs, locs = self._send([(m, e) for e in range(E)])
+                self._ahead[m] = (slabs, dict(enumerate(locs)))
 
     # ------------------------------------------------------------ dispatch
     def dispatch(self, layer: int, counts, step: int) -> np.ndarray:
@@ -393,6 +459,9 @@ class ExpertStore:
                 int(slot_map[e]) for e in needed)
             self.dispatching[layer] = frozenset(needed)
             self.prev_needed[layer] = needed
+            # A slab staged ahead that this dispatch did not take is stale.
+            self._ahead.pop(layer, None)
+            self._next = (layer + 1) % self.n_layers
             return slot_map
 
     # ----------------------------------------------------- controller face

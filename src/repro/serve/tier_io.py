@@ -24,8 +24,11 @@ by the slot number the caller gives it.
 
 A slab's host memory is freed when its last row is released.  Fetching a
 row moves its whole slab, so callers park rows that travel together (one
-request's pages, one layer's experts) in one slab.  Every transfer is
-synchronous staging; making it asynchronous is separate work.
+request's pages, one layer's experts) in one slab.  ``fetch`` returns the
+staged arrays as ``jax.device_put`` does, and its callers install them at
+once (the KV tier) or several dispatches later (the expert store's lead).
+Whether that call returns before the bytes land on a TPU v5e, and whether
+the copy runs beside programs, is not measured yet (PERF.md §7).
 """
 
 from __future__ import annotations

@@ -256,6 +256,130 @@ def test_resident_hits_count_neither_prefetched_nor_demanded_blocks():
     assert st.needed_blocks == st.resident_hits == 0
 
 
+def lead_step(st, step, route, check=None):
+    """One decode step in the tiered pipeline's order when the forecast
+    names every expert: dispatch layer l, then ``lead`` (after the last
+    layer, the wrap-around's).  ``check(before, after)`` sees the store
+    around every ``lead``.  Returns the blocks fetched."""
+    before = st.prefetch_fetches + st.demand_fetches
+    for l in range(st.n_layers):
+        st.dispatch(l, counts(*route(step, l)), step=step)
+        snap = residency(st)
+        st.lead(step)
+        if check is not None:
+            check(snap, residency(st))
+    return st.prefetch_fetches + st.demand_fetches - before
+
+
+def residency(st):
+    return {(l, e) for (l, e), b in st.blocks.items() if b.slot is not None}
+
+
+def staged_layers(st):
+    return set(st._pending) | set(st._ahead)
+
+
+def missing_order(st, resident):
+    """Layers in cycle order from the next dispatch that miss a block."""
+    n = st.n_layers
+    cycle = [(st._next + d) % n for d in range(n)]
+    return [m for m in cycle
+            if any((m, e) not in resident for e in range(E))]
+
+
+def test_lead_stages_the_next_two_missing_layers_and_skips_resident_ones():
+    n, slots = 8, 16
+    _, st = make_store(slots, n_layers=n)
+    seen = []
+
+    def check(before, after):
+        staged = staged_layers(st)
+        assert staged == set(missing_order(st, before)[:2]), staged
+        seen.append(staged)
+
+    every = lambda s, l: range(E)
+    lead_step(st, 0, every)                                 # warm-up
+    for step in range(1, 4):
+        lead_step(st, step, every, check)
+    # Resident layers were skipped: some lead staged a layer beyond the
+    # next dispatch.
+    assert any(m != st._next for s in seen for m in s)
+
+
+def test_lead_never_holds_more_than_two_slabs():
+    n, slots = 8, 16
+    rng = np.random.default_rng(7)
+    _, st = make_store(slots, n_layers=n)
+    sizes = []
+
+    def check(before, after):
+        sizes.append(len(staged_layers(st)))
+
+    for step in range(6):
+        route = {l: rng.choice(E, rng.integers(1, E + 1), replace=False)
+                 for l in range(n)}
+        lead_step(st, step, lambda s, l: route[l], check)
+    assert max(sizes) == 2
+
+
+def test_lead_never_evicts_a_block_of_a_layer_dispatching_before_a_staged_one():
+    n, slots = 8, 16
+    _, st = make_store(slots, n_layers=n)
+
+    def check(before, after):
+        cycle = [(st._next + d) % n for d in range(n)]
+        last = max(cycle.index(m) for m in staged_layers(st))
+        for m in cycle[:last]:
+            lost = {(l, e) for l, e in before - after if l == m}
+            assert not lost, (m, lost)
+
+    for step in range(5):
+        lead_step(st, step, lambda s, l: range(E), check)
+
+
+def test_lead_fetches_what_the_one_layer_lead_fetches():
+    """The same blocks move as under the one-layer lead, within the cyclic
+    scan's bound, with no demand fetch: only when their slabs go on the
+    link changes.  Every committed block is counted, landed or not."""
+    n, slots = 8, 16
+    runs = []
+    for step_fn in (decode_step, lead_step):
+        _, st = make_store(slots, n_layers=n)
+        fetched, hits = [], []
+        for step in range(6):
+            st.reset_counters()
+            fetched.append(step_fn(st, step, lambda s, l: range(E)))
+            hits.append(st.resident_hits)
+            assert st.demand_fetches == 0 or step == 0
+            assert 0 <= st.landed_blocks <= st.committed_blocks
+        assert all(f <= n * E - slots + 2 * E for f in fetched[1:])
+        runs.append((fetched, hits, st.bytes_fetched))
+    assert runs[0] == runs[1]
+
+
+@pytest.mark.parametrize("floor", [E, E - 1])
+def test_tight_cache_never_raises_where_the_one_layer_lead_did_not(floor):
+    """The cache at the decode floor plus one layer, routing of up to
+    ``floor`` experts a layer: staging two slabs ahead never turns a
+    dispatch the one-layer lead served into an ``ExpertCacheMissError``."""
+    n = 6
+    for seed in range(4):
+        rng = np.random.default_rng(seed)
+        routes = [{l: rng.choice(E, rng.integers(1, floor + 1),
+                                 replace=False) for l in range(n)}
+                  for _ in range(6)]
+        outcome = []
+        for step_fn in (decode_step, lead_step):
+            _, st = make_store(floor + E, n_layers=n)
+            try:
+                for step, route in enumerate(routes):
+                    step_fn(st, step, lambda s, l: route[l])
+                outcome.append(None)
+            except ExpertCacheMissError as e:
+                outcome.append(e)
+        assert outcome == [None, None], outcome
+
+
 # ============================================== engine-level equivalence
 @pytest.fixture(scope="module")
 def moe_model():
@@ -330,6 +454,29 @@ def test_tiered_bitwise_equal_across_cache_sizes(moe_model,
     st = llm.engine.expert_store
     if cache < 16:
         assert st.evictions > 0, "sweep must actually churn the cache"
+
+
+def test_lead_in_decode_bitwise_equal(moe_model):
+    """Four rows x top-2 + 2 >= 8 experts: decode stages whole layers ahead
+    with no forecast read, and still matches the resident path bitwise,
+    at a cache that churns."""
+    model, params = moe_model
+    prompts = PROMPTS + [[4, 4, 2, 7, 1, 9]]
+    plist = PLIST + [SamplingParams(max_tokens=6, temperature=0.7, seed=5)]
+
+    def run(**kw):
+        llm = LLM(model, params, ServeConfig(
+            max_batch=4, page_size=4, hbm_pages=48, host_pages=64,
+            max_pages_per_seq=16, interval_steps=4, keep_logits=True, **kw))
+        return llm, drive(llm, prompts, plist)
+
+    _, ref = run()
+    llm, got = run(expert_offchip=True, expert_cache_size=8)
+    assert_equal_runs(ref, got)
+    stats = llm.engine.stats()
+    assert stats["expert_evictions"] > 0
+    assert 0 < stats["expert_landed_blocks"] \
+        <= stats["expert_committed_blocks"]
 
 
 def test_chunked_prefill_tight_cache_bitwise_equal(moe_model,

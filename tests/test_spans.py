@@ -93,3 +93,37 @@ def test_host_syncs_counts_the_sync_spans(traced):
     # forecast of every layer after the first and of the next step's
     # first, and the sampled tokens.
     assert delta == STEPS * (2 * cfg.n_layers + 1)
+
+
+def test_a_forecast_of_every_expert_is_not_read_back(tmp_path):
+    """At 4 rows x top-2 + 2 >= 8 experts the forecast names every expert:
+    the store stages whole layers ahead and the engine reads back only the
+    routed picks of each layer and the sampled tokens."""
+    cfg = dataclasses.replace(get_smoke("granite_moe_3b_a800m"),
+                              remat=False)
+    model = build_model(cfg)
+    assert 4 * model.moe_cfg.top_k + 2 >= model.moe_cfg.padded_experts
+    llm = LLM(model, model.init(jax.random.PRNGKey(0)), ServeConfig(
+        max_batch=4, page_size=4, hbm_pages=48, host_pages=64,
+        max_pages_per_seq=16, interval_steps=2, policy="gdt",
+        expert_offchip=True,
+        expert_cache_size=cfg.n_layers * cfg.n_experts // 2))
+    for rid in range(4):
+        llm.submit([3 + rid, 1, 4, 1, 5, 9], SamplingParams(max_tokens=16),
+                   request_id=rid)
+    for _ in range(2):
+        llm.step()
+    before = llm.stats()["host_syncs"]
+    span_trace.start(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("bench.window"):
+            for _ in range(STEPS):
+                llm.step()
+    finally:
+        jax.profiler.stop_trace()
+    events = [e for e in span_trace.load_dir(tmp_path)
+              if e.name.startswith("repro.")]
+    names = Counter(e.name for e in events)
+    assert names["repro.engine.decode"] == STEPS
+    assert names["repro.sync.spec"] == 0
+    assert llm.stats()["host_syncs"] - before == STEPS * (cfg.n_layers + 1)
