@@ -29,6 +29,7 @@ ARCHS: List[str] = [
     "phi3_vision_4_2b",
     "xlstm_350m",
     "deepseek_v2_lite",
+    "granite_4_0_h_small",
 ]
 
 # Archs whose long_500k cell runs (sub-quadratic decode state).

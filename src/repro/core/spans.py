@@ -11,7 +11,9 @@ function a span would time the tracing, not the run.
 Names start with ``repro.`` and follow the layers: ``repro.engine.*``
 (the step and its phases), ``repro.kv.*``, ``repro.dispatch.*`` (the
 enqueue of a jitted program), ``repro.sync.*`` (a blocking device-to-host
-read), ``repro.expert.*`` and ``repro.guidance.*``.
+read), ``repro.expert.*`` and ``repro.guidance.*``;
+``repro.engine.state`` marks a recurrent-state slot handed out or given
+back (models with Mamba layers).
 """
 
 from __future__ import annotations

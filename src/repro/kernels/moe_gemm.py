@@ -49,6 +49,10 @@ from . import ref
 F32 = jnp.float32
 DEFAULT_BLOCK_T = 128
 DEFAULT_BLOCK_F = 512
+# VMEM for the double-buffered gate, up and down weight tiles of one grid
+# step: the ff tile shrinks at wide d to keep them, with the row tiles and
+# the accumulator, within the 16 MiB scoped VMEM.
+WEIGHT_VMEM = 10 << 20
 
 
 def _round_up(n: int, m: int) -> int:
@@ -154,7 +158,8 @@ def _grouped_ffn_fwd(x, w_gate, w_up, w_down, group_sizes, group_experts, *,
     E, _, f = w_gate.shape
     G = group_sizes.shape[0]
     d_p = _round_up(d, 128)
-    bf = _ff_block(f, block_f)
+    fit = WEIGHT_VMEM // (6 * d_p * w_gate.dtype.itemsize) // 128 * 128
+    bf = _ff_block(f, max(128, min(block_f, fit)))
     f_p = _round_up(f, bf)
     T_p = _round_up(T, block_t)
     tiles_m = T_p // block_t

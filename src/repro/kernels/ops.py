@@ -51,20 +51,26 @@ def flash_attention(q, k, v, causal: bool = True,
 
 
 def paged_attention(q, k_pool, v_pool, page_table, lengths,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None,
+                    scale: Optional[float] = None):
+    """Decode attention over paged K/V pools, (N,P,K,dh) or flat
+    (N,P,K*dh); ``scale`` defaults to 1/sqrt(dh)."""
     mode = current_mode()
     if mode == "reference":
         return ref.paged_attention_reference(q, k_pool, v_pool, page_table,
-                                             lengths, window=window)
+                                             lengths, window=window,
+                                             scale=scale)
     from .paged_attention import paged_attention_pallas
 
     return paged_attention_pallas(q, k_pool, v_pool, page_table, lengths,
                                   window=window,
-                                  interpret=(mode == "interpret"))
+                                  interpret=(mode == "interpret"),
+                                  scale=scale)
 
 
 def paged_prefill(q, k_pool, v_pool, page_table, lengths,
-                  window: Optional[int] = None):
+                  window: Optional[int] = None,
+                  scale: Optional[float] = None):
     """One-shot prompt attention over paged KV: the S prompt tokens of one
     sequence attend as S query rows over a shared page table, with row t's
     causal visibility carried by ``lengths[t]`` (0 disables a padded row).
@@ -76,12 +82,14 @@ def paged_prefill(q, k_pool, v_pool, page_table, lengths,
     mode = current_mode()
     if mode == "reference":
         return ref.paged_prefill_reference(q, k_pool, v_pool, page_table,
-                                           lengths, window=window)
+                                           lengths, window=window,
+                                           scale=scale)
     from .paged_attention import paged_prefill_pallas
 
     return paged_prefill_pallas(q, k_pool, v_pool, page_table, lengths,
                                 window=window,
-                                interpret=(mode == "interpret"))
+                                interpret=(mode == "interpret"),
+                                scale=scale)
 
 
 def paged_mla_decode(q, pool, page_table, lengths, scale: float, dv: int):
@@ -132,6 +140,44 @@ def sample_tokens(logits, seeds, positions, temperature, top_k, top_p):
 
     return _sample_tokens(logits, seeds, positions, temperature, top_k,
                           top_p)
+
+
+def ssm_decode(pool, layer, slots, x, dt, A, D, Bm, Cm):
+    """Mamba-2 decode step of R rows over layer ``layer`` of a recurrent
+    state pool (Lm, slots, N, H*P) f32, each row's state at
+    ``slots[r]``, updated in place.  x (R, H*P); dt (R, H) f32 after
+    softplus; A, D (H,); Bm, Cm (R, G, N).  Returns (y (R, H*P) f32 with
+    the D term, the pool)."""
+    mode = current_mode()
+    if mode == "reference":
+        return ref.ssm_decode_reference(pool, layer, slots, x, dt, A, D,
+                                        Bm, Cm)
+    from .ssm_decode import ssm_decode_pallas
+
+    return ssm_decode_pallas(pool, layer, slots, x, dt, A, D, Bm, Cm,
+                             interpret=(mode == "interpret"))
+
+
+def ssm_state_read(pool, layer, slot):
+    """Row ``slot`` of layer ``layer`` of a state pool, (N, H*P)."""
+    mode = current_mode()
+    if mode == "reference":
+        return pool[layer, slot]
+    from .ssm_decode import ssm_state_read_pallas
+
+    return ssm_state_read_pallas(pool, layer, slot,
+                                 interpret=(mode == "interpret"))
+
+
+def ssm_state_write(pool, layer, slot, state):
+    """The pool with row ``slot`` of layer ``layer`` set to ``state``."""
+    mode = current_mode()
+    if mode == "reference":
+        return pool.at[layer, slot].set(state.astype(pool.dtype))
+    from .ssm_decode import ssm_state_write_pallas
+
+    return ssm_state_write_pallas(pool, layer, slot, state,
+                                  interpret=(mode == "interpret"))
 
 
 def ssd_scan(x, dt, A, Bm, Cm):

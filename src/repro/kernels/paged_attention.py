@@ -44,11 +44,11 @@ NEG_INF = -1e30
 
 def _kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
             m_scr, l_scr, acc_scr, *,
-            page_size: int, dh: int, window: Optional[int],
+            page_size: int, scale: float, window: Optional[int],
             shared_table: bool):
     _page_step(table_ref, len_ref, q_ref, lambda: k_ref[0],
                lambda k: v_ref[0].astype(F32), o_ref, m_scr, l_scr, acc_scr,
-               page_size=page_size, scale=1.0 / np.sqrt(dh),
+               page_size=page_size, scale=scale,
                window=window, shared_table=shared_table)
 
 
@@ -114,15 +114,21 @@ def _page_step(table_ref, len_ref, q_ref, keys, values, o_ref,
 
 
 def _paged_call(q, k_pool, v_pool, page_table, lengths, *,
-                window: Optional[int], interpret: bool, shared_table: bool):
-    """q: (R,H,dh); page_table: (R,MP), or (1,MP) shared by every row."""
+                window: Optional[int], interpret: bool, shared_table: bool,
+                scale: Optional[float]):
+    """q: (R,H,dh); pools (N,P,K,dh) or already flat (N,P,K*dh);
+    page_table: (R,MP), or (1,MP) shared by every row."""
     R, H, dh = q.shape
-    N, P, K, _ = k_pool.shape
+    N, P = k_pool.shape[:2]
+    K = k_pool.shape[-1] // dh if k_pool.ndim == 3 else k_pool.shape[2]
     MP = page_table.shape[1]
     G = H // K
+    if scale is None:
+        scale = 1.0 / np.sqrt(dh)
 
-    # Pools flattened to (N, P, K*dh) lane-padded tiles (a free reshape
-    # whenever K*dh is a multiple of 128, as at every served width).
+    # Pools flattened to (N, P, K*dh) lane-padded tiles.  The reshape of a
+    # (N, P, K, dh) pool changes its tiled layout, so XLA copies it; a
+    # pool kept flat is read as it lies.
     kd = K * dh
     kd_p = ((kd + 127) // 128) * 128
     kp = jnp.pad(k_pool.reshape(N, P, kd), ((0, 0), (0, 0), (0, kd_p - kd)))
@@ -139,7 +145,7 @@ def _paged_call(q, k_pool, v_pool, page_table, lengths, *,
         return (jnp.maximum(table[row, p], 0), 0, 0)
 
     out = pl.pallas_call(
-        functools.partial(_kernel, page_size=P, dh=dh, window=window,
+        functools.partial(_kernel, page_size=P, scale=scale, window=window,
                           shared_table=shared_table),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,      # page_table, lengths
@@ -168,10 +174,11 @@ def _paged_call(q, k_pool, v_pool, page_table, lengths, *,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("window", "interpret"))
+    jax.jit, static_argnames=("window", "interpret", "scale"))
 def paged_prefill_pallas(q, k_pool, v_pool, page_table, lengths,
                          window: Optional[int] = None,
-                         interpret: bool = False):
+                         interpret: bool = False,
+                         scale: Optional[float] = None):
     """One-shot prompt attention: S query rows of one sequence over a
     shared page table, causality via per-row ``lengths``.  The decode
     kernel with grid (S, MP) and the ONE table row every query row reads
@@ -181,18 +188,22 @@ def paged_prefill_pallas(q, k_pool, v_pool, page_table, lengths,
 
     q: (S,H,dh); page_table: (MP,) int32; lengths: (S,) int32."""
     return _paged_call(q, k_pool, v_pool, page_table[None, :], lengths,
-                       window=window, interpret=interpret, shared_table=True)
+                       window=window, interpret=interpret, shared_table=True,
+                       scale=scale)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("window", "interpret"))
+    jax.jit, static_argnames=("window", "interpret", "scale"))
 def paged_attention_pallas(q, k_pool, v_pool, page_table, lengths,
                            window: Optional[int] = None,
-                           interpret: bool = False):
-    """q: (B,H,dh); k_pool/v_pool: (N,P,K,dh); page_table: (B,MP) int32
-    (-1 = unused); lengths: (B,).  Returns (B,H,dh)."""
+                           interpret: bool = False,
+                           scale: Optional[float] = None):
+    """q: (B,H,dh); k_pool/v_pool: (N,P,K,dh), or flat (N,P,K*dh);
+    page_table: (B,MP) int32 (-1 = unused); lengths: (B,).  Scores are
+    scaled by ``scale`` (default 1/sqrt(dh)).  Returns (B,H,dh)."""
     return _paged_call(q, k_pool, v_pool, page_table, lengths,
-                       window=window, interpret=interpret, shared_table=False)
+                       window=window, interpret=interpret, shared_table=False,
+                       scale=scale)
 
 
 @functools.partial(

@@ -40,19 +40,26 @@ def mha_reference(q, k, v, causal: bool = True,
 
 # ------------------------------------------------------- paged attention
 def paged_attention_reference(q, k_pool, v_pool, page_table, lengths,
-                              window: Optional[int] = None) -> jax.Array:
+                              window: Optional[int] = None,
+                              scale: Optional[float] = None) -> jax.Array:
     """Decode-time attention over paged KV.
 
     q: (B, H, dh) — one new token per sequence.
-    k_pool/v_pool: (N_pages, P, K, dh) — one layer's HBM page pool.
+    k_pool/v_pool: (N_pages, P, K, dh) — one layer's HBM page pool (or
+    flat, (N_pages, P, K*dh)).
     page_table: (B, MP) int32 — pool slot per logical page, -1 = unused.
     lengths: (B,) int32 — tokens so far (including the new one).
+    ``scale`` defaults to 1/sqrt(dh).
     """
     B, H, dh = q.shape
+    if k_pool.ndim == 3:
+        k_pool = k_pool.reshape(k_pool.shape[:2] + (-1, dh))
+        v_pool = v_pool.reshape(v_pool.shape[:2] + (-1, dh))
     N, P, K, _ = k_pool.shape
     MP = page_table.shape[1]
     G = H // K
-    scale = 1.0 / np.sqrt(dh)
+    if scale is None:
+        scale = 1.0 / np.sqrt(dh)
 
     safe = jnp.maximum(page_table, 0)
     k = k_pool[safe]            # (B, MP, P, K, dh)
@@ -75,7 +82,8 @@ def paged_attention_reference(q, k_pool, v_pool, page_table, lengths,
 
 
 def paged_prefill_reference(q, k_pool, v_pool, page_table, lengths,
-                            window: Optional[int] = None) -> jax.Array:
+                            window: Optional[int] = None,
+                            scale: Optional[float] = None) -> jax.Array:
     """One-shot prompt attention over paged KV — masked-einsum oracle.
 
     The S prompt tokens of ONE sequence are presented as S independent
@@ -92,7 +100,7 @@ def paged_prefill_reference(q, k_pool, v_pool, page_table, lengths,
     S = q.shape[0]
     table = jnp.broadcast_to(page_table[None, :], (S, page_table.shape[0]))
     return paged_attention_reference(q, k_pool, v_pool, table, lengths,
-                                     window=window)
+                                     window=window, scale=scale)
 
 
 def paged_mla_decode_reference(q, pool, page_table, lengths, scale: float,
@@ -213,3 +221,23 @@ def ssd_reference(x, dt, A, Bm, Cm) -> jax.Array:
     xdt = x.astype(F32) * dt[..., None]
     y = jnp.einsum("bij,bijh,bjhp->bihp", scores, L, xdt)
     return y
+
+
+def ssm_decode_reference(pool, layer, slots, x, dt, A, D, Bm, Cm):
+    """Mamba-2 decode step over a state pool — jnp oracle of
+    ``kernels/ssm_decode.py``.  pool (Lm, slots, N, H*P) f32; x (R, H*P);
+    dt (R, H) f32; A, D (H,); Bm, Cm (R, G, N).  Returns (y (R, H*P) f32,
+    the pool with layer ``layer``'s rows ``slots`` advanced one token)."""
+    _, _, N, HP = pool.shape
+    R, H = dt.shape
+    P = HP // H
+    rep = H // Bm.shape[1]
+    Bh = jnp.repeat(Bm.astype(F32), rep, axis=1)             # (R, H, N)
+    Ch = jnp.repeat(Cm.astype(F32), rep, axis=1)
+    xf = x.astype(F32).reshape(R, H, P)
+    state = pool[layer, slots].reshape(R, N, H, P)
+    new = (jnp.exp(dt * A)[:, None, :, None] * state
+           + jnp.einsum("rhn,rhp->rnhp", Bh, xf * dt[..., None]))
+    y = jnp.einsum("rhn,rnhp->rhp", Ch, new) + xf * D[None, :, None]
+    pool = pool.at[layer, slots].set(new.reshape(R, N, HP))
+    return y.reshape(R, HP), pool

@@ -54,7 +54,32 @@ class ModelConfig:
     ssm_state: int = 0
     ssm_expand: int = 2
     ssm_head_dim: int = 64
+    ssm_groups: int = 1                   # Mamba-2 B/C groups (n_groups)
+    ssm_conv: int = 4                     # causal conv width over x, B, C
+    ssm_conv_bias: bool = True
+    ssm_chunk: int = 256                  # SSD chunk of the parallel form
     attn_every: int = 0                   # hybrid: shared attn every k layers
+    # A decoder whose layers differ by mixer (granite-4.0-H): one entry per
+    # layer, "attention" or "mamba"; every layer keeps its FFN (the MoE of
+    # a "moe" family).  None: every layer is attention.
+    layer_types: Optional[Tuple[str, ...]] = None
+    # Experts whose weights this chip holds (the first n_local_experts of
+    # n_experts, the chip's expert-parallel share; 0: all).  The router
+    # keeps all n_experts outputs; picks of experts held elsewhere add
+    # nothing here.
+    n_local_experts: int = 0
+    # Granite's multipliers and positions: the embedding is scaled by
+    # embedding_multiplier, each sublayer's output by residual_multiplier
+    # before the residual add, the logits divided by logits_scaling;
+    # attention_multiplier replaces 1/sqrt(head_dim) as the softmax scale;
+    # use_rope False is NoPE attention.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: Optional[float] = None
+    use_rope: bool = True
+    tie_embeddings: bool = False          # the head is the embedding's
+    norm_eps: float = 1e-6                # RMSNorm eps (the hybrid path)
     # xLSTM
     slstm_every: int = 0                  # one sLSTM per k layers
     # enc-dec
@@ -65,6 +90,10 @@ class ModelConfig:
     dtype: object = jnp.bfloat16
     # training
     remat: bool = True
+
+    def __post_init__(self):
+        if self.layer_types is not None:      # a JSON list, say
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
 
     @property
     def resolved_head_dim(self) -> int:
@@ -77,6 +106,20 @@ class ModelConfig:
     @property
     def mla(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def mixers(self) -> Tuple[str, ...]:
+        """Each layer's mixer, "attention" or "mamba"."""
+        return self.layer_types or ("attention",) * self.n_layers
+
+    @property
+    def mixed(self) -> bool:
+        """A stack of Mamba-2 and attention layers (``layer_types``)."""
+        return "mamba" in self.mixers
+
+    @property
+    def held_experts(self) -> int:
+        return self.n_local_experts or self.n_experts
 
     @property
     def moe_layers(self) -> int:
@@ -99,6 +142,18 @@ class ModelConfig:
                     == self.qk_nope_head_dim + self.qk_rope_head_dim), \
                 "MLA head_dim is qk_nope_head_dim + qk_rope_head_dim"
             assert self.qk_rope_head_dim % 2 == 0 and self.v_head_dim > 0
+        if self.layer_types is not None:
+            assert len(self.layer_types) == self.n_layers, \
+                "layer_types names every layer"
+            assert set(self.layer_types) <= {"attention", "mamba"}
+            assert not self.first_dense_layers and not self.mla
+        if self.mixed:
+            assert self.family == "moe" and self.ssm_state > 0
+            assert self.d_inner % self.ssm_head_dim == 0
+            assert (self.d_inner // self.ssm_head_dim) % self.ssm_groups == 0
+        if self.n_local_experts:
+            assert 0 < self.n_local_experts <= self.n_experts
+            assert self.moe_parallelism == "tp"
         if self.family == "hybrid":
             assert self.ssm_state > 0 and self.attn_every > 0
         if self.family == "encdec":

@@ -67,6 +67,8 @@ class AttnConfig:
     rope_theta: float = 10000.0
     window: Optional[int] = None      # sliding-window size (None = full)
     causal: bool = True
+    use_rope: bool = True             # False: NoPE
+    scale: Optional[float] = None     # softmax scale (None: 1/sqrt(dh))
 
 
 def attention_defs(cfg: AttnConfig):
@@ -157,10 +159,11 @@ def attention(p, x, cfg: AttnConfig, positions, kv_x=None,
               kv_positions=None, q_chunk: int = 1024):
     """Full attention for training/prefill (self or cross)."""
     cross = kv_x is not None
-    q, k, v = _qkv(p, x, cfg, positions, kv_x=kv_x, use_rope=not cross)
+    q, k, v = _qkv(p, x, cfg, positions, kv_x=kv_x,
+                   use_rope=cfg.use_rope and not cross)
     k_pos = kv_positions if kv_positions is not None else positions
     mask = _mask(positions[0], k_pos[0], cfg, cross)  # same for all batch rows
-    out = _attend(q, k, v, mask, cfg, q_chunk=q_chunk)
+    out = _attend(q, k, v, mask, cfg, q_chunk=q_chunk, scale=cfg.scale)
     out = constrain(out, ("batch", "seq", "heads", None))
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return constrain(y, ("batch", "seq", "embed"))
@@ -178,8 +181,9 @@ def attention_decode(p, x, cache_k, cache_v, pos, cfg: AttnConfig):
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k1 = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v1 = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
-    q = rope(q, positions, cfg.rope_theta)
-    k1 = rope(k1, positions, cfg.rope_theta)
+    if cfg.use_rope:
+        q = rope(q, positions, cfg.rope_theta)
+        k1 = rope(k1, positions, cfg.rope_theta)
     cache_k = jax.lax.dynamic_update_slice(cache_k, k1.astype(cache_k.dtype),
                                            (0, pos, 0, 0))
     cache_v = jax.lax.dynamic_update_slice(cache_v, v1.astype(cache_v.dtype),
@@ -198,7 +202,7 @@ def attention_decode(p, x, cache_k, cache_v, pos, cfg: AttnConfig):
                    ("batch", "kv_heads", None, "qdh"))
     s = jnp.einsum("bkgd,bskd->bkgs", qg.astype(F32),
                    cache_k.astype(F32))
-    s *= 1.0 / np.sqrt(dh)
+    s *= 1.0 / np.sqrt(dh) if cfg.scale is None else cfg.scale
     k_pos = jnp.arange(S_max)
     allowed = k_pos <= pos
     if cfg.window is not None:

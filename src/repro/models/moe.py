@@ -61,6 +61,14 @@ class MoEConfig:
     parallelism: str = "tp"          # "tp" | "ep"
     ep_axis_size: int = 16           # ep pad target; validated vs the mesh
     norm_topk_prob: bool = True      # renormalize the top-k gates to sum 1
+    # Experts whose weights this layer holds: the first n_local of them
+    # (an expert-parallel share on one chip; 0 = all).  Routing still runs
+    # over every expert; picks of the others add nothing here.
+    n_local: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_local or self.padded_experts
 
     @property
     def padded_experts(self) -> int:
@@ -87,16 +95,16 @@ class MoEConfig:
 
 
 def moe_defs(cfg: MoEConfig):
-    E = cfg.padded_experts
+    E, held = cfg.padded_experts, cfg.held
     expert_axis = "experts" if cfg.parallelism == "ep" else None
     mlp_axis = None if cfg.parallelism == "ep" else "mlp"
     return {
         "router": ArrayDef((cfg.d_model, E), ("embed", None), dtype=F32),
-        "w_gate": ArrayDef((E, cfg.d_model, cfg.d_ff),
+        "w_gate": ArrayDef((held, cfg.d_model, cfg.d_ff),
                            (expert_axis, "embed", mlp_axis)),
-        "w_up": ArrayDef((E, cfg.d_model, cfg.d_ff),
+        "w_up": ArrayDef((held, cfg.d_model, cfg.d_ff),
                          (expert_axis, "embed", mlp_axis)),
-        "w_down": ArrayDef((E, cfg.d_ff, cfg.d_model),
+        "w_down": ArrayDef((held, cfg.d_ff, cfg.d_model),
                            (expert_axis, mlp_axis, "embed")),
     }
 
@@ -178,6 +186,10 @@ def apply_dropless_flat(gates, experts, x, w_gate, w_up, w_down,
     the result is bitwise-identical to the dense layout whenever the
     referenced rows hold the same bytes.  ``expert_slots=None`` preserves
     the classic dense layout (row i == expert i) unchanged.
+
+    A layer that holds only its first ``cfg.n_local`` experts gives the
+    grouped GEMM their segments alone: picks of the other experts sort
+    last, past every held segment, and their rows and gates are zeroed.
     """
     B, S, d = x.shape
     E = cfg.padded_experts
@@ -187,9 +199,16 @@ def apply_dropless_flat(gates, experts, x, w_gate, w_up, w_down,
     order, tok_idx = _sort_picks_by_expert(flat_e, k)
     xs = jnp.take(x.reshape(B * S, d), tok_idx, axis=0)         # (B*Sk, d)
     group_sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
+    held = cfg.held
+    if held < E:
+        group_sizes = group_sizes[:held]
     slots = None if expert_slots is None else expert_slots.astype(jnp.int32)
     ys = ops.moe_grouped_ffn(xs, w_gate, w_up, w_down, group_sizes, slots)
     gs = gates.reshape(B * Sk)[order]                           # f32
+    if held < E:
+        mine = flat_e[order] < held
+        ys = jnp.where(mine[:, None], ys, 0)
+        gs = jnp.where(mine, gs, 0)
     y = jnp.zeros((B * S, d), F32).at[tok_idx].add(
         ys.astype(F32) * gs[:, None])
     return constrain(y.astype(x.dtype).reshape(B, S, d),
@@ -228,7 +247,7 @@ def _moe_dropless(p, x, cfg: MoEConfig, per_row: Optional[bool] = None):
     if per_row is None:
         per_row = _data_sharded()
 
-    if not per_row:
+    if not per_row or cfg.held < E:
         return apply_dropless_flat(gates, experts, x, p["w_gate"],
                                    p["w_up"], p["w_down"], cfg)
 
@@ -484,6 +503,8 @@ def moe(p, x, cfg: MoEConfig, dispatch: Optional[str] = None):
     experts receive no rows), so the two agree exactly.
     """
     mode = dispatch if dispatch is not None else cfg.dispatch
+    assert mode == "dropless" or cfg.held == cfg.padded_experts, \
+        "a layer holding a share of the experts dispatches dropless"
     if mode == "dropless":
         mesh = _ep_mesh(cfg)
         if mesh is not None:

@@ -81,6 +81,8 @@ class Model:
             head_dim=cfg.resolved_head_dim,
             rope_theta=cfg.rope_theta,
             window=cfg.window,
+            use_rope=cfg.use_rope,
+            scale=cfg.attention_multiplier,
         )
         self.mla_cfg = MLAConfig(
             d_model=cfg.d_model, n_heads=cfg.n_heads,
@@ -95,11 +97,15 @@ class Model:
                 parallelism=cfg.moe_parallelism,
                 ep_axis_size=cfg.moe_ep_axis_size,
                 norm_topk_prob=cfg.norm_topk_prob,
+                n_local=cfg.n_local_experts,
             )
-        if cfg.family == "hybrid":
+        if cfg.family == "hybrid" or cfg.mixed:
             self.ssm_cfg = SSMConfig(
                 d_model=cfg.d_model, d_inner=cfg.d_inner,
                 head_dim=cfg.ssm_head_dim, state_dim=cfg.ssm_state,
+                n_groups=cfg.ssm_groups, conv_width=cfg.ssm_conv,
+                conv_bias=cfg.ssm_conv_bias, chunk=cfg.ssm_chunk,
+                eps=cfg.norm_eps,
             )
         if cfg.family == "xlstm":
             self.xl_cfg = XLSTMConfig(d_model=cfg.d_model, n_heads=cfg.n_heads)
@@ -140,12 +146,17 @@ class Model:
 
     def param_defs(self) -> PyTree:
         cfg = self.cfg
-        out: Dict[str, PyTree] = {
-            "embed": embed_defs(cfg.vocab, cfg.d_model),
-            "final_ln": rmsnorm_defs(cfg.d_model),
-            "head": lm_head_defs(cfg.d_model, cfg.vocab),
-        }
-        if cfg.family in ("dense", "moe", "vlm"):
+        out: Dict[str, PyTree] = {"final_ln": rmsnorm_defs(cfg.d_model)}
+        if cfg.tie_embeddings:
+            # One (vocab, d) table, the embedding and (transposed) the
+            # output head, under its own name (``embed_table``).
+            out["tied_embed"] = embed_defs(cfg.vocab, cfg.d_model)
+        else:
+            out["embed"] = embed_defs(cfg.vocab, cfg.d_model)
+            out["head"] = lm_head_defs(cfg.d_model, cfg.vocab)
+        if cfg.mixed:
+            out["layers"] = self._mixed_layer_defs()
+        elif cfg.family in ("dense", "moe", "vlm"):
             nd = cfg.first_dense_layers
             if nd:
                 out["dense_layers"] = stack(self._lead_layer_defs(), nd)
@@ -186,6 +197,23 @@ class Model:
                  "mlp": mlp_defs(cfg.d_model, cfg.d_ff)}, cfg.n_layers)
         else:
             raise ValueError(f"unknown family {cfg.family}")
+        return out
+
+    def _mixed_layer_defs(self):
+        """A stack whose layers differ by mixer: per-layer norms, experts
+        and shared expert stacked over every layer, attention and Mamba-2
+        weights each over the layers of their kind, in depth order."""
+        cfg = self.cfg
+        L, kinds = cfg.n_layers, cfg.mixers
+        out = {"ln1": stack(rmsnorm_defs(cfg.d_model), L),
+               "ln2": stack(rmsnorm_defs(cfg.d_model), L),
+               "moe": stack(moe_defs(self.moe_cfg), L),
+               "attn": stack(attention_defs(self.attn_cfg),
+                             kinds.count("attention")),
+               "mamba": stack(ssm_defs(self.ssm_cfg), kinds.count("mamba"))}
+        if cfg.n_shared_experts:
+            out["shared"] = stack(
+                mlp_defs(cfg.d_model, cfg.n_shared_experts * cfg.d_ff), L)
         return out
 
     def _xlstm_counts(self) -> Tuple[int, int]:
@@ -239,7 +267,22 @@ class Model:
         return x, jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
                                lead, rest)
 
+    def _run_mixed_stack(self, params, x, positions):
+        """Mamba-2 and attention layers in depth order, each followed by
+        its FFN (``_mixed_layers``)."""
+        lay = params["layers"]
+
+        def mixer(kind, j, h):
+            if kind == "attention":
+                return attention(jax.tree.map(lambda a: a[j], lay["attn"]),
+                                 h, self.attn_cfg, positions)
+            return ssm_forward(jax.tree.map(lambda a: a[j], lay["mamba"]),
+                               h, self.ssm_cfg)[0]
+        return self._mixed_layers(params, x, mixer)
+
     def _run_dense_stack(self, params, x, positions):
+        if self.cfg.mixed:
+            return self._run_mixed_stack(params, x, positions)
         body = self._maybe_remat(
             lambda x, xs: (self._dense_body(x, xs[0], positions), None))
         x, _ = self.scan_stacks(body, x, params)
@@ -257,7 +300,7 @@ class Model:
 
         def ssm_body(x, lp):
             return x + ssm_forward(lp["ssm"], rmsnorm(lp["ln"], x),
-                                   self.ssm_cfg), None
+                                   self.ssm_cfg)[0], None
 
         body = self._maybe_remat(ssm_body)
         lp_all = params["ssm_layers"]
@@ -329,7 +372,7 @@ class Model:
             B, S = tokens.shape
             positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32),
                                          (B, S))
-            x = embed(params["embed"], tokens)
+            x = self.embed_tokens(params, tokens)
             x = self._run_dense_stack(params, x, positions)
         elif cfg.family == "vlm":
             patches, tokens = batch["patches"], batch["tokens"]
@@ -373,9 +416,25 @@ class Model:
             raise ValueError(cfg.family)
         return x, positions
 
+    def embed_table(self, params):
+        """The embedding's {"tok": (vocab, d)}, tied to the head or not."""
+        return params["tied_embed" if self.cfg.tie_embeddings else "embed"]
+
+    def embed_tokens(self, params, tokens):
+        """Token embeddings times the embedding multiplier (Granite's)."""
+        x = embed(self.embed_table(params), tokens)
+        m = self.cfg.embedding_multiplier
+        return x if m == 1.0 else (x * m).astype(x.dtype)
+
+    def head_weights(self, params):
+        """(d, vocab): the output head, or the tied embedding's transpose."""
+        if self.cfg.tie_embeddings:
+            return {"w": params["tied_embed"]["tok"].T}
+        return params["head"]
+
     def loss(self, params, batch) -> jax.Array:
         x, _ = self._trunk(params, batch)
-        x = rmsnorm(params["final_ln"], x)
+        x = rmsnorm(params["final_ln"], x, self.cfg.norm_eps)
         labels = batch["labels"]
         if self.cfg.family == "vlm":
             # image prefix carries no LM loss
@@ -383,7 +442,10 @@ class Model:
             pad = jnp.full(
                 (labels.shape[0], P), -1, labels.dtype)
             labels = jnp.concatenate([pad, labels], axis=1)
-        return chunked_lm_loss(params["head"], x, labels)
+        head = self.head_weights(params)
+        if self.cfg.logits_scaling != 1.0:
+            head = {"w": head["w"] / self.cfg.logits_scaling}
+        return chunked_lm_loss(head, x, labels)
 
     # ============================================================ serving
     def cache_defs(self, batch: int, seq: int) -> PyTree:
@@ -400,6 +462,20 @@ class Model:
             return {"k": ArrayDef(shape, kv_axes, cfg.dtype, init="zeros"),
                     "v": ArrayDef(shape, kv_axes, cfg.dtype, init="zeros")}
 
+        if cfg.mixed:
+            sc, kinds = self.ssm_cfg, cfg.mixers
+            n_m = kinds.count("mamba")
+            return {
+                "kv": kv(kinds.count("attention")),
+                "conv": ArrayDef(
+                    (n_m, batch, sc.conv_width - 1, sc.conv_dim),
+                    ("layers", "batch", None, None), cfg.dtype,
+                    init="zeros"),
+                "ssm": ArrayDef(
+                    (n_m, batch, sc.state_dim, sc.n_heads, sc.head_dim),
+                    ("layers", "batch", None, "ssm_heads", None), F32,
+                    init="zeros"),
+            }
         if cfg.mla:
             return {"kv": {"lat": ArrayDef(
                 (cfg.n_layers, batch, seq, self.mla_cfg.latent_dim),
@@ -414,12 +490,12 @@ class Model:
                 "kv": kv(n_attn),
                 "conv": ArrayDef(
                     (cfg.n_layers, batch, self.ssm_cfg.conv_width - 1,
-                     cfg.d_inner),
-                    ("layers", "batch", None, "d_inner"), cfg.dtype,
+                     self.ssm_cfg.conv_dim),
+                    ("layers", "batch", None, None), cfg.dtype,
                     init="zeros"),
                 "ssm": ArrayDef(
-                    (cfg.n_layers, batch, H, cfg.ssm_state, cfg.ssm_head_dim),
-                    ("layers", "batch", "ssm_heads", None, None), F32,
+                    (cfg.n_layers, batch, cfg.ssm_state, H, cfg.ssm_head_dim),
+                    ("layers", "batch", None, "ssm_heads", None), F32,
                     init="zeros"),
             }
         if cfg.family == "xlstm":
@@ -463,8 +539,10 @@ class Model:
         """tokens: (B,1) int32; pos: scalar int32 (same for all rows).
         Returns (logits (B, vocab), new cache)."""
         cfg = self.cfg
-        x = embed(params["embed"], tokens)
-        if cfg.mla:
+        x = self.embed_tokens(params, tokens)
+        if cfg.mixed:
+            x, cache = self._decode_mixed(params, cache, x, pos)
+        elif cfg.mla:
             def body(x, xs):
                 lp, cl = xs
                 y, cl = mla_attention_decode(
@@ -521,9 +599,91 @@ class Model:
             cache = {**cache, "kv": {"k": nk, "v": nv}}
         else:
             raise ValueError(cfg.family)
-        x = rmsnorm(params["final_ln"], x)
-        logits = lm_head(params["head"], x)[:, 0]
-        return logits, cache
+        return self._logits(params, x)[:, 0], cache
+
+    def _logits(self, params, x):
+        """The final norm and the head (Granite's logits scaling)."""
+        x = rmsnorm(params["final_ln"], x, self.cfg.norm_eps)
+        logits = lm_head(self.head_weights(params), x)
+        if self.cfg.logits_scaling != 1.0:
+            logits = logits / self.cfg.logits_scaling
+        return logits
+
+    def _mixed_layers(self, params, x, mixer):
+        """A Mamba-2/attention stack unrolled in depth order:
+        ``mixer(kind, j, h)`` is the mixer of the layer (``j``-th of its
+        kind) on its normed input; each sublayer's output is scaled by the
+        residual multiplier."""
+        cfg = self.cfg
+        lay, eps, rm = params["layers"], cfg.norm_eps, cfg.residual_multiplier
+        idx = {"attention": 0, "mamba": 0}
+        for l, kind in enumerate(cfg.mixers):
+            lp = jax.tree.map(lambda a: a[l], {
+                k: v for k, v in lay.items() if k not in ("attn", "mamba")})
+            j = idx[kind]
+            idx[kind] += 1
+            y = mixer(kind, j, rmsnorm(lp["ln1"], x, eps))
+            x = x + (y * rm).astype(x.dtype)
+            x = x + (self._ffn(lp, rmsnorm(lp["ln2"], x, eps)) * rm
+                     ).astype(x.dtype)
+        return x
+
+    def _decode_mixed(self, params, cache, x, pos):
+        lay = params["layers"]
+        new = {"k": [], "v": [], "conv": [], "ssm": []}
+
+        def mixer(kind, j, h):
+            if kind == "attention":
+                y, ck, cv = attention_decode(
+                    jax.tree.map(lambda a: a[j], lay["attn"]), h,
+                    cache["kv"]["k"][j], cache["kv"]["v"][j], pos,
+                    self.attn_cfg)
+                new["k"].append(ck)
+                new["v"].append(cv)
+                return y
+            y, cs, ss = ssm_decode(jax.tree.map(lambda a: a[j], lay["mamba"]),
+                                   h, cache["conv"][j], cache["ssm"][j],
+                                   self.ssm_cfg)
+            new["conv"].append(cs)
+            new["ssm"].append(ss)
+            return y
+
+        x = self._mixed_layers(params, x, mixer)
+        return x, {"kv": {"k": jnp.stack(new["k"]), "v": jnp.stack(new["v"])},
+                   "conv": jnp.stack(new["conv"]),
+                   "ssm": jnp.stack(new["ssm"])}
+
+    def _prefill_mixed(self, params, batch, cache):
+        cfg, lay = self.cfg, params["layers"]
+        tokens = batch["tokens"]
+        B, S = tokens.shape
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+        x = self.embed_tokens(params, tokens)
+        pad = cache["kv"]["k"].shape[2] - S
+        new = {"k": [], "v": [], "conv": [], "ssm": []}
+
+        def mixer(kind, j, h):
+            if kind == "attention":
+                p = jax.tree.map(lambda a: a[j], lay["attn"])
+                for name in ("k", "v"):
+                    t = jnp.einsum("bsd,dhk->bshk", h, p["w" + name])
+                    if name == "k" and cfg.use_rope:
+                        t = rope(t, positions, cfg.rope_theta)
+                    new[name].append(jnp.pad(
+                        t, ((0, 0), (0, pad), (0, 0), (0, 0))).astype(
+                            cache["kv"][name].dtype))
+                return attention(p, h, self.attn_cfg, positions)
+            y, cs, ss = ssm_forward(
+                jax.tree.map(lambda a: a[j], lay["mamba"]), h, self.ssm_cfg)
+            new["conv"].append(cs.astype(cache["conv"].dtype))
+            new["ssm"].append(ss)
+            return y
+
+        x = self._mixed_layers(params, x, mixer)
+        cache = {"kv": {"k": jnp.stack(new["k"]), "v": jnp.stack(new["v"])},
+                 "conv": jnp.stack(new["conv"]),
+                 "ssm": jnp.stack(new["ssm"])}
+        return self._logits(params, x[:, -1:])[:, 0], cache
 
     def _decode_hybrid(self, params, cache, x, pos):
         cfg = self.cfg
@@ -626,6 +786,8 @@ class Model:
         the prompt's K/V land in the cache (ring-ified for windowed archs).
         """
         cfg = self.cfg
+        if cfg.mixed:
+            return self._prefill_mixed(params, batch, cache)
         if cfg.family in ("dense", "moe", "vlm", "encdec"):
             return self._prefill_attn(params, batch, cache)
         if cfg.family == "hybrid":
@@ -644,8 +806,7 @@ class Model:
 
         def body(x, lp):
             out, conv_s, ssm_s = ssm_forward(
-                lp["ssm"], rmsnorm(lp["ln"], x), self.ssm_cfg,
-                return_state=True)
+                lp["ssm"], rmsnorm(lp["ln"], x), self.ssm_cfg)
             return x + out, (conv_s, ssm_s)
 
         body = self._maybe_remat(body)

@@ -1,8 +1,11 @@
 """Continuous-batching serving engine with guided KV-page tiering.
 
 The engine serves dense/MoE decoder models from a paged two-tier KV cache
-(serve/kvcache.py).  Each *request* is an allocation site; its pages are the
-chunks.  The request lifecycle is explicit:
+(serve/kvcache.py); a model whose layers mix Mamba-2 and attention
+(``ModelConfig.layer_types``) keeps K/V pages for its attention layers and
+a recurrent-state slot per request for its Mamba layers (DESIGN.md §17).
+Each *request* is an allocation site; its pages are the chunks.  The
+request lifecycle is explicit:
 
     waiting --admit--> [prefilling -->] active <--pause/resume--> paused
         ^                                 |                         |
@@ -106,10 +109,17 @@ from ..models.layers import (
     rope,
 )
 from ..models.moe import apply_dropless_flat, moe_decode, route_tokens
+from ..models.ssm import (
+    conv_window,
+    gated_out,
+    split_xbc,
+    ssm_forward,
+    ssm_project,
+)
 from ..models.transformer import Model
 from .eviction import make_eviction_policy
 from .expert_store import ExpertBackend, ExpertCacheMissError, ExpertStore
-from .kvcache import PageExport, PagedKVPool
+from .kvcache import PageExport, PagedKVPool, StatePool
 from .prefix_cache import PrefixBackend, PrefixCache
 from .sampling import DEFAULT_MAX_TOKENS, SamplingParams
 from .scheduler import make_scheduler_policy
@@ -356,6 +366,19 @@ class Engine:
             raise ValueError(
                 f"ServeConfig.prefill_chunk_tokens must be >= 0, got "
                 f"{cfg.prefill_chunk_tokens}")
+        if model.cfg.mixed:
+            # Mamba layers keep a recurrent state per request, which the
+            # tiered expert pipeline does not carry and a shared prefix's
+            # pages do not capture.
+            if cfg.expert_offchip:
+                raise ValueError(
+                    "ServeConfig.expert_offchip does not serve a model with "
+                    "Mamba layers yet: their experts stay resident")
+            if cfg.enable_prefix_cache:
+                raise ValueError(
+                    "ServeConfig.enable_prefix_cache needs state snapshots "
+                    "for a model with Mamba layers: a cached prefix's pages "
+                    "hold no recurrent state")
         if cfg.expert_offchip:
             if model.cfg.family != "moe":
                 raise ValueError(
@@ -383,6 +406,12 @@ class Engine:
         self.model = model
         self.cfg = cfg
         self.hw = hw
+        # The most tokens one prefill dispatch takes: a MoE dispatch's
+        # float32 combine of its routed expert outputs (tokens x top_k x
+        # d_model values) stays within 2**27 values (512 MiB).
+        routed = model.cfg.top_k * model.cfg.d_model
+        self._dispatch_cap = (1 << (((1 << 27) // routed).bit_length() - 1)
+                              if model.cfg.family == "moe" else 1 << 62)
         self.device = device if device is not None else jax.devices()[0]
         mc = model.cfg
         # Latent attention caches one latent per token and layer, read as
@@ -390,8 +419,23 @@ class Engine:
         kv_heads, head_dim, n_arrays = (
             (0, model.mla_cfg.latent_dim, 1) if mc.mla
             else (mc.kv_heads, model.attn_cfg.head_dim, 2))
+        kv_layers = mc.n_layers
+        self.states: Optional[StatePool] = None
+        if mc.mixed:
+            # Pages hold the attention layers' K/V alone, each token's
+            # heads as one flat row (the layout the kernel reads); every
+            # Mamba layer keeps a state per request instead, one slot for
+            # each row of a batch.
+            kv_heads, head_dim = 0, mc.kv_heads * model.attn_cfg.head_dim
+            kv_layers = mc.mixers.count("attention")
+            sc = model.ssm_cfg
+            self.states = StatePool(
+                mc.mixers.count("mamba"), cfg.max_batch,
+                conv=(sc.conv_width - 1) * sc.conv_dim,
+                state=(sc.state_dim, sc.d_inner), dtype=mc.dtype,
+                device=self.device)
         self.pool = PagedKVPool(
-            n_layers=mc.n_layers, page_size=cfg.page_size,
+            n_layers=kv_layers, page_size=cfg.page_size,
             kv_heads=kv_heads, head_dim=head_dim,
             hbm_pages=cfg.hbm_pages, host_pages=cfg.host_pages,
             dtype=mc.dtype, device=self.device, n_arrays=n_arrays)
@@ -502,6 +546,10 @@ class Engine:
         self.truncations = 0           # requests finished early for capacity
         self.saved_prefill_tokens = 0  # prompt tokens served from the cache
         self.host_syncs = 0            # blocking device->host reads (_read)
+        # Requests whose recurrent state a preemption dropped, and how
+        # many were admitted again (their state rebuilt by re-prefill).
+        self._dropped_state: set = set()
+        self.ssm_recomputes = 0
         # Per-finish_reason totals (monotonic — surviving pop_finished
         # drains), reported through stats() and serving_summary.
         self.finish_counts: Dict[str, int] = {
@@ -601,24 +649,30 @@ class Engine:
                 write_off=write_off, row_mask=row_mask, lane_mask=lane_mask,
                 rows=rows, unrows=unrows, attend=attend)
         acfg = self.model.attn_cfg
+        eps = self.model.cfg.norm_eps
         kp, vp = pools
-        h = rmsnorm(lp["ln1"], x)
+        h = rmsnorm(lp["ln1"], x, eps)
         q = jnp.einsum("bsd,dhk->bshk", h, lp["attn"]["wq"])
         k1 = jnp.einsum("bsd,dhk->bshk", h, lp["attn"]["wk"])
         v1 = jnp.einsum("bsd,dhk->bshk", h, lp["attn"]["wv"])
-        q = rope(q, positions, acfg.rope_theta)
-        k1 = rope(k1, positions, acfg.rope_theta)
-        m = row_mask[:, None, None]
+        if acfg.use_rope:
+            q = rope(q, positions, acfg.rope_theta)
+            k1 = rope(k1, positions, acfg.rope_theta)
+        # A flat pool (N, P, K*dh) takes each token's heads as one row.
+        kr, vr = rows(k1), rows(v1)
+        if kp.ndim == 3:
+            kr, vr = kr.reshape(kr.shape[0], -1), vr.reshape(vr.shape[0], -1)
+        m = row_mask[(slice(None),) + (None,) * (kp.ndim - 2)]
         kp = kp.at[write_slot, write_off].set(
-            jnp.where(m, rows(k1), 0).astype(kp.dtype))
+            jnp.where(m, kr, 0).astype(kp.dtype))
         vp = vp.at[write_slot, write_off].set(
-            jnp.where(m, rows(v1), 0).astype(vp.dtype))
+            jnp.where(m, vr, 0).astype(vp.dtype))
         o = attend(rows(q), kp, vp)                      # (R, H, dh)
         y = jnp.einsum("rhk,hkd->rd",
                        o.reshape(o.shape[0], acfg.n_heads, acfg.head_dim),
                        lp["attn"]["wo"])
-        x = x + jnp.where(lane_mask, unrows(y), 0)
-        h2 = rmsnorm(lp["ln2"], x)
+        x = x + self._residual(jnp.where(lane_mask, unrows(y), 0))
+        h2 = rmsnorm(lp["ln2"], x, eps)
         return x, h2, (kp, vp)
 
     def _mla_attn_half(self, lp, x, pools, *, positions, write_slot,
@@ -642,9 +696,10 @@ class Engine:
         from ..kernels.ops import paged_attention, paged_mla_decode
 
         if not self.model.cfg.mla:
-            window = self.model.attn_cfg.window
+            acfg = self.model.attn_cfg
             return lambda q, kp, vp: paged_attention(
-                q, kp, vp, page_table, lengths, window=window)
+                q, kp, vp, page_table, lengths, window=acfg.window,
+                scale=acfg.scale)
         mcfg = self.model.mla_cfg
 
         def attend(q_nope, q_pe, pool, p):
@@ -665,9 +720,10 @@ class Engine:
         from ..kernels.ops import flash_attention, paged_prefill
 
         if not self.model.cfg.mla:
-            window = self.model.attn_cfg.window
+            acfg = self.model.attn_cfg
             return lambda q, kp, vp: paged_prefill(
-                q, kp, vp, page_table, lengths, window=window)
+                q, kp, vp, page_table, lengths, window=acfg.window,
+                scale=acfg.scale)
         mcfg = self.model.mla_cfg
 
         def attend(q_nope, q_pe, pool, p):
@@ -686,9 +742,36 @@ class Engine:
             return jax.lax.dynamic_slice_in_dim(o, start, S)[..., :dv]
         return attend
 
-    def _ffn_half(self, lp, x, h2, lane_mask):
+    def _residual(self, y):
+        """A sublayer's output as the residual stream takes it: scaled by
+        the model's residual multiplier (Granite's), where it has one."""
+        rm = self.model.cfg.residual_multiplier
+        return y if rm == 1.0 else (y * rm).astype(y.dtype)
+
+    def _ffn_half(self, lp, x, h2, lane_mask, layer=None, experts=None):
         """FFN + residual, the second half of ``_layer_body``: the routed
-        experts (plus the shared ones), or a dense MLP."""
+        experts (plus the shared ones), or a dense MLP.  ``experts``: the
+        MoE weights of every layer, stacked, and ``layer`` this layer's
+        index into them — the grouped GEMM then reads this layer's
+        experts in place through its slot map (a slice of the stack would
+        be copied out for the kernel)."""
+        if experts is not None:
+            mcfg = self.model.moe_cfg
+            B, S, d = h2.shape
+            gates, picks = route_tokens(lp["moe"]["router"],
+                                        h2.reshape(B * S, d), mcfg)
+
+            def flat(w):
+                return w.reshape((-1,) + w.shape[2:])
+
+            d_out = apply_dropless_flat(
+                gates, picks, h2, flat(experts["w_gate"]),
+                flat(experts["w_up"]), flat(experts["w_down"]), mcfg,
+                expert_slots=layer * mcfg.held + jnp.arange(
+                    mcfg.padded_experts, dtype=jnp.int32))
+            if "shared" in lp:
+                d_out = d_out + mlp(lp["shared"], h2)
+            return x + self._residual(jnp.where(lane_mask, d_out, 0))
         if "moe" in lp:
             # Same dropless routing + grouped GEMM as model.prefill, so a
             # token's expert assignment never depends on how the stream is
@@ -698,7 +781,7 @@ class Engine:
                 d = d + mlp(lp["shared"], h2)
         else:
             d = mlp(lp["mlp"], h2)
-        return x + jnp.where(lane_mask, d, 0)
+        return x + self._residual(jnp.where(lane_mask, d, 0))
 
     # ===================================================== dispatch parts
     # Each part of a dispatch is defined once.  The resident programs run
@@ -733,7 +816,7 @@ class Engine:
                                                 positions[0]))
 
     def _embed(self, params, tokens):
-        return jnp.take(params["embed"]["tok"], tokens, axis=0)
+        return self.model.embed_tokens(params, tokens)
 
     def _prefill_prologue(self, params, tokens, n_real, start):
         """tokens: (S,) the padded suffix; n_real: () int32 live rows;
@@ -763,8 +846,16 @@ class Engine:
             """seeds/temperature/top_k/top_p: (B,) per-request sampling
             knobs; the sampler folds the next token's stream position
             ``lengths`` into the PRNG — the replay contract."""
-            x = rmsnorm(params["final_ln"], x)
-            logits = lm_head(params["head"], x)[:, 0]
+            mc = self.model.cfg
+            x = rmsnorm(params["final_ln"], x, mc.norm_eps)
+            if mc.tie_embeddings:
+                logits = jnp.einsum("bsd,vd->bsv", x,
+                                    params["tied_embed"]["tok"],
+                                    preferred_element_type=F32)[:, 0]
+            else:
+                logits = lm_head(params["head"], x)[:, 0]
+            if mc.logits_scaling != 1.0:
+                logits = logits / mc.logits_scaling
             logits = jnp.where(active[:, None], logits, 0.0)
             if with_sampler:
                 next_tokens = sample_tokens(logits, seeds, lengths,
@@ -774,41 +865,135 @@ class Engine:
             return logits, next_tokens
         return tail
 
-    def _scan_layers(self, params, pools, x, frame, *frame_args):
+    def _scan_layers(self, params, pools, x, frame, *frame_args,
+                     mamba=None):
         """Every layer in one scan; ``frame(*frame_args)`` builds the layer
-        keywords inside the scan body."""
+        keywords inside the scan body.  A Mamba-2/attention stack runs
+        ``_mixed_layers`` instead, its Mamba layers through ``mamba``."""
+        if mamba is not None:
+            return self._mixed_layers(params, pools, x, frame(*frame_args),
+                                      mamba)
+
         def body(carry, xs):
             lp, *pl = xs             # pl: each pool's (N,P,K,dh)
             return self._layer_body(lp, carry, tuple(pl),
                                     **frame(*frame_args))
         return self.model.scan_stacks(body, x, params, pools)
 
+    def _mixed_layers(self, params, pools, x, frame, mamba):
+        """Every layer of a Mamba-2/attention stack, unrolled in depth
+        order (the two kinds' weights are separate stacks), each mixer
+        followed by its FFN half.  ``pools`` is the K/V pools of the
+        attention layers, then the recurrent state pool's (conv, ssm);
+        ``mamba(lp, mp, x, states, j)`` is the mixer half of Mamba layer
+        ``j`` (``_mamba_decode_half``, ``_mamba_prefill_half``)."""
+        kv, states = pools[:-2], pools[-2:]
+        lay = params["layers"]
+        at = {"attention": 0, "mamba": 0}
+        for l, kind in enumerate(self.model.cfg.mixers):
+            j = at[kind]
+            at[kind] += 1
+            lp = {k: jax.tree.map(lambda a: a[l], lay[k])
+                  for k in ("ln1", "ln2", "shared") if k in lay}
+            lp["moe"] = {"router": lay["moe"]["router"][l]}
+            if kind == "attention":
+                lp["attn"] = jax.tree.map(lambda a: a[j], lay["attn"])
+                x, h2, pl = self._attn_half(lp, x, tuple(p[j] for p in kv),
+                                            **frame)
+                kv = tuple(p.at[j].set(q) for p, q in zip(kv, pl))
+            else:
+                x, h2, states = mamba(
+                    lp, jax.tree.map(lambda a: a[j], lay["mamba"]), x,
+                    states, j)
+            x = self._ffn_half(lp, x, h2, frame["lane_mask"], layer=l,
+                               experts=lay["moe"])
+        return x, kv + states
+
+    def _mamba_decode_half(self, slots, lane_mask):
+        """The mixer half of a Mamba-2 layer over a decode batch, x
+        (B,1,d): each row's conv and SSM state at ``slots`` (B,) — an
+        inactive row's at the scratch slot — read, advanced one token and
+        written back in place (``ops.ssm_decode``)."""
+        from ..kernels.ops import ssm_decode
+
+        sc, eps = self.model.ssm_cfg, self.model.cfg.norm_eps
+
+        def half(lp, mp, x, states, j):
+            conv, ssm = states
+            z, xbc, dt = ssm_project(mp, rmsnorm(lp["ln1"], x, eps)[:, 0],
+                                     sc)
+            B = xbc.shape[0]
+            window = jnp.concatenate(
+                [conv[j, slots].reshape(B, -1, sc.conv_dim),
+                 xbc[:, None].astype(conv.dtype)], axis=1)
+            conv = conv.at[j, slots].set(window[:, 1:].reshape(B, -1))
+            xs, Bm, Cm = split_xbc(conv_window(mp, window, x.dtype), sc)
+            y, ssm = ssm_decode(ssm, jnp.int32(j), slots,
+                                xs.reshape(xs.shape[0], -1), dt,
+                                -jnp.exp(mp["a_log"]), mp["D"], Bm, Cm)
+            out = gated_out(mp, y, z, sc, x.dtype)[:, None]
+            x = x + self._residual(jnp.where(lane_mask, out, 0))
+            return x, rmsnorm(lp["ln2"], x, eps), (conv, ssm)
+        return half
+
+    def _mamba_prefill_half(self, slot, n_real, lane_mask):
+        """The mixer half of a Mamba-2 layer over one sequence's S rows, x
+        (1,S,d): the chunked scan from the request's states at ``slot``,
+        which it leaves as the last of the ``n_real`` live rows left them
+        (padding rows leave them untouched)."""
+        from ..kernels.ops import ssm_state_read, ssm_state_write
+
+        sc, eps = self.model.ssm_cfg, self.model.cfg.norm_eps
+
+        def half(lp, mp, x, states, j):
+            conv, ssm = states
+            N, HP = ssm.shape[2:]
+            out, nc, ns = ssm_forward(
+                mp, rmsnorm(lp["ln1"], x, eps), sc,
+                conv_state=conv[j, slot].reshape(1, -1, sc.conv_dim),
+                ssm_state=ssm_state_read(ssm, jnp.int32(j), slot).reshape(
+                    1, N, sc.n_heads, sc.head_dim),
+                n_real=n_real)
+            conv = conv.at[j, slot].set(nc.reshape(-1).astype(conv.dtype))
+            ssm = ssm_state_write(ssm, jnp.int32(j), slot,
+                                  ns.reshape(N, HP))
+            x = x + self._residual(jnp.where(lane_mask, out, 0))
+            return x, rmsnorm(lp["ln2"], x, eps), (conv, ssm)
+        return half
+
     # ================================================== resident programs
     def _decode_scan(self, with_sampler, params, pools, tokens, page_table,
                      lengths, write_slot, write_off, active, seeds,
-                     temperature, top_k, top_p):
+                     temperature, top_k, top_p, state_slots=None):
         """One decode step of the batch in one scan.  tokens: (B,1); the
-        rest as ``_decode_frame`` and ``_tail`` take them."""
+        rest as ``_decode_frame`` and ``_tail`` take them; a model with
+        Mamba layers also takes each row's recurrent-state slot."""
         x, pools = self._scan_layers(
             params, pools, self._embed(params, tokens), self._decode_frame,
-            page_table, lengths, write_slot, write_off, active)
+            page_table, lengths, write_slot, write_off, active,
+            mamba=None if state_slots is None else self._mamba_decode_half(
+                state_slots, active[:, None, None]))
         return (*self._tail(with_sampler)(
             params, x, lengths, active, seeds, temperature, top_k, top_p),
             pools)
 
     def _prefill_scan(self, params, pools, tokens, page_table, slots, offs,
-                      n_real, start):
+                      n_real, start, state_slot=None):
         """One-shot prompt ingestion: a single jitted call writes S tokens'
         K/V into their page-table slots and attends with per-token causal
         lengths via ``ops.paged_prefill`` — the same layer body decode
         runs, so the result is bitwise-equal to chunked ingestion.  Latent
         attention prefills in the plain form and decodes absorbed: equal
-        in exact arithmetic, not bitwise (``_prefill_attend``)."""
+        in exact arithmetic, not bitwise (``_prefill_attend``).  A model
+        with Mamba layers continues the request's recurrent state at
+        ``state_slot``."""
         x, positions, lengths, valid = self._prefill_prologue(
             params, tokens, n_real, start)
         return self._scan_layers(
             params, pools, x, self._prefill_frame, page_table, slots, offs,
-            positions, lengths, valid)[1]
+            positions, lengths, valid,
+            mamba=None if state_slot is None else self._mamba_prefill_half(
+                state_slot, n_real, valid[None, :, None]))[1]
 
     # ==================================================== tiered expert path
     def _build_tiered_closures(self):
@@ -1044,6 +1229,18 @@ class Engine:
         # profile.
         self.expert_store.prefetch(0, self.step_count)
 
+    def _pools(self) -> tuple:
+        """What a resident program takes donated: the K/V pools, then the
+        recurrent state pool's arrays (a model with Mamba layers)."""
+        return self.pool.hbm + (self.states.arrays if self.states else ())
+
+    def _rebind(self, pools) -> None:
+        """Re-bind the pools to what a program returned in their place."""
+        n = len(self.pool.hbm)
+        self.pool.hbm = tuple(pools[:n])
+        if self.states is not None:
+            self.states.arrays = tuple(pools[n:])
+
     def _read(self, what: str, value, **args) -> np.ndarray:
         """A blocking device->host read on the step path: the host waits
         here for the device work ``value`` depends on.  Counted in
@@ -1162,6 +1359,10 @@ class Engine:
             self.wait_queue.append(rid)
             self._admit_waiting()
             return req
+        if self.states is not None:
+            raise ValueError(
+                f"cannot import request {rid} warm: its recurrent state does "
+                f"not travel with its pages; import it cold (kv=None)")
         context = req.context
         n_ingest = len(context) - 1
         self.requests[rid] = req
@@ -1284,6 +1485,13 @@ class Engine:
                 self.wait_queue.remove(req.request_id)
                 self._finish(req, reason="truncated")
                 continue
+            # A model with Mamba layers needs a free recurrent-state slot:
+            # a paused request yields its slot (and pages) before the head
+            # waits.
+            if self.states is not None and not self.states.free:
+                if not self._preempt_one():
+                    return
+                continue
             # Admit with one page of growth slack (capped at the request's
             # real lifetime need), so an admitted request can always decode
             # at least a page's worth before capacity pressure returns.
@@ -1308,7 +1516,15 @@ class Engine:
         ``active`` via eager whole-suffix prefill (one ``_prefill_chunk``
         over the whole uncovered suffix), or — with chunked-prefill
         interleaving on — into ``prefilling``, where ``_advance_prefills``
-        ingests budgeted chunks each step."""
+        ingests budgeted chunks each step.  A model with Mamba layers
+        takes its recurrent-state slot here."""
+        if self.states is not None:
+            rid = req.request_id
+            with span("repro.engine.state", request_id=rid, op="alloc"):
+                self.states.alloc(rid)
+            if rid in self._dropped_state:
+                self._dropped_state.discard(rid)
+                self.ssm_recomputes += 1
         budget = self.scheduler.step_budget(self)
         if self.cfg.prefill == "one_shot" and budget.prefill_tokens > 0:
             self._begin_prefill(req)
@@ -1391,7 +1607,20 @@ class Engine:
         dispatch at absolute start ``req.pos`` — eager prefill is one such
         chunk over the whole suffix, so every chunking of a prompt produces
         bitwise-identical K/V (rows attend by absolute length over the
-        request's full page table)."""
+        request's full page table).  A chunk longer than the dispatch cap
+        goes as consecutive dispatches of the cap's bucket each."""
+        cap = self._dispatch_cap
+        S = cap if n > cap else None
+        while n > 0:
+            m = min(n, cap)
+            self._prefill_dispatch(req, m, S)
+            n -= m
+
+    def _prefill_dispatch(self, req: Request, n: int,
+                          S: Optional[int] = None) -> None:
+        """One dispatch of ``_prefill_chunk``: ``n`` tokens padded to the
+        bucket ``S`` (default the power of two at or above ``n``, at least
+        a page)."""
         context = req.context
         P = self.cfg.page_size
         MP = self.cfg.max_pages_per_seq
@@ -1407,7 +1636,7 @@ class Engine:
         by_index = {p.index_in_seq: p for p in my_pages}
         # Pad the token axis to a power-of-two bucket (>= one page) so jit
         # compiles per bucket, not per chunk length.
-        S = max(P, 1 << (n - 1).bit_length())
+        S = S or max(P, 1 << (n - 1).bit_length())
         tokens = np.zeros((S,), np.int32)
         tokens[:n] = context[start:start + n]
         slots = np.full((S,), self.scratch_slot, np.int32)
@@ -1428,9 +1657,10 @@ class Engine:
         if self.expert_store is not None:
             self._tiered_prefill(*args, mask=np.arange(S) < n)
         else:
+            if self.states is not None:
+                args += (jnp.int32(self.states.slot_of[rid]),)
             with span("repro.dispatch.step"):
-                self.pool.hbm = self._prefill(self.params, self.pool.hbm,
-                                              *args)
+                self._rebind(self._prefill(self.params, self._pools(), *args))
         req.pos = start + n
         for idx in range(start // P, (start + n - 1) // P + 1):
             if not by_index[idx].shared:      # the chunk's write set
@@ -1447,19 +1677,27 @@ class Engine:
         if not victims:
             return False
         victim = self.scheduler.preempt_paused(victims, self)
-        self._release_pages(victim.request_id)
+        self._release_pages(victim.request_id, preempted=True)
         victim.pos = 0
         victim.state = "preempted"
         self.preemptions += 1
         return True
 
-    def _release_pages(self, request_id: int):
-        """Drop every page reference the request holds.  Shared prefix
-        pages survive on the cache's reference; only pages that actually
-        died leave the eviction policy's recommendation view."""
+    def _release_pages(self, request_id: int, preempted: bool = False):
+        """Drop every page reference the request holds, and its
+        recurrent-state slot.  Shared prefix pages survive on the cache's
+        reference; only pages that actually died leave the eviction
+        policy's recommendation view.  ``preempted``: the request will be
+        prefilled again from its context (preemption by recompute)."""
         freed = self.pool.release_request(request_id)
         if self.kv_backend is not None:
             self.kv_backend.forget_pages(freed)
+        if self.states is not None and request_id in self.states.slot_of:
+            with span("repro.engine.state", request_id=request_id,
+                      op="free"):
+                self.states.release(request_id)
+            if preempted:
+                self._dropped_state.add(request_id)
 
     def _reclaim_logical_pages(self):
         """Nothing schedulable while active requests exist — logical pages
@@ -1482,7 +1720,7 @@ class Engine:
             self._finish(cands[0], reason="truncated")
             return
         victim = self.scheduler.preempt_active(holders, self)
-        self._release_pages(victim.request_id)
+        self._release_pages(victim.request_id, preempted=True)
         victim.pos = 0
         victim.state = "waiting"
         victim.queued_step = self.step_count
@@ -1791,10 +2029,16 @@ class Engine:
             logits, toks = self._tiered_decode(
                 *args, use_sampler=not greedy, mask=active)
         else:
+            if self.states is not None:
+                slots = np.full((B,), self.states.scratch, np.int32)
+                for i, (req, _) in enumerate(pairs):
+                    slots[i] = self.states.slot_of[req.request_id]
+                args += (jnp.asarray(slots),)
             decode = self._decode_greedy if greedy else self._decode_sampled
             with span("repro.dispatch.step"):
-                logits, toks, self.pool.hbm = decode(
-                    self.params, self.pool.hbm, *args)
+                logits, toks, pools = decode(self.params, self._pools(),
+                                             *args)
+            self._rebind(pools)
         if self.cfg.keep_logits:
             logits_np = self._read("tokens", logits)
             for i, (req, _) in enumerate(pairs):
@@ -1831,9 +2075,17 @@ class Engine:
             "expert_committed_blocks": es.committed_blocks,
             "expert_landed_blocks": es.landed_blocks,
         } if es is not None else {}
+        st = self.states
+        state = {
+            "ssm_slots_live": st.live(),
+            "ssm_state_bytes": sum(a.size * a.dtype.itemsize
+                                   for a in st.arrays),
+            "ssm_recomputes": self.ssm_recomputes,
+        } if st is not None else {}
         return {
             **prefix,
             **expert,
+            **state,
             "saved_prefill_tokens": self.saved_prefill_tokens,
             "steps": self.step_count,
             "host_syncs": self.host_syncs,

@@ -32,11 +32,15 @@ sequence table (``request_pages``/``attach``/``release_request``), and
 Shared pages are immutable (copy-on-write: ``copy_page`` gives a writer a
 private copy) — sharing is full-page granular, so the serving engine never
 writes into a page with refcount > 1 on the normal path.
+
+A model with Mamba layers keeps a second kind of cache beside the pages:
+``StatePool``, one fixed-size recurrent state per request (DESIGN.md §17).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -532,3 +536,62 @@ class PagedKVPool:
         a shared page's allocator may be long finished."""
         return sorted(self._seq.get(request_id, ()),
                       key=lambda p: p.index_in_seq)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _clear_slot(arrays, slot):
+    return tuple(a.at[:, slot].set(jnp.zeros((), a.dtype)) for a in arrays)
+
+
+class StatePool:
+    """The second kind of cache: a fixed-size recurrent state per request
+    for every Mamba layer, in HBM beside the KV pages.
+
+    ``arrays`` is (conv, ssm): the conv's last W-1 inputs, (Lm, slots + 1,
+    (W-1) * conv_dim) in the model's dtype, and the SSM state, (Lm, slots
+    + 1, N, H*P) float32 — each slot's rows lane-dense, so a batch gathers
+    and scatters its rows without relayout.  A request holds one slot from
+    admission to finish or preemption (pause keeps it); the last slot is
+    scratch, the write target of a batch's inactive rows.  A slot is zeroed
+    when it is handed out, so a sequence starts from the zero state.  The
+    engine programs take ``arrays`` donated and the engine re-binds them,
+    like the KV pools.
+    """
+
+    def __init__(self, n_layers: int, slots: int, conv: int,
+                 state: Tuple[int, int], dtype=jnp.bfloat16, device=None):
+        device = device if device is not None else jax.devices()[0]
+        self.slots = slots
+        self.scratch = slots
+        self.arrays = (
+            jnp.zeros((n_layers, slots + 1, conv), dtype, device=device),
+            jnp.zeros((n_layers, slots + 1) + tuple(state), jnp.float32,
+                      device=device))
+        self.free: List[int] = list(range(slots - 1, -1, -1))
+        self.slot_of: Dict[int, int] = {}
+
+    @property
+    def slot_bytes(self) -> int:
+        """HBM one slot holds, every layer."""
+        return sum(a[:, 0].size * a.dtype.itemsize for a in self.arrays)
+
+    def live(self) -> int:
+        return len(self.slot_of)
+
+    def alloc(self, request_id: int) -> int:
+        if not self.free:
+            raise MemoryError(
+                f"all {self.slots} recurrent-state slots (ServeConfig."
+                f"max_batch) are held; finish or preempt a request first")
+        slot = self.free.pop()
+        self.arrays = _clear_slot(self.arrays, jnp.int32(slot))
+        self.slot_of[request_id] = slot
+        return slot
+
+    def release(self, request_id: int) -> bool:
+        """Free the request's slot; False if it held none."""
+        slot = self.slot_of.pop(request_id, None)
+        if slot is None:
+            return False
+        self.free.append(slot)
+        return True

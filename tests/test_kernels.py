@@ -190,7 +190,8 @@ def test_ssd_kernel_agrees_with_model_chunk():
     A = jnp.asarray(-rng.uniform(0.5, 2.0, size=(H,)), jnp.float32)
     Bm = jnp.asarray(rng.normal(size=(B, Q, N)), jnp.float32)
     Cm = jnp.asarray(rng.normal(size=(B, Q, N)), jnp.float32)
-    y_model, _ = ssd_chunked(x, dt, A, Bm, Cm, cfg)
+    # The model's scan takes B and C by group: one group here.
+    y_model, _ = ssd_chunked(x, dt, A, Bm[:, :, None], Cm[:, :, None], cfg)
     y_kernel = ssd_scan_pallas(x, dt, A, Bm, Cm, interpret=True)
     np.testing.assert_allclose(np.asarray(y_kernel), np.asarray(y_model),
                                atol=1e-4, rtol=1e-4)

@@ -28,7 +28,8 @@ def f32_params(model, key):
         model.init(key))
 
 
-@pytest.mark.parametrize("arch", ["zamba2_7b", "xlstm_350m"])
+@pytest.mark.parametrize("arch", ["zamba2_7b", "xlstm_350m",
+                                  "granite_4_0_h_small"])
 def test_prefill_then_decode_matches_stepwise(arch):
     cfg = f32_cfg(arch)
     model = build_model(cfg)
@@ -67,7 +68,8 @@ def test_prefill_then_decode_matches_stepwise(arch):
     assert a == b, (arch, a, b)
 
 
-@pytest.mark.parametrize("arch", ["zamba2_7b", "xlstm_350m"])
+@pytest.mark.parametrize("arch", ["zamba2_7b", "xlstm_350m",
+                                  "granite_4_0_h_small"])
 def test_prefill_logits_match_stepwise_logits(arch):
     """The prefill's final-position logits themselves agree with stepwise
     decode at the same position (tight tolerance: same math, chunked vs

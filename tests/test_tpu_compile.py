@@ -198,3 +198,80 @@ def test_tiered_piece_updates_the_pools_in_place_for_v5e(
                                 dispatch=dispatch).compile().as_text()
     assert "tpu_custom_call" in text
     assert not _copies_of(text, pool), _copies_of(text, pool)
+
+
+# granite-4.0-h-small as its cell serves it: stage 1 of 4 (layers 0-9:
+# nine Mamba-2 layers, attention at index 5), experts 0-35 of 72 held; 64
+# decode rows; the pools: 20,480 pages of the one attention layer's K and
+# V, each token's 8 heads of 128 one flat row, and the recurrent state of
+# 65 slots (64 rows and the scratch): the conv's last 3 inputs of 8,448
+# lanes, and 128 x (128 heads x 64) float32 SSM values.
+HYBRID_ROWS, HYBRID_MP, HYBRID_BUCKET = 64, 640, 2048
+HYBRID_POOLS = (((1, 20480, PAGE, 1024), BF16), ((1, 20480, PAGE, 1024), BF16),
+                ((9, 65, 3 * 8448), BF16), ((9, 65, 128, 8192), jnp.float32))
+
+
+def test_ssm_decode_compiles_for_v5e_and_updates_the_pool_in_place(
+        one_chip):
+    from repro.kernels.ssm_decode import ssm_decode_pallas
+
+    R, F32 = HYBRID_ROWS, jnp.float32
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in (
+                HYBRID_POOLS[3], ((), I32), ((R,), I32), ((R, 8192), BF16),
+                ((R, 128), F32), ((128,), F32), ((128,), F32),
+                ((R, 1, 128), F32), ((R, 1, 128), F32))]
+    compiled = jax.jit(ssm_decode_pallas, donate_argnums=0).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not _copies_of(text, HYBRID_POOLS[3][0])
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("dispatch", ["decode", "prefill"])
+def test_hybrid_programs_update_every_pool_in_place_for_v5e(
+        one_chip, monkeypatch, dispatch):
+    """The resident decode step and the largest prefill dispatch of the
+    granite-4.0-H cell take the K/V pools and the state pool donated and
+    copy none of them whole (1.34 GB of K/V, 2.45 GB of state), and fit
+    the chip beside the weights."""
+    from repro.configs import get
+    from repro.kernels import ops
+    from repro.models import build_model
+    from repro.serve.engine import Engine, _jit
+
+    monkeypatch.setattr(ops, "_MODE", "pallas")
+    full = get("granite_4_0_h_small")
+    engine = Engine.__new__(Engine)
+    engine.model = build_model(dataclasses.replace(
+        full, remat=False, n_layers=10, layer_types=full.layer_types[:10],
+        n_local_experts=36))
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(lambda a: sd(a.shape, a.dtype),
+                                    engine.model.abstract())
+    pools = tuple(sd(*p) for p in HYBRID_POOLS)
+    if dispatch == "decode":
+        B, F32 = HYBRID_ROWS, jnp.float32
+        fn = functools.partial(engine._decode_scan, False)
+        args = (sd((B, 1), I32), sd((B, HYBRID_MP), I32),
+                *[sd((B,), I32)] * 3, sd((B,), jnp.bool_), sd((B,), I32),
+                sd((B,), F32), sd((B,), I32), sd((B,), F32), sd((B,), I32))
+    else:
+        S = HYBRID_BUCKET
+        fn = engine._prefill_scan
+        args = (sd((S,), I32), sd((HYBRID_MP,), I32), sd((S,), I32),
+                sd((S,), I32), sd((), I32), sd((), I32), sd((), I32))
+    compiled = _jit(fn, donate_argnums=1).lower(params, pools,
+                                                *args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    for shape, _ in HYBRID_POOLS:
+        assert not _copies_of(text, shape), _copies_of(text, shape)
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert held < 16e9
