@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention_pallas
@@ -65,41 +66,97 @@ def test_flash_attention_grad_matches_reference():
 
 
 # --------------------------------------------------------- paged attention
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize(
-    "B,H,K,dh,N,P,MP,window",
-    [
-        (2, 8, 8, 64, 8, 8, 3, None),     # MHA
-        (3, 8, 4, 64, 16, 8, 4, None),    # GQA
-        (2, 4, 4, 128, 8, 16, 2, None),   # bigger pages
-        (2, 8, 4, 64, 16, 8, 4, 7),       # sliding window
-        (1, 8, 2, 96, 8, 8, 4, None),     # unaligned dh
-    ],
-)
-def test_paged_attention_sweep(B, H, K, dh, N, P, MP, window, dtype):
-    rng = np.random.default_rng(0)
+# The TPU interpreter: scratch memory starts as NaNs and a DMA lands only
+# when it is waited for, so a stale buffer or a missed wait shows.
+TPU_INTERPRET = pltpu.InterpretParams()
+
+
+def _case(B, H, K, dh, N, P, MP, lens, flat, dtype, rng):
+    """Queries, pools and a table for ``lens`` (None: random lengths).
+    Given lengths also hold one page past the length where the row has
+    room, which the walk must not read."""
     q = jnp.asarray(rng.normal(size=(B, H, dh)), dtype)
-    kp = jnp.asarray(rng.normal(size=(N, P, K, dh)), dtype)
-    vp = jnp.asarray(rng.normal(size=(N, P, K, dh)), dtype)
+    pool = (N, P, K * dh) if flat else (N, P, K, dh)
+    kp = jnp.asarray(rng.normal(size=pool), dtype)
+    vp = jnp.asarray(rng.normal(size=pool), dtype)
     table = np.full((B, MP), -1, np.int32)
     lengths = np.zeros((B,), np.int32)
     slots = rng.permutation(N)
     si = 0
     for b in range(B):
-        n_pages = int(rng.integers(1, MP + 1))
-        lengths[b] = int(rng.integers((n_pages - 1) * P + 1, n_pages * P + 1))
+        if lens is None:
+            n_pages = int(rng.integers(1, MP + 1))
+            lengths[b] = int(rng.integers((n_pages - 1) * P + 1,
+                                          n_pages * P + 1))
+        else:
+            lengths[b] = lens[b]
+            n_pages = min(MP, -(-lens[b] // P) + 1)
         for pg in range(n_pages):
             table[b, pg] = slots[si]
             si += 1
-    table = jnp.asarray(table)
-    lengths = jnp.asarray(lengths)
-    got = paged_attention_pallas(q, kp, vp, table, lengths, window=window,
-                                 interpret=True)
-    want = ref.paged_attention_reference(q, kp, vp, table, lengths,
-                                         window=window)
-    np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(want, np.float32),
-        atol=TOL[dtype], rtol=TOL[dtype])
+    return q, kp, vp, jnp.asarray(table), jnp.asarray(lengths)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize(
+    "B,H,K,dh,N,P,MP,window,lens,flat",
+    [
+        pytest.param(2, 8, 8, 64, 8, 8, 3, None, None, False,
+                     id="2-8-8-64-8-8-3-None"),           # MHA
+        pytest.param(3, 8, 4, 64, 16, 8, 4, None, None, False,
+                     id="3-8-4-64-16-8-4-None"),          # GQA
+        pytest.param(2, 4, 4, 128, 8, 16, 2, None, None, False,
+                     id="2-4-4-128-8-16-2-None"),         # bigger pages
+        pytest.param(2, 8, 4, 64, 16, 8, 4, 7, None, False,
+                     id="2-8-4-64-16-8-4-7"),             # sliding window
+        pytest.param(1, 8, 2, 96, 8, 8, 4, None, None, False,
+                     id="1-8-2-96-8-8-4-None"),           # unaligned dh
+        # 24 pages of 16 in three blocks of eight: rows that end mid-block,
+        # on a block's end, at 0, at the table's end and in the first page.
+        pytest.param(5, 8, 2, 64, 64, 16, 24, None,
+                     (200, 128, 0, 384, 1), False, id="blocks"),
+        # 20 pages: the table padded to three blocks; a window across
+        # blocks.
+        pytest.param(4, 8, 2, 64, 64, 16, 20, 40, (300, 0, 256, 17), False,
+                     id="blocks-padded-window"),
+        # granite-4.0-H's tile: a flat pool, 8 KV heads of 128.
+        pytest.param(2, 32, 8, 128, 48, 16, 24, None, (130, 384), True,
+                     id="flat-kv8-dh128"),
+        # granite's tile: an (N, P, K, dh) pool, 8 KV heads of 64.
+        pytest.param(3, 24, 8, 64, 48, 16, 20, None, (129, 0, 320), False,
+                     id="kv8-dh64"),
+    ],
+)
+def test_paged_attention_sweep(B, H, K, dh, N, P, MP, window, lens, flat,
+                               dtype):
+    q, kp, vp, table, lengths = _case(B, H, K, dh, N, P, MP, lens, flat,
+                                      dtype, np.random.default_rng(0))
+    got = np.asarray(
+        paged_attention_pallas(q, kp, vp, table, lengths, window=window,
+                               interpret=TPU_INTERPRET), np.float32)
+    want = np.asarray(
+        ref.paged_attention_reference(q, kp, vp, table, lengths,
+                                      window=window), np.float32)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(got[live], want[live],
+                               atol=TOL[dtype], rtol=TOL[dtype])
+    # A row of length 0 sees nothing: zeros, as padded prefill rows need.
+    assert np.array_equal(got[~live], np.zeros_like(got[~live]))
+
+
+def test_paged_prefill_equals_decode_bitwise():
+    """S prompt rows in one prefill call equal S one-row decode calls at
+    lengths 1..S, bit for bit, over a table of three blocks whose rows
+    reach the second."""
+    rng = np.random.default_rng(5)
+    S, N, P, K, dh, MP = 140, 32, 16, 2, 64, 24
+    q, kp, vp, table = _prefill_case(rng, S, N, P, K, dh, MP)
+    lengths = jnp.arange(1, S + 1, dtype=jnp.int32)
+    got = paged_prefill_pallas(q, kp, vp, table, lengths, interpret=True)
+    for t in range(S):
+        one = paged_attention_pallas(q[t:t + 1], kp, vp, table[None, :],
+                                     lengths[t:t + 1], interpret=True)
+        assert np.array_equal(np.asarray(one[0]), np.asarray(got[t])), t
 
 
 # ----------------------------------------------------- one-shot prefill

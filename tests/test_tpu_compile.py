@@ -1,6 +1,6 @@
-"""The serving kernels compile for a TPU v5e at granite-moe-3b-a800m's
-and DeepSeek-V2-Lite's widths — checked here, without the chip, against a
-described v5e topology.
+"""The serving kernels compile for a TPU v5e at granite-moe-3b-a800m's,
+DeepSeek-V2-Lite's and granite-4.0-h-small's widths — checked here,
+without the chip, against a described v5e topology.
 
 Each kernel test compiles one Pallas kernel at the shapes the chip run
 serves and asserts the compiled program holds the Mosaic kernel
@@ -209,6 +209,24 @@ def test_tiered_piece_updates_the_pools_in_place_for_v5e(
 HYBRID_ROWS, HYBRID_MP, HYBRID_BUCKET = 64, 640, 2048
 HYBRID_POOLS = (((1, 20480, PAGE, 1024), BF16), ((1, 20480, PAGE, 1024), BF16),
                 ((9, 65, 3 * 8448), BF16), ((9, 65, 128, 8192), jnp.float32))
+
+
+@pytest.mark.parametrize("rows,heads,dh,pool,mp", [
+    (HYBRID_ROWS, 32, 128, HYBRID_POOLS[0][0][1:], HYBRID_MP),
+    (32, H, DH, (2560, PAGE, K, DH), 160),
+], ids=["granite-h-small", "granite-moe"])
+def test_paged_attention_walk_compiles_at_cell_shapes_for_v5e(
+        one_chip, rows, heads, dh, pool, mp):
+    """The decode kernel's block walk at the cells' shapes: granite-4.0-H's
+    64 rows over 640 pages of a flat (N, 16, 1024) pool, granite's 32 rows
+    over 160 pages of an (N, 16, 8, 64) pool.  Its VMEM buffers fit, and
+    the Mosaic call keeps the name the benchmark's trace reads."""
+    text = _compiled_text(
+        paged_attention_pallas, one_chip,
+        ((rows, heads, dh), BF16), (pool, BF16), (pool, BF16),
+        ((rows, mp), I32), ((rows,), I32))
+    assert re.search(r'^\s*%paged_attention_pallas[.\d]* = .*'
+                     r'custom_call_target="tpu_custom_call"', text, re.M)
 
 
 def test_ssm_decode_compiles_for_v5e_and_updates_the_pool_in_place(
